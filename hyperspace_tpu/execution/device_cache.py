@@ -21,12 +21,12 @@ measured profile (utils/calibrate.py) instead of the cold-transfer
 threshold.  Population policy (conf ``deviceCachePolicy``):
 
   - ``auto`` (default): populate whenever the device path runs anyway —
-    free on locally attached chips where the calibrated cold threshold
-    routes large scans to the device organically.
+    free where the calibrated cold threshold routes large scans to the
+    device organically.
   - ``eager``: ship eligible scan columns on FIRST use even when the
     cold cost model would stay on host — an explicit opt-in for
-    repeat-heavy workloads behind a slow attachment (pay the tunnel
-    once, serve every later query from HBM).
+    repeat-heavy workloads (pay the transfer once, serve every later
+    query from HBM).
   - ``off``: never cache.
 """
 

@@ -30,7 +30,7 @@ from hyperspace_tpu.execution import sync_guard
 from hyperspace_tpu.io import columnar
 from hyperspace_tpu.telemetry import timeline
 from hyperspace_tpu.utils import deadline as _deadline
-from hyperspace_tpu.utils.compat import enable_x64 as _enable_x64
+from jax import enable_x64 as _enable_x64
 from hyperspace_tpu.io.files import list_data_files
 from hyperspace_tpu.io.parquet import bucket_id_of_file, read_table
 from hyperspace_tpu.plan.expr import (
@@ -824,7 +824,7 @@ class Executor:
         evaluation, and segment reduction all happen in HBM; only
         per-group results return.  The north-star shapes
         (BASELINE.md Q3/Q10) are exactly this pattern — executed
-        separately, the full joined row set would cross the attachment.
+        separately, the full joined row set would cross to the host.
 
         Returns None to leave the plan alone (structural mismatch, or
         the device isn't plausibly profitable); ("done", table) with the

@@ -30,8 +30,8 @@ reach a jax array's buffer zero-copy, so a raw ``np.asarray`` is not
 interceptable there — but ``.item()``/``float()``/``bool()``/``int()``
 (the scalar syncs that dominate the 196-site audit) always route through
 the patched surface, and the static rule covers ``np.asarray`` at review
-time.  On TPU every pull crosses the wire through ``__array__`` and is
-caught.
+time.  On TPU every pull is a device→host copy through ``__array__`` and
+is caught.
 """
 
 from __future__ import annotations
@@ -137,23 +137,21 @@ def _violation(kind: str):
 
 def _install() -> None:
     """Patch the concrete jax array type's host-conversion surface.
-    Idempotent; never raises (an unpatchable surface just leaves the
-    guard static-only, and doctor/tests surface that via the metrics)."""
+    Idempotent.  Raises RuntimeError when the surface cannot be patched:
+    an armed guard that patches nothing would pass every test while
+    catching no sync."""
     global _patched
     with _install_lock:
         if _patched:
             return
-        try:
-            import jaxlib.xla_extension as _xe
+        from jaxlib._jax import ArrayImpl as cls
 
-            cls = _xe.ArrayImpl
-        except Exception:  # noqa: BLE001 — no jaxlib, nothing to guard
-            _patched = True
-            return
+        missing = []
 
         def _wrap(name: str):
             orig = getattr(cls, name, None)
             if orig is None:
+                missing.append(name)
                 return
 
             def guarded(self, *args, **kwargs):
@@ -165,9 +163,13 @@ def _install() -> None:
             try:
                 setattr(cls, name, guarded)
             except (AttributeError, TypeError):
-                pass  # immutable slot on this jaxlib — partial coverage
+                missing.append(name)
 
         for name in ("item", "tolist", "__float__", "__int__",
                      "__bool__", "__index__", "__array__"):
             _wrap(name)
+        if missing:
+            raise RuntimeError(
+                f"deviceGuard cannot patch {cls.__module__}."
+                f"{cls.__qualname__}: {', '.join(missing)}")
         _patched = True

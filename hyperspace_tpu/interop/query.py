@@ -111,7 +111,8 @@ def value_expr_from_json(obj: Any) -> Expr:
     if op == "neg":
         return Neg(value_expr_from_json(obj["child"]))
     if op == "cast":
-        return Cast(value_expr_from_json(obj["child"]), obj["type"])
+        cast = Cast(value_expr_from_json(obj["child"]), obj["type"])
+        return _fold_temporal_literal(cast)
     if op == "extract":
         # {"op": "extract", "field": "year", "child": {"col": "d"}}
         return Extract(obj["field"], value_expr_from_json(obj["child"]))
@@ -135,6 +136,26 @@ def value_expr_from_json(obj: Any) -> Expr:
     if op is None and "value" in obj:
         return Lit(obj["value"])
     raise ValueError(f"Unknown value expression: {obj!r}")
+
+
+def _fold_temporal_literal(cast: Cast) -> Expr:
+    """``cast('1995-03-15' AS date)`` of a literal becomes the typed
+    literal, as the SQL front end's ``DATE '1995-03-15'`` is: JSON has
+    no date type, and the device filter compares temporal columns only
+    against temporal literals.  A literal that does not parse keeps the
+    Cast node (Spark's null-on-failure)."""
+    import pyarrow as pa
+
+    from hyperspace_tpu.io.parquet import _dtype_from_string
+
+    target = _dtype_from_string(cast.type_name)
+    if not (isinstance(cast.child, Lit) and isinstance(cast.child.value, str)
+            and pa.types.is_temporal(target)):
+        return cast
+    try:
+        return Lit(pa.scalar(cast.child.value).cast(target).as_py())
+    except (pa.ArrowInvalid, pa.ArrowNotImplementedError):
+        return cast
 
 
 def expr_from_json(obj: Dict[str, Any]) -> Expr:

@@ -418,8 +418,7 @@ class Rule:
             self._emit(
                 findings, src.relpath, node.lineno, "float64-literal", fn,
                 f"{name} outside a scoped `with _enable_x64():` region — "
-                f"under the 32-bit default this silently downcasts "
-                f"(utils/compat.py shim)")
+                f"under the 32-bit default this silently downcasts")
 
     def _check_jit_unsafe(self, src, graph, findings) -> None:
         for info in graph.functions_in(src.relpath):

@@ -3,9 +3,10 @@
 The native library accelerates the metadata-side hot loops — the per-query
 file walk + stat + md5 fingerprint fold behind index-validity signatures
 (FileBasedSignatureProvider.scala:38-61; SURVEY §3.2's driver bottleneck).
-Loading is best-effort: a prebuilt ``native/build/libhs_native.so`` is used
-if present, otherwise the library is compiled once with g++ into a cache
-directory; on any failure every entry point returns None and callers fall
+Loading is best-effort: the library is compiled once with g++ into the
+in-checkout cache ``.hs_cache/native``, keyed by the sha256 of the
+committed ``hs_native.cc`` (so a stale build of older source is never
+loaded); on any failure every entry point returns None and callers fall
 back to the pure-Python implementations, which are byte-identical.
 
 Set ``HS_NATIVE=0`` to disable the native path entirely.
@@ -17,7 +18,6 @@ import ctypes
 import hashlib
 import os
 import subprocess
-import tempfile
 import threading
 from typing import List, Optional, Sequence, Tuple
 
@@ -25,8 +25,6 @@ from typing import List, Optional, Sequence, Tuple
 # fast path (it compiles on demand wherever g++ exists).
 _SOURCE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                        "hs_native.cc")
-_PREBUILT = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__)))), "native", "build", "libhs_native.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -43,14 +41,12 @@ _SCAN_CB = ctypes.CFUNCTYPE(None, ctypes.c_void_p, ctypes.c_char_p,
 
 
 def _cache_so_path() -> str:
+    from hyperspace_tpu.utils.xla_cache import checkout_cache_dir
+
     with open(_SOURCE, "rb") as f:
-        digest = hashlib.md5(f.read()).hexdigest()[:12]
-    cache_dir = os.path.join(
-        os.environ.get("XDG_CACHE_HOME",
-                       os.path.join(os.path.expanduser("~"), ".cache")),
-        "hyperspace_tpu")
-    os.makedirs(cache_dir, exist_ok=True)
-    return os.path.join(cache_dir, f"libhs_native-{digest}.so")
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(checkout_cache_dir("native"),
+                        f"libhs_native-{digest}.so")
 
 
 def _compile(out_path: str) -> bool:
@@ -104,13 +100,6 @@ def get_lib() -> Optional[ctypes.CDLL]:
     with _lock:
         if _lib is not None or _lib_failed:
             return _lib
-        for candidate in (_PREBUILT,):
-            if os.path.isfile(candidate):
-                try:
-                    _lib = _declare(ctypes.CDLL(candidate))
-                    return _lib
-                except OSError:
-                    pass
         if not os.path.isfile(_SOURCE):
             _lib_failed = True
             return None

@@ -31,7 +31,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from hyperspace_tpu.execution import sync_guard
-from hyperspace_tpu.utils.compat import enable_x64 as _enable_x64
+from hyperspace_tpu.ops.hash import lexsort32
+from jax import enable_x64 as _enable_x64
 from hyperspace_tpu.utils.shapes import round_up_pow2
 
 AGG_OPS = ("sum", "min", "max", "mean", "count", "count_all")
@@ -51,7 +52,7 @@ def _group_sort(key_words, n_valid):
         keys.append(w[:, 1])
         keys.append(w[:, 0])
     keys.append(invalid)  # LAST key = primary: valid rows first
-    perm = jnp.lexsort(tuple(keys)).astype(jnp.int32)
+    perm = lexsort32(keys)
     is_valid = positions < n_valid
     diff = jnp.zeros(n, dtype=bool)
     for w in key_words:

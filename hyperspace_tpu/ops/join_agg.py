@@ -3,7 +3,7 @@
 The north-star workloads (BASELINE.md: TPC-H Q3/Q10 wall-clock) are
 ``aggregate(filter ⨝ index)`` shapes.  Executing the join and the
 aggregation as separate engines forces the full joined row set through
-host memory — and over a narrow attachment, back across the wire.  This
+host memory and back across the host–device link.  This
 pipeline keeps the intermediate entirely in HBM:
 
   1. sorted equi-join over the (resident) key columns — searchsorted
@@ -38,8 +38,9 @@ import numpy as np
 
 from hyperspace_tpu.execution import sync_guard
 from hyperspace_tpu.ops.aggregate import _group_sort, _segment_reduce
-from hyperspace_tpu.utils.compat import enable_x64 as _enable_x64
-from hyperspace_tpu.ops.join import _expand, _match_ranges
+from jax import enable_x64 as _enable_x64
+from hyperspace_tpu.ops.join import _join_pairs, _join_ranges
+from hyperspace_tpu.ops.sort import _pad_rows
 from hyperspace_tpu.utils.shapes import round_up_pow2
 
 
@@ -48,7 +49,7 @@ def _topk_groups(col, n_valid, *, k: int, ascending: bool,
                  capacity: int):
     """Indices of the top/bottom-k VALID group slots by ``col`` —
     the device form of ORDER BY <agg> LIMIT k, so only k groups (not
-    all of them) ever cross the attachment.  Invalid (padding) slots
+    all of them) ever cross to the host.  Invalid (padding) slots
     are parked with sentinels; ``k`` and the capacity are static, the
     valid count is traced."""
     valid = jnp.arange(capacity) < n_valid
@@ -208,22 +209,24 @@ def join_group_aggregate(
             for a in (l_key, r_key, *columns)
             if not isinstance(a, jax.Array)))
     with _enable_x64():
-        lk = jnp.asarray(l_key)
-        rk = jnp.asarray(r_key)
-        if lk.shape[0] == 0 or rk.shape[0] == 0:
+        n_left, n_right = int(l_key.shape[0]), int(r_key.shape[0])
+        if n_left == 0 or n_right == 0:
             return (np.empty(0, np.int64), np.empty(0, np.int64),
                     np.empty(0, np.int32), [np.empty(0) for _ in agg_ops])
-        r_perm = jnp.argsort(rk)
-        lo, hi = _match_ranges(lk, rk[r_perm])
+        # Keys padded to a power of two (ops/join.sorted_equi_join's
+        # capacity contract): padded rows match nothing, so the match
+        # indices only ever address the real rows of ``columns``.
+        lk = jnp.asarray(_pad_rows(l_key, round_up_pow2(n_left)))
+        rk = jnp.asarray(_pad_rows(r_key, round_up_pow2(n_right)))
+        r_perm, lo, hi, total = _join_ranges(lk, rk, n_left, n_right)
         # sync 1: match count (the standard XLA dynamic-shape point)
-        total = int(sync_guard.scalar(jnp.sum(hi - lo), "join_agg.matches"))
+        total = int(sync_guard.scalar(total, "join_agg.matches"))
         if total == 0:
             timeline.kernel_end("join_agg", t0, (lo, hi))
             return (np.empty(0, np.int64), np.empty(0, np.int64),
                     np.empty(0, np.int32), [np.empty(0) for _ in agg_ops])
         capacity = round_up_pow2(total)
-        li, right_pos = _expand(lo, hi, capacity)
-        ri = r_perm[jnp.clip(right_pos, 0, rk.shape[0] - 1)]
+        li, ri = _join_pairs(r_perm, lo, hi, capacity)
         gathered = [
             jnp.asarray(c)[li if side == "l" else ri]
             for c, side in zip(columns, column_sides)]

@@ -34,11 +34,7 @@ from typing import List, Sequence, Tuple
 import jax
 import numpy as np
 from jax.sharding import PartitionSpec as P
-
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 from hyperspace_tpu.ops.aggregate import AGG_OPS, _group_sort, _segment_reduce
 from hyperspace_tpu.ops.hash import bucket_ids_np
@@ -47,7 +43,7 @@ from hyperspace_tpu.parallel.mesh import (
     make_shard_and_gather_fns,
     match_partition_rules,
 )
-from hyperspace_tpu.utils.compat import enable_x64 as _enable_x64
+from jax import enable_x64 as _enable_x64
 from hyperspace_tpu.utils.shapes import round_up_pow2
 
 

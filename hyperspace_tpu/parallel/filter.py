@@ -22,7 +22,7 @@ import numpy as np
 
 from hyperspace_tpu.execution import sync_guard
 from hyperspace_tpu.parallel.mesh import SHARD_AXIS, build_mesh
-from hyperspace_tpu.utils.compat import enable_x64 as _enable_x64
+from jax import enable_x64 as _enable_x64
 
 
 def eval_predicate_on_mesh(fn: Callable, columns: Sequence[np.ndarray],

@@ -22,15 +22,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 from hyperspace_tpu.execution import sync_guard
+from hyperspace_tpu.ops.hash import lexsort32
 from hyperspace_tpu.parallel.mesh import SHARD_AXIS
-from hyperspace_tpu.utils.compat import enable_x64 as _enable_x64
+from jax import enable_x64 as _enable_x64
 from hyperspace_tpu.utils.shapes import round_up_pow2
 
 
@@ -44,7 +41,10 @@ def _ranges_local(lk, lvalid, rk, rvalid):
     with the largest valid key so the array stays sorted, and both range
     ends are clamped to the valid count."""
     inv = jnp.uint32(1) - rvalid.astype(jnp.uint32)
-    r_order = jnp.lexsort((rk, inv))  # primary: valid rows first
+    # Primary: valid rows first.
+    r_order = lexsort32((rk, inv)) \
+        if not jnp.issubdtype(rk.dtype, jnp.floating) \
+        else jnp.lexsort((rk, inv))
     rk_ord = rk[r_order]
     n_r = jnp.sum(rvalid, dtype=jnp.int32)
     max_valid = rk_ord[jnp.maximum(n_r - 1, 0)]

@@ -554,9 +554,26 @@ _WORKER_CONF_FIELDS = (
 )
 
 
+def require_cpu_hosts(n: int) -> None:
+    """The local build hosts are child processes of this one.  A chip
+    belongs to one process, and this process may already hold it, so the
+    children would fail or hang on it: they run only where the caller
+    pinned ``JAX_PLATFORMS=cpu`` explicitly.  Anything else is refused
+    here, before a plan is written, instead of defaulting to the CPU."""
+    from hyperspace_tpu.exceptions import HyperspaceError
+
+    if os.environ.get("JAX_PLATFORMS", "").lower() != "cpu":
+        raise HyperspaceError(
+            f"multihost build: {n} local host processes cannot each get a "
+            f"chip of their own (a chip belongs to one process, and this "
+            f"one may hold it); set JAX_PLATFORMS=cpu to run the hosts on "
+            f"the CPU, or run host_main() once per machine")
+
+
 def spawn_hosts(conf, build_id: str, n: int) -> List[subprocess.Popen]:
     """Spawn ``n`` build-host subprocesses against one plan.  Each
-    inherits the environment (JAX_PLATFORMS etc.) plus the spec; the
+    inherits the environment (the explicit ``JAX_PLATFORMS=cpu`` pin
+    that :func:`require_cpu_hosts` checked) plus the spec; the
     host-vs-device route threshold is resolved HERE so every host (and
     any host that later reclaims) routes through the same path."""
     import hyperspace_tpu
@@ -575,7 +592,6 @@ def spawn_hosts(conf, build_id: str, n: int) -> List[subprocess.Popen]:
             "conf": overrides,
             "owner": None,  # fleet.process_identity() of the subprocess
         })
-        env.setdefault("JAX_PLATFORMS", "cpu")
         # The parent may import the package from its cwd; the child has
         # no cwd entry on sys.path, so pin the package's location.
         env["PYTHONPATH"] = pkg_parent + (
@@ -636,8 +652,9 @@ def run_multihost_build(action, files, columns, relation, resolved,
     from hyperspace_tpu.telemetry import fleet, metrics
 
     conf = action.conf
-    reap_orphan_build_dirs(conf)
     n_hosts = int(conf.multihost_build_hosts)
+    require_cpu_hosts(n_hosts)
+    reap_orphan_build_dirs(conf)
     build_id = f"{_BUILD_DIR_PREFIX}{os.getpid()}-{uuid.uuid4().hex[:8]}"
     plan = make_plan(conf, build_id, action.index_name, relation, resolved,
                      files, columns, lineage, batch_rows)

@@ -33,15 +33,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-
-try:  # jax >= 0.4.35 exports shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 from hyperspace_tpu.execution import sync_guard
 from hyperspace_tpu.io.columnar import join_words64, split_words64
-from hyperspace_tpu.ops.hash import _bucket_ids_impl, use_pallas
+from hyperspace_tpu.ops.hash import _bucket_ids_impl, lexsort32, use_pallas
 from hyperspace_tpu.parallel.mesh import SHARD_AXIS
 
 
@@ -112,7 +108,7 @@ def sort_received(recv, n_key_cols: int):
         keys.append(recv[:, 4 + 2 * k])      # hi
     keys.append(rbucket)
     keys.append(jnp.uint32(1) - flag)        # primary: invalid rows last
-    perm = jnp.lexsort(tuple(keys))
+    perm = lexsort32(keys)
     return recv[perm], jnp.sum(flag, dtype=jnp.int32)
 
 
@@ -152,6 +148,9 @@ def _shuffle_program(hash_words, order_words, row_words, payload, valid, *,
         body, mesh=mesh,
         in_specs=(spec, spec, spec, spec, spec),
         out_specs=(spec, spec, spec),
+        # The pallas hash kernel's pallas_call cannot type its output's
+        # varying mesh axes; every output here is sharded on the one axis.
+        check_vma=False,
     )(hash_words, order_words, row_words, payload, valid)
 
 

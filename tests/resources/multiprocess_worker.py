@@ -46,10 +46,7 @@ from hyperspace_tpu.parallel.multihost import (  # noqa: E402
     initialize_distributed,
 )
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 
 def main() -> None:

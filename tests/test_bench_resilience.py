@@ -31,7 +31,7 @@ SECTIONS = ("setup", "sf1_queries", "device_agg_probe", "resident_agg",
 def _env(tmp_path, budget: str) -> dict:
     env = dict(os.environ)
     env.update(
-        JAX_PLATFORMS="cpu",          # the probe and the run stay local
+        JAX_PLATFORMS="cpu",          # the bench runs on the CPU only when asked
         HS_XLA_CACHE="0",
         HS_CALIBRATE="0",
         HS_DEVICE_BATCH_ROWS="65536",
@@ -85,7 +85,7 @@ def test_exhausted_budget_still_emits_full_headline(tmp_path):
     """A budget too small for ANY section: every section is skipped with
     the budget reason, the headline still prints, exit code 0."""
     proc = subprocess.run(
-        [sys.executable, BENCH], env=_env(tmp_path, budget="0.01"),
+        [sys.executable, BENCH], env=_env(tmp_path, budget="0.000001"),
         capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-2000:]
     lines, headline = _parse_lines(proc.stdout)

@@ -352,6 +352,25 @@ def test_spec_union_and_cast(env, tmp_path):
     assert sorted(out.column("k").to_pylist()) == [0, 1, 10_000, 10_001]
 
 
+@pytest.mark.parametrize("value,typed", [
+    ("1995-03-15", True), ("not a date", False)])
+def test_spec_cast_of_a_literal_folds_to_a_temporal_literal(value, typed):
+    """JSON has no date type: cast('...' AS date) of a literal becomes
+    the typed literal (what SQL's DATE '...' gives), so the device filter
+    can compare a date column with it; an unparsable one stays a Cast."""
+    import datetime
+
+    from hyperspace_tpu.interop.query import value_expr_from_json
+    from hyperspace_tpu.plan.expr import Cast, Lit
+
+    e = value_expr_from_json({"op": "cast", "child": {"value": value},
+                              "type": "date"})
+    if typed:
+        assert isinstance(e, Lit) and e.value == datetime.date(1995, 3, 15)
+    else:
+        assert isinstance(e, Cast)
+
+
 def test_spec_select_preserves_interleaved_order(env):
     """["a", {computed}, "b"] keeps the caller's column order — computed
     entries must not be shoved after all plain names."""

@@ -355,7 +355,7 @@ class TestTopkGroups:
         import jax.numpy as jnp
 
         from hyperspace_tpu.ops.join_agg import _topk_groups
-        from hyperspace_tpu.utils.compat import enable_x64 as _x64
+        from jax import enable_x64 as _x64
 
         with _x64():
             idx = _topk_groups(jnp.asarray(col_np), n_valid, k=k,

@@ -870,7 +870,7 @@ class TestDeviceDiscipline:
                "def bad(x):\n"
                "    return x.astype(jnp.float64)\n")
         ok = ("import jax.numpy as jnp\n\n"
-              "from hyperspace_tpu.utils.compat import enable_x64 as "
+              "from jax import enable_x64 as "
               "_enable_x64\n\n\n"
               "def good(x):\n"
               "    with _enable_x64():\n"

@@ -31,6 +31,7 @@ import pyarrow.parquet as pq
 import pytest
 
 from hyperspace_tpu import Hyperspace, HyperspaceSession, IndexConfig
+from hyperspace_tpu.exceptions import HyperspaceError
 from hyperspace_tpu.io.parquet import bucket_id_of_file
 from hyperspace_tpu.lifecycle import journal as lifecycle_journal
 from hyperspace_tpu.lifecycle.lease import WorkClaims
@@ -258,3 +259,17 @@ def test_sigkill_mid_route_survivor_completes(tmp_path, mh_source,
     done_items = [e["item"] for e in events if e["mode"] == "complete"]
     assert len(done_items) == len(set(done_items))
     assert multihost_build.scan_build_claims(s.conf) == []
+
+
+@pytest.mark.parametrize("platforms", [None, "tpu", ""])
+def test_hosts_refuse_without_an_explicit_cpu_pin(monkeypatch, platforms):
+    """No host child silently defaults to the CPU: without the explicit
+    JAX_PLATFORMS=cpu pin the build refuses and names the reason."""
+    if platforms is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    with pytest.raises(HyperspaceError, match="chip of their own"):
+        multihost_build.require_cpu_hosts(2)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    multihost_build.require_cpu_hosts(2)

@@ -349,3 +349,32 @@ def test_bucket_sort_permutation_host_mirror_parity():
         hb, hp = bucket_sort_permutation_np(word_cols, order_words, nb)
         np.testing.assert_array_equal(np.asarray(db), hb)
         np.testing.assert_array_equal(np.asarray(dp), hp)
+
+
+@pytest.mark.parametrize("dtypes", [
+    ("uint32", "uint32"), ("int32", "bool"), ("int64",), ("int64", "int32"),
+    ("uint32", "int64", "uint32"),
+])
+def test_lexsort32_matches_jnp_lexsort(dtypes):
+    """The looped word sort is the same stable permutation as one
+    multi-operand lexsort, ties (few distinct values) included."""
+    import jax
+    import jax.numpy as jnp
+
+    from hyperspace_tpu.ops.hash import lexsort32
+
+    rng = np.random.default_rng(len(dtypes))
+    n = 5000
+    with jax.enable_x64(True):
+        keys = []
+        for dt in dtypes:
+            if dt == "bool":
+                keys.append(jnp.asarray(rng.integers(0, 2, n).astype(bool)))
+            elif dt.startswith("u"):
+                keys.append(jnp.asarray(rng.integers(0, 7, n).astype(dt)))
+            else:
+                vals = rng.integers(-4, 4, n) * (2**40 if dt == "int64"
+                                                 else 2**20)
+                keys.append(jnp.asarray(vals.astype(dt)))
+        np.testing.assert_array_equal(np.asarray(lexsort32(keys)),
+                                      np.asarray(jnp.lexsort(keys)))

@@ -19,6 +19,12 @@ from hyperspace_tpu.ops.pallas_kernels import (
 from hyperspace_tpu.ops.sort import _bucket_counts_xla
 
 
+@pytest.fixture(autouse=True)
+def _ask_for_interpret_mode(monkeypatch):
+    # Off-TPU, pallas runs in interpret mode only when asked.
+    monkeypatch.setenv("HYPERSPACE_TPU_PALLAS", "on")
+
+
 def _words(n, cols=2, seed=0):
     rng = np.random.default_rng(seed)
     return tuple(
@@ -71,3 +77,12 @@ def test_env_switch(monkeypatch):
     assert not use_pallas()
     via_xla = np.asarray(bucket_ids(cols, 64))
     np.testing.assert_array_equal(via_dispatch, via_xla)
+
+
+def test_unrequested_interpret_mode_is_refused(monkeypatch):
+    from hyperspace_tpu.ops.pallas_kernels import _interpret
+
+    assert _interpret()  # asked for by the autouse fixture
+    monkeypatch.delenv("HYPERSPACE_TPU_PALLAS")
+    with pytest.raises(RuntimeError, match="HYPERSPACE_TPU_PALLAS=on"):
+        _interpret()

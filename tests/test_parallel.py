@@ -15,7 +15,7 @@ import jax
 
 from hyperspace_tpu.io import columnar
 from hyperspace_tpu.ops.hash import bucket_ids
-from hyperspace_tpu.utils.compat import enable_x64 as _enable_x64
+from jax import enable_x64 as _enable_x64
 from hyperspace_tpu.ops.sort import bucket_sort_permutation
 from hyperspace_tpu.parallel import (
     bucket_shuffle,
@@ -50,7 +50,12 @@ class TestBucketShuffle:
             payload_words=np.empty((0, 3), np.uint32))
         assert payload.shape == (0, 3)
 
-    def test_matches_single_device_assignment(self, mesh):
+    @pytest.mark.parametrize("pallas", ["off", "on"])
+    def test_matches_single_device_assignment(self, mesh, pallas,
+                                              monkeypatch):
+        # "on": the pallas hash kernel inside shard_map (interpret mode
+        # here, Mosaic on a TPU).
+        monkeypatch.setenv("HYPERSPACE_TPU_PALLAS", pallas)
         rng = np.random.default_rng(0)
         vals = rng.integers(0, 10_000, size=5_000)
         hw, ow = _key_arrays(vals)
@@ -416,8 +421,10 @@ class TestHierarchicalShuffle:
     """Two-stage (DCN then ICI) shuffle over a 2-axis mesh — must be
     bit-identical to the flat 1-axis shuffle on the same devices."""
 
+    @pytest.mark.parametrize("pallas", ["off", "on"])
     @pytest.mark.parametrize("shape", [(2, 4), (4, 2), (8, 1), (1, 8)])
-    def test_matches_flat_shuffle(self, mesh, shape):
+    def test_matches_flat_shuffle(self, mesh, shape, pallas, monkeypatch):
+        monkeypatch.setenv("HYPERSPACE_TPU_PALLAS", pallas)
         from hyperspace_tpu.parallel import (
             build_mesh_2d,
             hierarchical_bucket_shuffle,

@@ -178,11 +178,16 @@ class TestActiveMesh:
 # Sharded route+partition: bit-equality + ownership
 # ---------------------------------------------------------------------------
 class TestMeshRoutePartition:
+    @pytest.mark.parametrize("pallas", ["off", "on"])
     @pytest.mark.parametrize("n", [8, 37, 1000, 4096])
-    def test_bit_equal_vs_host_mirror(self, n):
+    def test_bit_equal_vs_host_mirror(self, n, pallas, monkeypatch):
         from hyperspace_tpu.parallel.sharded_build import (
             mesh_route_partition,
         )
+
+        # "on": the pallas hash kernel inside shard_map (interpret mode
+        # here, Mosaic on a TPU).
+        monkeypatch.setenv("HYPERSPACE_TPU_PALLAS", pallas)
 
         rng = np.random.default_rng(n)
         mesh = build_mesh(8)
@@ -511,18 +516,15 @@ class TestExecutorMeshDispatch:
 # ---------------------------------------------------------------------------
 def _run_dryrun(code: str, extra_env=None) -> subprocess.CompletedProcess:
     env = dict(os.environ)
-    # Simulate the driver: no pytest conftest, no pre-set virtual mesh.
+    # Simulate the driver: no pytest conftest, no pre-set virtual mesh;
+    # the child is pinned to the CPU so it never contends for a chip.
     env.pop("XLA_FLAGS", None)
-    env.pop("JAX_PLATFORMS", None)
     env.pop("HS_DEVICE_BATCH_ROWS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     env.update(extra_env or {})
-    try:
-        return subprocess.run(
-            [sys.executable, "-c", code], cwd=REPO, env=env,
-            capture_output=True, text=True, timeout=600)
-    except subprocess.TimeoutExpired:
-        pytest.skip("default jax backend unreachable on this host "
-                    "(subprocess hung initializing devices)")
+    return subprocess.run(
+        [sys.executable, "-c", code], cwd=REPO, env=env,
+        capture_output=True, text=True, timeout=600)
 
 
 def test_dryrun_multichip_fresh_process():
@@ -531,11 +533,8 @@ def test_dryrun_multichip_fresh_process():
 
 
 def test_dryrun_multichip_after_backend_init():
-    # entry() may have initialized the default backend first; the dryrun
-    # must still provision the 8-device CPU mesh.
-    from tests.test_graft_entry import _skip_unless_default_backend
-
-    _skip_unless_default_backend()
+    # entry() may have initialized the backend first; the dryrun must
+    # still provision the 8-device CPU mesh.
     r = _run_dryrun(
         "import jax\n"
         "import __graft_entry__ as g\n"
