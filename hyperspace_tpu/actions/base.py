@@ -233,8 +233,8 @@ class Action:
                 raise
 
     def _finish_report(self, outcome: str, error: str, sp) -> None:
-        """Finalize + publish this run's BuildReport; export metrics,
-        synthesize phase spans, and append the perf-ledger record.
+        """Finalize + publish this run's BuildReport; export metrics, tag
+        the action span, and append the perf-ledger record.
         Diagnostics must never fail the action — everything here is
         best-effort."""
         from hyperspace_tpu.telemetry import build_report as br
@@ -273,18 +273,16 @@ class Action:
         (``"ok"``/``"noop"``) for the build report.  The ``validate`` and
         ``commit`` phases are timed here so a report's phase sum accounts
         for the whole protocol, not just op()'s build work."""
-        t0 = time.perf_counter()
+        report = self.build_report
         try:
-            self.validate()
+            with report.phase("validate"):
+                self.validate()
         except NoChangesError as e:
             emit(States.ACTIVE, f"No-op: {e}")
             return "noop"
-        finally:
-            self.build_report.add_phase("validate", time.perf_counter() - t0)
         try:
-            t0 = time.perf_counter()
-            self.begin()
-            self.build_report.add_phase("commit", time.perf_counter() - t0)
+            with report.phase("commit"):
+                self.begin()
             self.op()
             # Crash checkpoint (io/faults.py): the work is done but the
             # final entry is not committed — the state a killed process
@@ -292,9 +290,8 @@ class Action:
             # InjectedCrash is a BaseException, so the handlers below
             # (like a real kill -9) never see it.
             faults.check("action.commit")
-            t0 = time.perf_counter()
-            self.end()
-            self.build_report.add_phase("commit", time.perf_counter() - t0)
+            with report.phase("commit"):
+                self.end()
             emit(self.final_state)
             return "ok"
         except ConcurrentWriteError:

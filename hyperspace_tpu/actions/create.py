@@ -18,6 +18,7 @@ actions/CreateActionBase.scala:56-222 —
 
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 from typing import Dict, List, Optional
@@ -46,6 +47,7 @@ from hyperspace_tpu.io import columnar
 from hyperspace_tpu.io.parquet import read_table, write_bucketed
 from hyperspace_tpu.plan.nodes import LogicalPlan
 from hyperspace_tpu.telemetry.events import CreateActionEvent
+from hyperspace_tpu.telemetry.trace import span
 from hyperspace_tpu.utils.resolver import resolve_or_raise
 
 DATA_FILE_ID_COLUMN = "_data_file_id"  # IndexConstants.scala lineage column
@@ -135,21 +137,21 @@ class _PrefetchReader:
         self._pending: List = []
 
     def _record_stall(self, seconds: float) -> None:
-        """Attribute consumer stall (the ``prefetch`` phase/lane) — but
-        only once the build is known to SPILL.  On a monolithic build
-        the consumer has nothing to overlap, so its wait and the reader
-        thread's ``read`` cover the same wall time; counting both would
-        break the phase-sum-within-10%-of-wall audit.  Pre-spill stalls
-        buffer and flush with the first post-spill one."""
+        """Count consumer stall in the ``prefetch`` phase — but only once
+        the build is known to SPILL.  On a monolithic build the consumer
+        has nothing to overlap, so its wait and the reader thread's
+        ``read`` cover the same wall time; counting both would break the
+        phase-sum-within-10%-of-wall audit.  Pre-spill stalls buffer and
+        are counted with the first post-spill one (each wait has its own
+        span and timeline interval either way)."""
         if self.spill is None or not self.spill.spilled:
             self._stall_buffer_s += seconds
             return
-        self.action._phase("prefetch_s", self._stall_buffer_s + seconds)
+        self.action.build_report.add_seconds(
+            "prefetch", self._stall_buffer_s + seconds)
         self._stall_buffer_s = 0.0
 
     def __iter__(self):
-        import time as _time
-
         from hyperspace_tpu.utils import deadline
 
         if self.depth == 0:
@@ -177,9 +179,10 @@ class _PrefetchReader:
                 # Stall attribution: time the CONSUMER spends waiting on
                 # decode is the pipeline bubble prefetch exists to close
                 # (the ``prefetch`` phase/lane; near zero when it wins).
-                t0 = _time.perf_counter()
-                t = fut.result()
-                self._record_stall(_time.perf_counter() - t0)
+                with self.action.build_report.phase(
+                        "prefetch", count=False) as wait:
+                    t = fut.result()
+                self._record_stall(wait.seconds)
                 if queue:
                     self._pending.append(self._pool.submit(
                         self.action._read_chunk, queue.pop(0),
@@ -215,31 +218,17 @@ class CreateActionBase(Action):
         self._written_version: Optional[int] = None
         self._file_id_tracker = FileIdTracker()
         self._relation_cache = None
-        # Per-phase wall-clock of this build (read / kernel / write /
-        # sketch, seconds) — appended to session.build_stats_log on
-        # completion so bench.py can attribute build time (the round-2
-        # regression was unattributable without this).  Concurrent spill
-        # route workers update it, hence the lock (note: summed seconds
-        # are CPU-attributed time and can exceed wall-clock once routing
-        # overlaps reads).
-        self.build_phases: Dict[str, float] = {}
-        self._phase_lock = threading.Lock()
-
-    def _phase(self, name: str, seconds: float) -> None:
-        with self._phase_lock:
-            self.build_phases[name] = \
-                self.build_phases.get(name, 0.0) + seconds
-        # The structured report keeps the same numbers under bare phase
-        # names (telemetry/build_report.py; locked internally).
-        self.build_report.add_phase(name, seconds)
 
     def _publish_build_stats(self) -> None:
+        """This build's phase seconds so far, as ``<phase>_s`` keys, on
+        session.build_stats_log (bench.py attributes build time by it)."""
         log = getattr(self.session, "build_stats_log", None)
         if log is None:
             log = []
             self.session.build_stats_log = log
+        phases = dict(self.build_report.phases)
         log.append({"index": self.index_name,
-                    **{k: round(v, 4) for k, v in self.build_phases.items()}})
+                    **{f"{k}_s": round(v, 4) for k, v in phases.items()}})
 
     @property
     def conf(self) -> HyperspaceConf:
@@ -300,44 +289,41 @@ class CreateActionBase(Action):
         per-bucket run files, and each bucket is then sorted independently —
         peak memory is bounded by max(batch, largest bucket), not the
         dataset."""
-        import time as _time
-
         from hyperspace_tpu.io import integrity
 
         # Build planning: conf application, source listing, column
         # resolution, and the backend probe (_use_distributed_build's
         # first jax.devices() call initializes the backend — a one-off
         # cost that must not hide between phases).
-        _t0 = _time.perf_counter()
-        # Spill dirs a SIGKILLed prior process leaked are reaped here —
-        # the one moment a build provably needs the temp space back.
-        reap_orphan_spill_dirs()
-        # Digest-on-write follows THIS session's conf (the recorder is
-        # process-global, like the fault injector).
-        integrity.configure_from_conf(self.conf)
-        relation = self._relation()
-        resolved = self._resolved_config()
-        lineage = self.lineage_enabled
-        files = relation.all_files(self._file_id_tracker)
-        if file_names is not None:
-            wanted = set(file_names)
-            files = [f for f in files if f.name in wanted]
-        if not files:
-            raise HyperspaceError("No source data files to index")
+        with self.build_report.phase("plan"):
+            # Spill dirs a SIGKILLed prior process leaked are reaped here —
+            # the one moment a build provably needs the temp space back.
+            reap_orphan_spill_dirs()
+            # Digest-on-write follows THIS session's conf (the recorder is
+            # process-global, like the fault injector).
+            integrity.configure_from_conf(self.conf)
+            relation = self._relation()
+            resolved = self._resolved_config()
+            lineage = self.lineage_enabled
+            files = relation.all_files(self._file_id_tracker)
+            if file_names is not None:
+                wanted = set(file_names)
+                files = [f for f in files if f.name in wanted]
+            if not files:
+                raise HyperspaceError("No source data files to index")
 
-        columns = resolved.all_columns
-        batch_rows = max(1, int(self.conf.device_batch_rows))
-        # Datasets beyond one batch stream through the spill builder —
-        # whose per-chunk route shards over the mesh when one is active
-        # (bounded memory AND horizontal scale; parallel/sharded_build).
-        # Only an EXPLICIT parallel_build="on" keeps the legacy
-        # monolithic all_to_all build, which holds the whole dataset in
-        # memory (bit-equal either way — layout never depends on the
-        # route).
-        streaming = not (
-            str(self.conf.parallel_build).lower() in ("on", "true")
-            and self._use_distributed_build())
-        self._phase("plan_s", _time.perf_counter() - _t0)
+            columns = resolved.all_columns
+            batch_rows = max(1, int(self.conf.device_batch_rows))
+            # Datasets beyond one batch stream through the spill build —
+            # whose per-chunk route shards over the mesh when one is active
+            # (bounded memory AND horizontal scale; parallel/sharded_build).
+            # Only an EXPLICIT parallel_build="on" keeps the legacy
+            # monolithic all_to_all build, which holds the whole dataset in
+            # memory (bit-equal either way — layout never depends on the
+            # route).
+            streaming = not (
+                str(self.conf.parallel_build).lower() in ("on", "true")
+                and self._use_distributed_build())
         from hyperspace_tpu.parallel import multihost_build
         if multihost_build.armed(self.conf):
             # Fault-tolerant multi-host build: N subprocess hosts route
@@ -379,13 +365,10 @@ class CreateActionBase(Action):
         type, like the monolithic concat's promotion) and, when enabled,
         the constant-per-file lineage column
         (CreateActionBase.scala:177-222 without the broadcast join)."""
-        import time as _time
-
-        t0 = _time.perf_counter()
-        t = read_table([f.name], relation.read_format, columns,
-                       relation.options,
-                       partition_roots=relation.root_paths)
-        self._phase("read_s", _time.perf_counter() - t0)
+        with self.build_report.phase("read"):
+            t = read_table([f.name], relation.read_format, columns,
+                           relation.options,
+                           partition_roots=relation.root_paths)
         self.build_report.add_bytes(read=t.nbytes)
         missing = [col_name for col_name in columns
                    if col_name not in t.column_names]
@@ -476,7 +459,6 @@ class CreateActionBase(Action):
         collapsed at scale (measured 50/108 files kept at SF1 for a 5%
         range vs ~1/8 expected)."""
         import tempfile
-        import time as _time
 
         import pyarrow.parquet as pq
 
@@ -527,26 +509,27 @@ class CreateActionBase(Action):
             # (identical layout, no run files).
             build_monolithic()
             return
-        t0 = _time.perf_counter()
-        per_col_words = []
-        for i in range(len(key_cols)):
-            if value_mapped[i]:
-                per_col_words.append(np.concatenate(word_parts[i], axis=0))
-            else:
-                per_col_words.append(np.asarray(_columnar.to_order_words(
-                    pa.chunked_array(word_parts[i]))))
-        codes, bits = zorder_codes_from_order_words(per_col_words)
-        del word_parts, per_col_words
-        order = np.argsort(codes, kind="stable")
-        chunks = zorder_split_chunks(codes[order], bits,
-                                     self.conf.index_max_rows_per_file)
-        file_of_sorted = np.empty(n, np.int32)
-        for i, (off, rows) in enumerate(chunks):
-            file_of_sorted[off:off + rows] = i
-        file_of_row = np.empty(n, np.int32)
-        file_of_row[order] = file_of_sorted
-        del order, file_of_sorted
-        self._phase("kernel_s", _time.perf_counter() - t0)
+        with self.build_report.phase("kernel"):
+            per_col_words = []
+            for i in range(len(key_cols)):
+                if value_mapped[i]:
+                    per_col_words.append(
+                        np.concatenate(word_parts[i], axis=0))
+                else:
+                    per_col_words.append(np.asarray(
+                        _columnar.to_order_words(
+                            pa.chunked_array(word_parts[i]))))
+            codes, bits = zorder_codes_from_order_words(per_col_words)
+            del word_parts, per_col_words
+            order = np.argsort(codes, kind="stable")
+            chunks = zorder_split_chunks(codes[order], bits,
+                                         self.conf.index_max_rows_per_file)
+            file_of_sorted = np.empty(n, np.int32)
+            for i, (off, rows) in enumerate(chunks):
+                file_of_sorted[off:off + rows] = i
+            file_of_row = np.empty(n, np.int32)
+            file_of_row[order] = file_of_sorted
+            del order, file_of_sorted
 
         # -- pass B: route full rows to per-output-file runs --------------
         # The routing code rides along as a temp column whose name cannot
@@ -563,69 +546,69 @@ class CreateActionBase(Action):
                 t = self._read_chunk(f, columns, relation, lineage)
                 if schema is None:
                     schema = t.schema
-                t0 = _time.perf_counter()
-                rows = t.num_rows
-                if offset + rows > n:
-                    raise HyperspaceError(
-                        "Source grew between Z-order build passes; retry")
-                fids = file_of_row[offset:offset + rows]
-                t = t.append_column(
-                    z_col, pa.array(codes[offset:offset + rows]))
-                offset += rows
-                o = np.argsort(fids, kind="stable")
-                sf = fids[o]
-                routed = t.take(pa.array(o))
-                uniq = np.unique(sf)
-                starts = np.searchsorted(sf, uniq, "left")
-                ends = np.searchsorted(sf, uniq, "right")
-                for fid, st, en in zip(uniq, starts, ends):
-                    d = os.path.join(run_dir, f"file={int(fid):06d}")
-                    os.makedirs(d, exist_ok=True)
-                    self.build_report.add_bytes(spill=_write_run(
-                        routed.slice(int(st), int(en - st)),
-                        os.path.join(d, f"run-{chunk_no:05d}.arrow")),
-                        spill_runs=1)
-                self._phase("spill_route_s", _time.perf_counter() - t0)
+                with self.build_report.phase("spill_route"):
+                    rows = t.num_rows
+                    if offset + rows > n:
+                        raise HyperspaceError(
+                            "Source grew between Z-order build passes; retry")
+                    fids = file_of_row[offset:offset + rows]
+                    t = t.append_column(
+                        z_col, pa.array(codes[offset:offset + rows]))
+                    offset += rows
+                    o = np.argsort(fids, kind="stable")
+                    sf = fids[o]
+                    routed = t.take(pa.array(o))
+                    uniq = np.unique(sf)
+                    starts = np.searchsorted(sf, uniq, "left")
+                    ends = np.searchsorted(sf, uniq, "right")
+                    for fid, st, en in zip(uniq, starts, ends):
+                        d = os.path.join(run_dir, f"file={int(fid):06d}")
+                        os.makedirs(d, exist_ok=True)
+                        self.build_report.add_bytes(spill=_write_run(
+                            routed.slice(int(st), int(en - st)),
+                            os.path.join(d, f"run-{chunk_no:05d}.arrow")),
+                            spill_runs=1)
             if offset != n:
                 raise HyperspaceError(
                     "Source shrank between Z-order build passes; retry")
 
-            t0 = _time.perf_counter()
-            version = self.data_manager.get_next_version()
-            out_dir = self.data_manager.version_path(version)
-            os.makedirs(out_dir, exist_ok=True)
+            with self.build_report.phase("spill_finish"):
+                version = self.data_manager.get_next_version()
+                out_dir = self.data_manager.version_path(version)
+                os.makedirs(out_dir, exist_ok=True)
 
-            def finish_file(dname: str) -> None:
-                d = os.path.join(run_dir, dname)
-                runs = sorted(os.listdir(d))  # chunk order = stable ties
-                bt = pa.concat_tables(
-                    [_read_run(os.path.join(d, r)) for r in runs],
-                    promote_options="default")
-                z = np.asarray(bt.column(z_col).to_numpy(
-                    zero_copy_only=False))
-                perm = np.argsort(z, kind="stable")
-                bt = bt.take(pa.array(perm)).drop_columns([z_col])
-                # One output file per pass-A chunk (already cell-aligned
-                # and capped), written as bucket 0 — the logical index has
-                # one bucket.
-                written = write_bucket_run(
-                    bt, 0, out_dir, 0,
-                    compression=self.conf.index_file_compression)
-                self.build_report.add_bytes(
-                    written=sum(os.path.getsize(p) for p in written),
-                    files=len(written))
-                remove_tree(d, ignore_errors=True)  # runs consumed
+                def finish_file(dname: str) -> None:
+                    d = os.path.join(run_dir, dname)
+                    runs = sorted(os.listdir(d))  # chunk order = ties
+                    bt = pa.concat_tables(
+                        [_read_run(os.path.join(d, r)) for r in runs],
+                        promote_options="default")
+                    z = np.asarray(bt.column(z_col).to_numpy(
+                        zero_copy_only=False))
+                    perm = np.argsort(z, kind="stable")
+                    bt = bt.take(pa.array(perm)).drop_columns([z_col])
+                    # One output file per pass-A chunk (already
+                    # cell-aligned and capped), written as bucket 0 — the
+                    # logical index has one bucket.
+                    written = write_bucket_run(
+                        bt, 0, out_dir, 0,
+                        compression=self.conf.index_file_compression)
+                    self.build_report.add_bytes(
+                        written=sum(os.path.getsize(p) for p in written),
+                        files=len(written))
+                    remove_tree(d, ignore_errors=True)  # runs consumed
 
-            from hyperspace_tpu.utils.parallel_map import parallel_map_ordered
+                from hyperspace_tpu.utils.parallel_map import (
+                    parallel_map_ordered,
+                )
 
-            parallel_map_ordered(finish_file, sorted(os.listdir(run_dir)),
-                                 max_workers=4)
-            self._phase("spill_finish_s", _time.perf_counter() - t0)
+                parallel_map_ordered(finish_file,
+                                     sorted(os.listdir(run_dir)),
+                                     max_workers=4)
         finally:
             remove_tree(run_dir, ignore_errors=True)
-        t0 = _time.perf_counter()
-        self._write_index_file_sketch(out_dir, resolved)
-        self._phase("sketch_s", _time.perf_counter() - t0)
+        with self.build_report.phase("sketch"):
+            self._write_index_file_sketch(out_dir, resolved)
         self._written_version = version
         self._index_schema = {name: str(t) for name, t in
                               zip(schema.names, schema.types)}
@@ -655,67 +638,62 @@ class CreateActionBase(Action):
         # hash shuffle would fragment the curve into per-partition samples,
         # gutting the pruning — so every build mode takes this path and
         # produces the identical, environment-independent layout.
-        import time as _time
+        with self.build_report.phase("kernel"):
+            split_keys, split_bits = (None, 0)
+            if resolved.layout == "zorder":
+                from hyperspace_tpu.io.parquet import zorder_codes_host
 
-        t0 = _time.perf_counter()
-        split_keys, split_bits = (None, 0)
-        if resolved.layout == "zorder":
-            from hyperspace_tpu.io.parquet import zorder_codes_host
+                split_keys, split_bits = zorder_codes_host(
+                    table, resolved.indexed_columns)
+                perm = np.argsort(split_keys, kind="stable")
+                buckets = np.zeros(table.num_rows, dtype=np.int32)
+            elif self._use_distributed_build():
+                from hyperspace_tpu.parallel import (
+                    build_mesh,
+                    distributed_bucket_sort_permutation,
+                )
 
-            split_keys, split_bits = zorder_codes_host(
-                table, resolved.indexed_columns)
-            perm = np.argsort(split_keys, kind="stable")
-            buckets = np.zeros(table.num_rows, dtype=np.int32)
-        elif self._use_distributed_build():
-            from hyperspace_tpu.parallel import (
-                build_mesh,
-                distributed_bucket_sort_permutation,
-            )
-
-            buckets, perm = distributed_bucket_sort_permutation(
-                table, resolved.indexed_columns, self.num_buckets,
-                build_mesh(), slack=self.conf.shuffle_capacity_slack,
-                pad_to=self.conf.device_batch_rows)
-        else:
-            from hyperspace_tpu.ops.sort import (
-                bucket_sort_permutation,
-                bucket_sort_permutation_np,
-            )
-
-            word_cols = [columnar.to_hash_words(table.column(c))
-                         for c in resolved.indexed_columns]
-            order_words = [
-                np.asarray(columnar.to_order_words(table.column(c)))
-                for c in resolved.indexed_columns]
-            if table.num_rows < self.conf.device_min_rows("build"):
-                # Host mirror below the threshold — identical layout, no
-                # device transfer/compile latency (see config).
-                buckets, perm = bucket_sort_permutation_np(
-                    [np.asarray(w) for w in word_cols], order_words,
-                    self.num_buckets)
-            else:
-                buckets, perm = bucket_sort_permutation(
-                    [np.asarray(w) for w in word_cols],
-                    order_words,
-                    self.num_buckets,
+                buckets, perm = distributed_bucket_sort_permutation(
+                    table, resolved.indexed_columns, self.num_buckets,
+                    build_mesh(), slack=self.conf.shuffle_capacity_slack,
                     pad_to=self.conf.device_batch_rows)
-        self._phase("kernel_s", _time.perf_counter() - t0)
+            else:
+                from hyperspace_tpu.ops.sort import (
+                    bucket_sort_permutation,
+                    bucket_sort_permutation_np,
+                )
+
+                word_cols = [columnar.to_hash_words(table.column(c))
+                             for c in resolved.indexed_columns]
+                order_words = [
+                    np.asarray(columnar.to_order_words(table.column(c)))
+                    for c in resolved.indexed_columns]
+                if table.num_rows < self.conf.device_min_rows("build"):
+                    # Host mirror below the threshold — identical layout, no
+                    # device transfer/compile latency (see config).
+                    buckets, perm = bucket_sort_permutation_np(
+                        [np.asarray(w) for w in word_cols], order_words,
+                        self.num_buckets)
+                else:
+                    buckets, perm = bucket_sort_permutation(
+                        [np.asarray(w) for w in word_cols],
+                        order_words,
+                        self.num_buckets,
+                        pad_to=self.conf.device_batch_rows)
         version = self.data_manager.get_next_version() if version is None else version
         out_dir = self.data_manager.version_path(version)
-        t0 = _time.perf_counter()
-        written = write_bucketed(
-            table, np.asarray(buckets), np.asarray(perm),
-            self.num_buckets, out_dir,
-            max_rows_per_file=self.conf.index_max_rows_per_file,
-            split_keys=split_keys, split_key_bits=split_bits,
-            compression=self.conf.index_file_compression)
-        self._phase("write_s", _time.perf_counter() - t0)
+        with self.build_report.phase("write"):
+            written = write_bucketed(
+                table, np.asarray(buckets), np.asarray(perm),
+                self.num_buckets, out_dir,
+                max_rows_per_file=self.conf.index_max_rows_per_file,
+                split_keys=split_keys, split_key_bits=split_bits,
+                compression=self.conf.index_file_compression)
         self.build_report.add_bytes(
             written=sum(os.path.getsize(p) for p in written),
             files=len(written))
-        t0 = _time.perf_counter()
-        self._write_index_file_sketch(out_dir, resolved)
-        self._phase("sketch_s", _time.perf_counter() - t0)
+        with self.build_report.phase("sketch"):
+            self._write_index_file_sketch(out_dir, resolved)
         self._written_version = version
         self._index_schema = {name: str(t) for name, t in
                               zip(table.column_names, table.schema.types)}
@@ -998,8 +976,11 @@ class _BucketSpill:
         if pool is None:
             self._route_chunk(table, chunk_no)
             return
-        while len(self._futures) >= self._MAX_IN_FLIGHT:
-            self._futures.pop(0).result()
+        if len(self._futures) >= self._MAX_IN_FLIGHT:
+            # Backpressure: the consumer waits on the oldest route job.
+            with span("build.route_wait"):
+                while len(self._futures) >= self._MAX_IN_FLIGHT:
+                    self._futures.pop(0).result()
         with self._close_lock:
             self._routes_pending += 1
         self._futures.append(
@@ -1045,74 +1026,80 @@ class _BucketSpill:
             route_partition_np,
         )
 
-        _t0 = _time.perf_counter()
-        n = table.num_rows
-        # Z-order builds never spill here (they take the dedicated
-        # two-pass path that preserves the global curve), so partitions
-        # are always real index buckets.
-        num_buckets = self._num_buckets
-        key_cols = list(self.resolved.indexed_columns)
-        word_cols = [np.asarray(columnar.to_hash_words(table.column(c)))
-                     for c in key_cols]
-        # Value-mapped keys: monotone sort codes come along, so the ONE
-        # fused pass both buckets the rows and sorts them within bucket
-        # — and the writer's sort codes are THIS pass's byproduct riding
-        # the runs as temp uint64 columns, not a finalize-time recompute
-        # over every row.  The host mirror keys on the uint64 codes
-        # directly; only the device kernel needs the 32-bit word split.
-        codes64 = [columnar.to_order_codes64(table.column(c))
-                   for c in key_cols] if self._code_cols else []
-        if n < self.action.conf.device_min_rows("build"):
-            # Host mirror below the threshold, same cost model as the
-            # monolithic build: a per-chunk device round trip (transfer
-            # + possible compile, per chunk!) can exceed a host pass —
-            # and the mirror is bit-identical, so
-            # layout cannot depend on the route.
-            buckets, perm = route_partition_np(word_cols, codes64,
-                                               num_buckets)
-        elif (mesh := self._active_mesh()) is not None:
-            # Sharded route: rows data-parallel over the mesh, each
-            # device owning buckets ``b % n_devices``, per-device runs
-            # gathered through the attributed host seam (one pull per
-            # device per chunk) — bit-identical layout, proven by
-            # tests/test_parallel_mesh.py's per-bucket digests.
-            devices = list(mesh.devices.flat)
-            buckets, perm = route_partition_mesh(
-                word_cols,
-                [columnar.split_words64(k) for k in codes64],
-                num_buckets, mesh,
-                pad_to=max(1, int(self.action.conf.device_batch_rows)))
-            ms = (_time.perf_counter() - _t0) * 1000.0
-            report = self.action.build_report
-            report.properties["mesh_devices"] = len(devices)
-            for dev in devices:
-                report.add_device_kernel_ms(
-                    int(getattr(dev, "id", -1)), ms)
-        else:
-            buckets, perm = route_partition(
-                word_cols,
-                [columnar.split_words64(k) for k in codes64],
-                num_buckets,
-                pad_to=max(1, int(self.action.conf.device_batch_rows)))
-            import jax
+        with self.action.build_report.phase("spill_route"):
+            _t0 = _time.perf_counter()  # the device-kernel attribution
+            n = table.num_rows
+            # Z-order builds never spill here (they take the dedicated
+            # two-pass path that preserves the global curve), so
+            # partitions are always real index buckets.
+            num_buckets = self._num_buckets
+            key_cols = list(self.resolved.indexed_columns)
+            word_cols = [
+                np.asarray(columnar.to_hash_words(table.column(c)))
+                for c in key_cols]
+            # Value-mapped keys: monotone sort codes come along, so the
+            # ONE fused pass both buckets the rows and sorts them within
+            # bucket — and the writer's sort codes are THIS pass's
+            # byproduct riding the runs as temp uint64 columns, not a
+            # finalize-time recompute over every row.  The host mirror
+            # keys on the uint64 codes directly; only the device kernel
+            # needs the 32-bit word split.
+            codes64 = [columnar.to_order_codes64(table.column(c))
+                       for c in key_cols] if self._code_cols else []
+            if n < self.action.conf.device_min_rows("build"):
+                # Host mirror below the threshold, same cost model as the
+                # monolithic build: a per-chunk device round trip
+                # (transfer + possible compile, per chunk!) can exceed a
+                # host pass — and the mirror is bit-identical, so layout
+                # cannot depend on the route.
+                buckets, perm = route_partition_np(word_cols, codes64,
+                                                   num_buckets)
+            elif (mesh := self._active_mesh()) is not None:
+                # Sharded route: rows data-parallel over the mesh, each
+                # device owning buckets ``b % n_devices``, per-device
+                # runs gathered through the attributed host seam (one
+                # pull per device per chunk) — bit-identical layout,
+                # proven by tests/test_parallel_mesh.py's per-bucket
+                # digests.
+                devices = list(mesh.devices.flat)
+                buckets, perm = route_partition_mesh(
+                    word_cols,
+                    [columnar.split_words64(k) for k in codes64],
+                    num_buckets, mesh,
+                    pad_to=max(1,
+                               int(self.action.conf.device_batch_rows)))
+                ms = (_time.perf_counter() - _t0) * 1000.0
+                report = self.action.build_report
+                report.properties["mesh_devices"] = len(devices)
+                for dev in devices:
+                    report.add_device_kernel_ms(
+                        int(getattr(dev, "id", -1)), ms)
+            else:
+                buckets, perm = route_partition(
+                    word_cols,
+                    [columnar.split_words64(k) for k in codes64],
+                    num_buckets,
+                    pad_to=max(1,
+                               int(self.action.conf.device_batch_rows)))
+                import jax
 
-            # Same attribution as the sharded route, on the one device.
-            self.action.build_report.add_device_kernel_ms(
-                int(jax.devices()[0].id),
-                (_time.perf_counter() - _t0) * 1000.0)
-        buckets = np.asarray(buckets)
-        perm = np.asarray(perm)
-        sorted_buckets = buckets[perm]
-        routed = table.take(pa.array(perm))
-        for i, name in enumerate(self._code_cols):
-            routed = routed.append_column(name,
-                                          pa.array(codes64[i][perm]))
-        starts = np.searchsorted(sorted_buckets, np.arange(num_buckets),
-                                 "left")
-        ends = np.searchsorted(sorted_buckets, np.arange(num_buckets),
-                               "right")
-        self._write_chunk_runs(routed, chunk_no, starts, ends)
-        self.action._phase("spill_route_s", _time.perf_counter() - _t0)
+                # Same attribution as the sharded route, on the one
+                # device.
+                self.action.build_report.add_device_kernel_ms(
+                    int(jax.devices()[0].id),
+                    (_time.perf_counter() - _t0) * 1000.0)
+            buckets = np.asarray(buckets)
+            perm = np.asarray(perm)
+            sorted_buckets = buckets[perm]
+            routed = table.take(pa.array(perm))
+            for i, name in enumerate(self._code_cols):
+                routed = routed.append_column(name,
+                                              pa.array(codes64[i][perm]))
+            starts = np.searchsorted(sorted_buckets,
+                                     np.arange(num_buckets), "left")
+            ends = np.searchsorted(sorted_buckets, np.arange(num_buckets),
+                                   "right")
+            self._write_chunk_runs(routed, chunk_no, starts, ends)
 
     def _write_chunk_runs(self, routed: pa.Table, chunk_no: int,
                           starts, ends) -> None:
@@ -1182,60 +1169,55 @@ class _BucketSpill:
         returned while OTHER groups still hold theirs, so peak disk is
         source + runs + in-flight groups, not source + runs + the whole
         final index."""
-        import time as _time
-
         from hyperspace_tpu.io.files import remove_file
         from hyperspace_tpu.io.parquet import (
             sort_permutation_from_codes,
             write_bucket_run,
         )
 
-        _t0 = _time.perf_counter()
         action = self.action
-        max_rows = action.conf.index_max_rows_per_file
-        b0, b1 = self._bounds[gid], self._bounds[gid + 1]
-        with self._manifest_lock:
-            paths = list(self._group_files.get(gid, ()))
-            buckets = sorted(b for b in self._runs if b0 <= b < b1)
-        readers = {}
-        handles = []
-        try:
+        with action.build_report.phase("spill_finish"):
+            max_rows = action.conf.index_max_rows_per_file
+            b0, b1 = self._bounds[gid], self._bounds[gid + 1]
+            with self._manifest_lock:
+                paths = list(self._group_files.get(gid, ()))
+                buckets = sorted(b for b in self._runs if b0 <= b < b1)
+            readers = {}
+            handles = []
+            try:
+                for p in paths:
+                    mm = pa.memory_map(p, "rb")
+                    handles.append(mm)
+                    readers[p] = pa.ipc.open_file(mm)
+                for b in buckets:
+                    with self._manifest_lock:
+                        runs = sorted(self._runs[b])  # chunk order = ties
+                    batches = [readers[p].get_batch(bi) for _, p, bi in runs]
+                    btable = pa.Table.from_batches(batches)
+                    if self._code_cols:
+                        perm = sort_permutation_from_codes(btable,
+                                                           self._code_cols)
+                        btable = btable.take(pa.array(perm)).drop_columns(
+                            list(self._code_cols))
+                    else:
+                        perm = self._sort_permutation(btable)
+                        btable = btable.take(pa.array(perm))
+                    written = write_bucket_run(
+                        btable, b, self._out_dir, max_rows,
+                        compression=action.conf.index_file_compression)
+                    action.build_report.add_bytes(
+                        written=sum(os.path.getsize(p) for p in written),
+                        files=len(written))
+            finally:
+                for mm in handles:
+                    try:
+                        mm.close()
+                    except OSError:
+                        pass
             for p in paths:
-                mm = pa.memory_map(p, "rb")
-                handles.append(mm)
-                readers[p] = pa.ipc.open_file(mm)
-            for b in buckets:
-                with self._manifest_lock:
-                    runs = sorted(self._runs[b])  # chunk order = ties
-                batches = [readers[p].get_batch(bi) for _, p, bi in runs]
-                btable = pa.Table.from_batches(batches)
-                if self._code_cols:
-                    perm = sort_permutation_from_codes(btable,
-                                                       self._code_cols)
-                    btable = btable.take(pa.array(perm)).drop_columns(
-                        list(self._code_cols))
-                else:
-                    perm = self._sort_permutation(btable)
-                    btable = btable.take(pa.array(perm))
-                written = write_bucket_run(
-                    btable, b, self._out_dir, max_rows,
-                    compression=action.conf.index_file_compression)
-                action.build_report.add_bytes(
-                    written=sum(os.path.getsize(p) for p in written),
-                    files=len(written))
-        finally:
-            for mm in handles:
-                try:
-                    mm.close()
-                except OSError:
-                    pass
-        for p in paths:
-            remove_file(p, missing_ok=True)
-        action._phase("spill_finish_s", _time.perf_counter() - _t0)
+                remove_file(p, missing_ok=True)
 
     def finish(self) -> None:
-        import time as _time
-
         from hyperspace_tpu.io.files import remove_tree
 
         action = self.action
@@ -1256,7 +1238,8 @@ class _BucketSpill:
                 fire = True
         if fire:
             self._close_groups()
-        self._drain()  # re-raise the first route failure
+        with span("build.route_wait"):
+            self._drain()  # re-raise the first route failure
         if self._pool is not None:
             self._pool.shutdown(wait=True)
             self._pool = None
@@ -1265,20 +1248,16 @@ class _BucketSpill:
         # the streaming overlap is spent against (``finalize`` phase and
         # timeline lane; the per-group work itself lands in
         # ``spill_finish`` on the pool workers).
-        _t0 = _time.perf_counter()
-        try:
+        with action.build_report.phase("finalize") if self.pipelined \
+                else contextlib.nullcontext():
             self._drain_finalize()
-        finally:
-            if self.pipelined:
-                action._phase("finalize_s", _time.perf_counter() - _t0)
         if self._finalize_pool is not None:
             self._finalize_pool.shutdown(wait=True)
             self._finalize_pool = None
         remove_tree(self._dir, ignore_errors=True)
         self._dir = None
-        _t0 = _time.perf_counter()
-        action._write_index_file_sketch(out_dir, resolved)
-        action._phase("sketch_s", _time.perf_counter() - _t0)
+        with action.build_report.phase("sketch"):
+            action._write_index_file_sketch(out_dir, resolved)
         action._written_version = version
         action._index_schema = {name: str(t) for name, t in
                                 zip(self._schema.names, self._schema.types)}

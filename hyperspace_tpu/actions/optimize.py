@@ -135,8 +135,6 @@ class OptimizeAction(Action):
                 "a single file or files exceed the size threshold)")
 
     def op(self) -> None:
-        import time as _time
-
         from hyperspace_tpu.io import integrity
 
         integrity.configure_from_conf(self.session.conf)
@@ -151,41 +149,38 @@ class OptimizeAction(Action):
                                                       "lexicographic")
         report = self.build_report
         for bucket, files in sorted(mergeable.items()):
-            t0 = _time.perf_counter()
-            merged = pa.concat_tables(
-                [read_parquet_file(f.name) for f in files],
-                promote_options="default")
-            report.add_phase("read", _time.perf_counter() - t0)
+            with report.phase("read"):
+                merged = pa.concat_tables(
+                    [read_parquet_file(f.name) for f in files],
+                    promote_options="default")
             report.add_bytes(read=merged.nbytes)
             # Layout-aware: a Z-ordered index must stay Z-ordered through
             # compaction — Morton sort AND Z-cell-aligned file cuts — or its
             # per-file sketches go wide on every non-primary dimension.
-            t0 = _time.perf_counter()
             if layout == "zorder":
                 from hyperspace_tpu.io.parquet import write_zorder_run
 
-                new = write_zorder_run(merged, bucket, out_dir, max_rows,
-                                       sort_cols,
-                                       compression=self.session.conf
-                                       .index_file_compression)
+                with report.phase("write"):
+                    new = write_zorder_run(merged, bucket, out_dir,
+                                           max_rows, sort_cols,
+                                           compression=self.session.conf
+                                           .index_file_compression)
                 self._new_files.extend(new)
-                report.add_phase("write", _time.perf_counter() - t0)
                 report.add_bytes(
                     written=sum(os.stat(p).st_size for p in new),
                     files=len(new))
                 continue
-            perm = sort_permutation_host(merged, sort_cols, layout)
-            merged = merged.take(pa.array(perm))
-            report.add_phase("sort", _time.perf_counter() - t0)
+            with report.phase("sort"):
+                perm = sort_permutation_host(merged, sort_cols, layout)
+                merged = merged.take(pa.array(perm))
             # Honor the file-size knob: collapsing a bucket to ONE file
             # would destroy the per-file sketch pruning granularity the
             # split exists for.
-            t0 = _time.perf_counter()
-            new = write_bucket_run(merged, bucket, out_dir, max_rows,
-                                   compression=self.session.conf
-                                   .index_file_compression)
+            with report.phase("write"):
+                new = write_bucket_run(merged, bucket, out_dir, max_rows,
+                                       compression=self.session.conf
+                                       .index_file_compression)
             self._new_files.extend(new)
-            report.add_phase("write", _time.perf_counter() - t0)
             report.add_bytes(written=sum(os.stat(p).st_size for p in new),
                              files=len(new))
         # Per-file min/max sketch for the compacted version, like every
@@ -193,9 +188,8 @@ class OptimizeAction(Action):
         # optimized indexes.
         from hyperspace_tpu.actions.data_skipping import write_index_file_sketch
 
-        t0 = _time.perf_counter()
-        write_index_file_sketch(out_dir, sort_cols)
-        report.add_phase("sketch", _time.perf_counter() - t0)
+        with report.phase("sketch"):
+            write_index_file_sketch(out_dir, sort_cols)
 
     def log_entry(self) -> IndexLogEntry:
         from hyperspace_tpu.io import integrity

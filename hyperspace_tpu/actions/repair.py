@@ -145,25 +145,23 @@ class RepairAction(RefreshActionBase):
         new_files: List[str] = []
         starts = np.searchsorted(sorted_buckets, self._target_buckets, "left")
         ends = np.searchsorted(sorted_buckets, self._target_buckets, "right")
-        import time as _time
-
-        t0 = _time.perf_counter()
-        for b, lo, hi in zip(self._target_buckets, starts, ends):
-            rows = int(hi - lo)
-            if rows == 0:
-                continue
-            bt = routed.slice(int(lo), rows)
-            if layout == "zorder":
-                new_files.extend(write_zorder_run(
-                    bt, int(b), out_dir, max_rows,
-                    resolved.indexed_columns, compression=compression))
-            else:
-                perm = sort_permutation_host(bt, resolved.indexed_columns,
-                                             layout)
-                bt = bt.take(pa.array(perm))
-                new_files.extend(write_bucket_run(
-                    bt, int(b), out_dir, max_rows, compression=compression))
-        self._phase("write_s", _time.perf_counter() - t0)
+        with self.build_report.phase("write"):
+            for b, lo, hi in zip(self._target_buckets, starts, ends):
+                rows = int(hi - lo)
+                if rows == 0:
+                    continue
+                bt = routed.slice(int(lo), rows)
+                if layout == "zorder":
+                    new_files.extend(write_zorder_run(
+                        bt, int(b), out_dir, max_rows,
+                        resolved.indexed_columns, compression=compression))
+                else:
+                    perm = sort_permutation_host(
+                        bt, resolved.indexed_columns, layout)
+                    bt = bt.take(pa.array(perm))
+                    new_files.extend(write_bucket_run(
+                        bt, int(b), out_dir, max_rows,
+                        compression=compression))
         self.build_report.add_bytes(
             written=sum(os.stat(p).st_size for p in new_files),
             files=len(new_files))

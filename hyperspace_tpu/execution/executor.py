@@ -29,6 +29,7 @@ import pyarrow.compute as pc
 from hyperspace_tpu.execution import sync_guard
 from hyperspace_tpu.io import columnar
 from hyperspace_tpu.telemetry import timeline
+from hyperspace_tpu.telemetry.trace import span
 from hyperspace_tpu.utils import deadline as _deadline
 from jax import enable_x64 as _enable_x64
 from hyperspace_tpu.io.files import list_data_files
@@ -413,8 +414,6 @@ class Executor:
 
     # -- aggregate ----------------------------------------------------------
     def _aggregate(self, plan: Aggregate) -> pa.Table:
-        from hyperspace_tpu.telemetry.trace import span
-
         with span("exec.aggregate", groups=len(plan.group_by)) as sp:
             attempt = self._try_join_aggregate(plan)
             if attempt is not None:
@@ -1090,8 +1089,6 @@ class Executor:
 
     # -- scan ---------------------------------------------------------------
     def _scan(self, plan: Scan, columns: Optional[List[str]] = None) -> pa.Table:
-        from hyperspace_tpu.telemetry.trace import span
-
         with span("exec.scan") as sp:
             out = self._scan_inner(plan, columns, sp)
             sp.set(rows=out.num_rows)
@@ -1110,32 +1107,36 @@ class Executor:
                 f"Plan contains a hypothetical index scan "
                 f"({rel.index_scan_of!r}); what-if plans are for analysis "
                 f"only and can never execute (docs/17-advisor.md)")
-        read_format = physical_read_format(rel.file_format)
-        lake_relation = None
-        if rel.file_paths is not None:
-            paths = list(rel.file_paths)
-        elif rel.file_format.lower() in LAKE_DATA_FORMATS:
-            # Lake formats resolve files through the provider's snapshot —
-            # a directory walk would see removed/overwritten files too.
-            lake_relation = self.session.source_provider_manager.get_relation(plan)
-            paths = [f.name for f in lake_relation.all_files()]
-            read_format = lake_relation.read_format
-        else:
-            paths = [f.name for f in list_data_files(rel.root_paths)]
-        all_paths = paths
-        if rel.prune_to_buckets is not None:
-            wanted = set(rel.prune_to_buckets)
-            paths = [p for p in paths
-                     if (b := bucket_id_of_file(p)) is None or b in wanted]
-        # Bytes are measured by stat (the files are about to be read, so
-        # the inodes are hot); a vanished file surfaces in read_table with
-        # a better error than here.
-        bytes_read = 0
-        for p in paths:
-            try:
-                bytes_read += os.path.getsize(p)
-            except OSError:
-                pass
+        with span("scan.files"):
+            read_format = physical_read_format(rel.file_format)
+            lake_relation = None
+            if rel.file_paths is not None:
+                paths = list(rel.file_paths)
+            elif rel.file_format.lower() in LAKE_DATA_FORMATS:
+                # Lake formats resolve files through the provider's
+                # snapshot — a directory walk would see removed/
+                # overwritten files too.
+                lake_relation = self.session.source_provider_manager \
+                    .get_relation(plan)
+                paths = [f.name for f in lake_relation.all_files()]
+                read_format = lake_relation.read_format
+            else:
+                paths = [f.name for f in list_data_files(rel.root_paths)]
+            all_paths = paths
+            if rel.prune_to_buckets is not None:
+                wanted = set(rel.prune_to_buckets)
+                paths = [p for p in paths
+                         if (b := bucket_id_of_file(p)) is None
+                         or b in wanted]
+            # Bytes are measured by stat (the files are about to be read,
+            # so the inodes are hot); a vanished file surfaces in
+            # read_table with a better error than here.
+            bytes_read = 0
+            for p in paths:
+                try:
+                    bytes_read += os.path.getsize(p)
+                except OSError:
+                    pass
         scan_record = {
             "relation": rel.index_scan_of or ",".join(rel.root_paths),
             "is_index": bool(rel.index_scan_of),
@@ -1179,7 +1180,8 @@ class Executor:
         if columns:
             out = out.select(columns)
         scan_record["rows"] = out.num_rows
-        self._register_scan_identity(out, paths)
+        with span("scan.identity"):
+            self._register_scan_identity(out, paths)
         return out
 
     # -- filter -------------------------------------------------------------
@@ -1187,15 +1189,18 @@ class Executor:
         table = self.execute(plan.child)
         if table.num_rows == 0:
             return table
-        mask = self._eval_predicate(plan.condition, table)
-        out = table.filter(pa.array(mask))
-        # The filtered rows are a pure function of (scan files, predicate):
-        # give the output a derived identity so repeats of the same query
-        # can serve its columns from the HBM cache (the resident join's
-        # filtered sides depend on this).
-        self._register_derived_identity(
-            out, self._scan_identity(table),
-            f"filter:{plan.condition!r}")
+        # The span covers this operator's own work, not its child's.
+        with span("exec.filter", rows_in=table.num_rows) as sp:
+            mask = self._eval_predicate(plan.condition, table)
+            out = table.filter(pa.array(mask))
+            # The filtered rows are a pure function of (scan files,
+            # predicate): give the output a derived identity so repeats
+            # of the same query can serve its columns from the HBM cache
+            # (the resident join's filtered sides depend on this).
+            self._register_derived_identity(
+                out, self._scan_identity(table),
+                f"filter:{plan.condition!r}")
+            sp.set(rows=out.num_rows)
         return out
 
     def _eval_predicate(self, expr: Expr, table: pa.Table) -> np.ndarray:
@@ -1386,8 +1391,6 @@ class Executor:
 
     # -- join ---------------------------------------------------------------
     def _join(self, plan: Join, _record: bool = True) -> pa.Table:
-        from hyperspace_tpu.telemetry.trace import span
-
         with span("exec.join", how=plan.how) as sp:
             joins_mark = len(self.stats["joins"])
             bucketed = self._try_bucketed_join(plan)

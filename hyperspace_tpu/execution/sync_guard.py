@@ -14,8 +14,11 @@ Two halves:
     (0-d/dynamic-shape sync point) are the sanctioned device→host
     conversions.  Each runs inside an allowance window, counts
     ``guard.sync.attributed``, and feeds ``exec.transfer.d2h.bytes``
-    through the PR 11 timeline seam.  They are cheap pass-throughs for
-    host inputs and when the guard is off.
+    through the timeline seam.  The blocking conversion itself is
+    a ``device.sync`` span (tag ``site``): with the guard's seams the
+    only place the host waits on the device, it is where tracing sees
+    the wait.  They are cheap pass-throughs for host inputs and when the
+    guard is off.
   - **the guard** — when a collect runs with the conf key on,
     :func:`arm` patches the concrete jax array type's scalar-conversion
     surface (``item``/``tolist``/``__float__``/``__int__``/``__bool__``/
@@ -41,6 +44,7 @@ import threading
 from typing import Any, Iterator
 
 from hyperspace_tpu.exceptions import DeviceSyncError
+from hyperspace_tpu.telemetry.trace import span
 
 _armed = False
 _patched = False
@@ -92,7 +96,7 @@ def pull(x: Any, site: str = "") -> Any:
 
     if not _is_device(x):
         return np.asarray(x)
-    with allowed():
+    with allowed(), span("device.sync", site=site):
         out = np.asarray(x)
     _count_attributed(site)
     from hyperspace_tpu.telemetry import timeline
@@ -107,9 +111,9 @@ def scalar(x: Any, site: str = "") -> Any:
     Python number; host numbers pass through."""
     if not _is_device(x):
         return x
-    with allowed():
-        import numpy as np
+    import numpy as np
 
+    with allowed(), span("device.sync", site=site):
         out = np.asarray(x).item()
     _count_attributed(site)
     return out

@@ -751,8 +751,8 @@ def run_multihost_build(action, files, columns, relation, resolved,
             multihost_route_wall_s=round(route_wall, 4),
             multihost_finalize_wall_s=round(finalize_wall, 4),
             multihost_total_wall_s=round(total_wall, 4))
-        action._phase("mh_route_s", route_wall)
-        action._phase("mh_finalize_s", finalize_wall)
+        report.add_seconds("mh_route", route_wall)
+        report.add_seconds("mh_finalize", finalize_wall)
     finally:
         for p in procs:
             if p.poll() is None:

@@ -8,18 +8,21 @@ spill) with nothing but a flat seconds dict to show for it.  Every
 action run through ``actions/base.Action.run()`` owns one
 :class:`BuildReport`:
 
-  - **phases**: wall seconds per named phase (``read`` → ``spill_route``
+  - **phases**: seconds per named phase (``read`` → ``spill_route``
     → ``kernel`` → ``spill_finish`` → ``write`` → ``sketch``, plus the
     protocol's ``validate``/``commit`` and the pipelined builder's two
     STALL phases ``prefetch``/``finalize`` — consumer time blocked on
-    decode, and the exposed finalize tail after routing drains),
-    accumulated across conflict retries and across the prefetch/route/
-    finalize pools' worker threads (the report is lock-protected and
-    owned by the ACTION, not a contextvar — worker threads do not
-    inherit context; overlapped phases are CPU-attributed seconds and
-    may sum past wall clock on a pipelined spill build).  Phases are classified device vs
-    host (``kernel`` is device compute; everything else is host/IO) so
-    ``device_s``/``host_s`` fall out.
+    decode, and the exposed finalize tail after routing drains).  Each
+    is timed where its work runs by :meth:`BuildReport.phase`, which
+    also opens a ``build.phase.<name>`` span on that thread (tracing
+    on) and gives the timeline the same interval (timeline on).
+    Seconds accumulate across conflict retries and across the
+    prefetch/route/finalize pools' worker threads (the report is
+    lock-protected and owned by the ACTION, not a contextvar — worker
+    threads do not inherit context; overlapped phases are thread
+    seconds and may sum past wall clock on a pipelined spill build).
+    Phases are classified device vs host (``kernel`` is device compute;
+    everything else is host/IO) so ``device_s``/``host_s`` fall out.
   - **bytes**: decoded source bytes in (``bytes_read``), index data
     bytes out (``bytes_written``), and the external build's temporary
     spill-run bytes (``spill_bytes`` — the figure that must match what
@@ -31,16 +34,15 @@ action run through ``actions/base.Action.run()`` owns one
 Finish exports the report into the PR 4 metrics registry
 (``build.phase.<name>.seconds``, ``build.spill.bytes``,
 ``build.bytes.written``, ``build.actions``, ``build.peak_rss_mb``
-gauge), synthesizes ``build.phase.<name>`` child spans onto the live
-``action.*`` span (so a JSONL trace greps for phase attribution), and
-publishes the report as ``session.last_build_report_value`` /
-:func:`last_report` — surfaced by ``Hyperspace.last_build_report()``.
+gauge), tags the live ``action.*`` span with the totals, and publishes
+the report as ``session.last_build_report_value`` / :func:`last_report`
+— surfaced by ``Hyperspace.last_build_report()``.
 
 Cost contract: ``hyperspace.system.buildProfiling.enabled`` (default on)
-gates the memory sampling, metric export, span synthesis, and the perf
-ledger append; phase timing itself predates this module (the
-``build_stats_log`` seconds bench.py already records) and stays on.  The
-bench ``build_profile`` section gates the on-vs-off delta < 3%.
+gates the memory sampling, metric export, span tags, and the perf
+ledger append; phase timing itself stays on (``build_stats_log``, which
+bench.py records, is derived from it).  The bench ``build_profile``
+section gates the on-vs-off delta < 3%.
 """
 
 from __future__ import annotations
@@ -49,6 +51,8 @@ import threading
 import time
 from typing import Any, Dict, Optional
 
+from hyperspace_tpu.telemetry.trace import span
+
 # Phase → attribution class.  ``kernel`` is the device hash+sort pass
 # (or its bit-identical host mirror — still "compute", and the mirror
 # only runs when the cost model says the chip would lose); everything
@@ -56,10 +60,37 @@ from typing import Any, Dict, Optional
 _DEVICE_PHASES = frozenset({"kernel"})
 
 
-def _phase_key(name: str) -> str:
-    """Normalize legacy ``<phase>_s`` keys (build_stats_log) to bare
-    phase names."""
-    return name[:-2] if name.endswith("_s") else name
+_MAX_INTERVALS = 8192  # per report: a runaway phase loop stays bounded
+
+
+class _Phase:
+    """One timed phase on the calling thread (:meth:`BuildReport.phase`);
+    ``seconds`` holds its length once the block has closed."""
+
+    __slots__ = ("report", "name", "count", "seconds", "_span", "_t0_ns")
+
+    def __init__(self, report: "BuildReport", name: str, count: bool) -> None:
+        self.report = report
+        self.name = name
+        self.count = count
+        self.seconds = 0.0
+
+    def __enter__(self) -> "_Phase":
+        self._span = span(f"build.phase.{self.name}")
+        self._span.__enter__()
+        self._t0_ns = time.monotonic_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        # The span closes right after the clock read: its duration and
+        # the phase's seconds cover the same stretch.
+        end_ns = time.monotonic_ns()
+        self._span.__exit__(exc_type, exc, tb)
+        self.seconds = (end_ns - self._t0_ns) / 1e9
+        if self.count:
+            self.report.add_seconds(self.name, self.seconds)
+        self.report._record_interval(self.name, self._t0_ns, end_ns)
+        return False
 
 
 class BuildReport:
@@ -92,7 +123,7 @@ class BuildReport:
         # attribution can see per-device skew across builds.
         self.device_kernel_ms: Dict[int, float] = {}
         # Timeline intervals (telemetry/timeline.py, when enabled): one
-        # (lane, start_ns, end_ns) per add_phase call — lane = phase
+        # (lane, start_ns, end_ns) per timed phase — lane = phase
         # name — so the gap/overlap analysis can say "read idle while
         # spill_route busy", which summed seconds cannot.  Memory
         # samples are fed by the background sampler; per-phase
@@ -103,22 +134,31 @@ class BuildReport:
         self._t0 = time.perf_counter()
 
     # -- recording (thread-safe: spill route/finish pools call in) ----------
-    def add_phase(self, name: str, seconds: float) -> None:
-        from hyperspace_tpu.telemetry import timeline
+    def phase(self, name: str, count: bool = True) -> _Phase:
+        """Time the with-block as phase ``name`` on this thread: a
+        ``build.phase.<name>`` span (tracing on), the same interval on
+        the timeline (timeline on), and its seconds added to
+        ``phases``.  ``count=False`` keeps the span and interval but
+        leaves the seconds to the caller (``_PrefetchReader`` counts a
+        stall only once the build is known to spill)."""
+        return _Phase(self, name, count)
 
-        name = _phase_key(name)
+    def add_seconds(self, name: str, seconds: float) -> None:
+        """Add seconds timed elsewhere to phase ``name``, with no span:
+        a stall held back until it is known to count, or work another
+        process did (the multi-host build's claim records)."""
         with self._lock:
             self.phases[name] = self.phases.get(name, 0.0) + float(seconds)
-        if timeline.timeline_enabled():
-            # The caller timed [now - seconds, now]: reconstruct the
-            # interval without touching any call site.
-            end_ns = time.monotonic_ns()
-            start_ns = end_ns - int(float(seconds) * 1e9)
-            with self._lock:
-                if len(self.intervals) < 8192:  # a runaway phase loop
-                    self.intervals.append((name, start_ns, end_ns))
-            timeline.record_interval(name, "build.phase", start_ns,
-                                     end_ns)
+
+    def _record_interval(self, name: str, start_ns: int, end_ns: int) -> None:
+        from hyperspace_tpu.telemetry import timeline
+
+        if not timeline.timeline_enabled():
+            return
+        with self._lock:
+            if len(self.intervals) < _MAX_INTERVALS:
+                self.intervals.append((name, start_ns, end_ns))
+        timeline.record_interval(name, "build.phase", start_ns, end_ns)
 
     def add_device_kernel_ms(self, device_id: int, ms: float) -> None:
         """Attribute ``ms`` of kernel time to one jax device (mesh route
@@ -244,23 +284,12 @@ class BuildReport:
                               self.device_live_bytes)
 
     def attach_to_span(self, sp) -> None:
-        """Summarize onto the live ``action.*`` span and synthesize one
-        ``build.phase.<name>`` child per phase, so a JSONL trace carries
-        per-phase build attribution (the CI smoke grep's contract)."""
-        from hyperspace_tpu.telemetry.trace import Span
-
+        """Tag the live ``action.*`` span with the report's totals (the
+        phases are spans of their own, opened where they ran)."""
         sp.set(build_wall_s=round(self.wall_s, 4),
                build_phase_total_s=round(self.phase_total_s(), 4),
                build_bytes_written=self.bytes_written,
                build_spill_bytes=self.spill_bytes)
-        children = getattr(sp, "children", None)
-        if children is None:
-            return  # tracing off: sp is the shared no-op
-        for name, s in sorted(self.phases.items()):
-            child = Span(f"build.phase.{name}", {})
-            child.start_s = self.started_at
-            child.duration_ms = s * 1000.0
-            children.append(child)
 
     # -- serialization -------------------------------------------------------
     def to_dict(self) -> Dict[str, Any]:

@@ -5,8 +5,8 @@ spill_finish dwarf read, BENCH_r04) — but summed phase seconds cannot
 show WHY: whether the read lane sits idle while spill runs, how the
 device kernel overlaps host IO, where memory peaks inside a phase.  This
 module records *intervals* — ``(lane, kind, start_ns, end_ns)`` — for
-every BuildReport phase (actions + spill worker threads report through
-``BuildReport.add_phase``), every executor operator dispatch, and every
+every BuildReport phase (actions + spill worker threads time them with
+``BuildReport.phase``), every executor operator dispatch, and every
 block_until_ready-timed device kernel, into one process-global bounded
 ring, plus a background memory sampler (host RSS + jax live
 device-buffer bytes at a conf cadence) whose samples intersect with the

@@ -13,18 +13,21 @@ Cost contract: tracing is OFF by default
 (``hyperspace.system.telemetry.tracing.enabled``) and the disabled path
 is one module-global bool check returning a shared no-op context manager
 — no allocation, no contextvar touch, no clock read.  Instrumentation
-sits at file/action/operator granularity, never per row; bench.py's
-``telemetry_overhead`` section holds the line on both claims.
+sits at file/action/operator/sync-point granularity, never per row.
+What tracing costs when on is measured on the chip and recorded in
+PERF.md ("Findings").
 
 Contextvar propagation means worker threads (``utils/parallel_map``) do
 NOT inherit the submitting thread's span: their spans are isolated roots,
 which keeps the tree race-free without locks.  Root spans emitted from
 worker threads still reach the sinks (sinks lock internally).
 
-The XLA profiler seam lives here too (``profiler_trace``, folded in from
-``utils/profiling.py``): spans time the engine's decisions; the XLA trace
-times the device's execution of them.  One timing subsystem, two zoom
-levels.
+One clock with the device: when ``jax`` is already imported, every live
+span also enters a ``jax.profiler.TraceAnnotation`` of its name on the
+same thread, so a profiler trace (``profiler_trace`` below, or any
+``jax.profiler`` capture of a running server) shows the program's spans
+on its ``/host:CPU`` plane beside the device's operations.  Tags stay on
+the ``Span``; the annotation carries the name only.
 """
 
 from __future__ import annotations
@@ -33,6 +36,7 @@ import contextlib
 import contextvars
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, Iterator, List, Optional, Tuple
@@ -108,14 +112,16 @@ _current: "contextvars.ContextVar[Optional[Span]]" = contextvars.ContextVar(
 
 class _SpanCtx:
     """Context manager for one live span: links into the parent via the
-    contextvar, times the region, records exception outcomes, and emits
-    the root to the sinks on close."""
+    contextvar, times the region, mirrors it as a profiler annotation,
+    records exception outcomes, and emits the root to the sinks on
+    close."""
 
-    __slots__ = ("span", "_token")
+    __slots__ = ("span", "_token", "_annotation")
 
     def __init__(self, span: Span) -> None:
         self.span = span
         self._token: Optional[contextvars.Token] = None
+        self._annotation = None
 
     def __enter__(self) -> Span:
         span = self.span
@@ -123,6 +129,12 @@ class _SpanCtx:
         if parent is not None:
             parent.children.append(span)
         self._token = _current.set(span)
+        # Never imports jax: a process that has not loaded it has no
+        # device trace to share a clock with.
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is not None:
+            self._annotation = profiler.TraceAnnotation(span.name)
+            self._annotation.__enter__()
         span.start_s = time.time()
         span._t0 = time.perf_counter()
         return span
@@ -130,6 +142,8 @@ class _SpanCtx:
     def __exit__(self, exc_type, exc, tb) -> bool:
         span = self.span
         span.duration_ms = (time.perf_counter() - span._t0) * 1000.0
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         if exc is not None:
             span.status = "error"
             span.error = f"{type(exc).__name__}: {exc}"

@@ -197,11 +197,18 @@ class TestKernelParity:
 
 
 def _spill_dirs():
+    """This process's spill dirs: they are pid-stamped, so builds that
+    other test workers run meanwhile in the same temp root do not count."""
     import tempfile
 
-    root = tempfile.gettempdir()
-    return {n for n in os.listdir(root)
-            if n.startswith(("hs_build_spill_", "hs_zbuild_"))}
+    from hyperspace_tpu.actions.create import (
+        _SPILL_DIR_KINDS,
+        _spill_dir_prefix,
+    )
+
+    mine = tuple(_spill_dir_prefix(kind) for kind in _SPILL_DIR_KINDS)
+    return {n for n in os.listdir(tempfile.gettempdir())
+            if n.startswith(mine)}
 
 
 @pytest.fixture(params=["posix", "object_store"])

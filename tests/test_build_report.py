@@ -232,12 +232,87 @@ class TestBuildReport:
         assert metrics.registry().counter("build.actions") == before + 1
         assert metrics.registry().counter(
             "build.phase.read.seconds") > 0
-        # The action span carries synthesized build.phase.* children —
-        # what the CI trace grep asserts on the real bench.
-        action_spans = sink.find("action.CreateAction")
-        assert action_spans
-        names = {s.name for s in action_spans[-1].walk()}
-        assert any(n.startswith("build.phase.") for n in names), names
+        # The phases are spans of their own, opened where they ran: the
+        # action's thread nests them under its span, worker threads
+        # deliver them as roots — what the CI trace grep asserts on the
+        # real bench.
+        assert sink.find("action.CreateAction")
+        names = {s.name for root in sink.spans for s in root.walk()}
+        for phase in ("validate", "read", "kernel", "write", "commit"):
+            assert f"build.phase.{phase}" in names, names
+
+    def test_phase_spans_are_timed_where_they_run(self, tmp_path):
+        """A spilled create plus a full refresh: every build.phase span
+        lies inside its action's interval, on the thread that did the
+        work (worker phases arrive as roots from their pool's threads),
+        the spans start at different moments, and each phase's spans
+        add up to its BuildReport seconds."""
+        import threading
+
+        from hyperspace_tpu.telemetry import trace
+
+        class ThreadSink(trace.CollectingTraceSink):
+            def __init__(self):
+                super().__init__()
+                self.thread_of = {}
+
+            def emit(self, root):
+                self.thread_of[id(root)] = threading.current_thread().name
+                super().emit(root)
+
+        src = str(tmp_path / "src")
+        _write_source(src, n=20_000, files=8)
+        session = HyperspaceSession(system_path=str(tmp_path / "ix"))
+        session.conf.num_buckets = 4
+        session.conf.device_batch_rows = 4096  # force the spill build
+        session.conf.parallel_build = "off"
+        hs = Hyperspace(session)
+        sink = trace.add_sink(ThreadSink())
+        trace.enable_tracing()
+        try:
+            hs.create_index(session.read.parquet(src),
+                            IndexConfig("ti", ["k"], ["v"]))
+            reports = [hs.last_build_report()]
+            pq.write_table(pa.table({"k": pa.array([3], type=pa.int64()),
+                                     "v": [0.5]}),
+                           os.path.join(src, "part-99999.parquet"))
+            hs.refresh_index("ti", "full")
+            reports.append(hs.last_build_report())
+        finally:
+            trace.disable_tracing()
+            trace.remove_sink(sink)
+        actions = [r for r in sink.spans if r.name.startswith("action.")]
+        assert [a.name for a in actions] == ["action.CreateAction",
+                                             "action.RefreshAction"]
+        worker_thread = {"read": "hs-prefetch", "spill_route": "hs-route",
+                         "spill_finish": "hs-finalize"}
+        slack = 0.002  # span starts are wall clock, durations monotonic
+
+        def end(s):
+            return s.start_s + s.duration_ms / 1000.0
+
+        for action, report in zip(actions, reports):
+            inside = [s for s in action.walk()
+                      if s.name.startswith("build.phase.")]
+            for root in sink.spans:
+                if root.name.startswith("build.phase.") and \
+                        action.start_s - slack <= root.start_s and \
+                        end(root) <= end(action) + slack:
+                    phase = root.name[len("build.phase."):]
+                    assert sink.thread_of[id(root)].startswith(
+                        worker_thread[phase]), (phase, sink.thread_of)
+                    inside.append(root)
+            for s in inside:
+                assert action.start_s - slack <= s.start_s
+                assert end(s) <= end(action) + slack
+            assert any(s.start_s - action.start_s > 1e-3 for s in inside)
+            summed = {}
+            for s in inside:
+                name = s.name[len("build.phase."):]
+                summed[name] = summed.get(name, 0.0) + s.duration_ms / 1e3
+            assert set(summed) == set(report.phases), report.phases
+            for name, seconds in report.phases.items():
+                assert summed[name] == pytest.approx(seconds, rel=0.01), name
 
 
 # ---------------------------------------------------------------------------

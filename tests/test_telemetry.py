@@ -220,6 +220,59 @@ def test_query_trace_covers_lifecycle(indexed, traced):
     assert scan.tags["rows"] >= 1
 
 
+def test_spans_land_on_the_profiler_host_plane(indexed, traced, tmp_path):
+    """With tracing on, each span is also a profiler annotation: a
+    query's scan, decode and device-sync names are events on the
+    ``/host:CPU`` plane of a ``jax.profiler`` trace."""
+    import glob
+
+    import jax
+
+    s, hs, d = indexed
+    s.conf.device_filter_min_rows = 1  # the device mask: a device.sync
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    out_dir = str(tmp_path / "profile")
+    jax.profiler.start_trace(out_dir, profiler_options=options)
+    try:
+        ds = s.read.parquet(d).filter(col("v") < 100.0).select("k", "v")
+        assert ds.collect().num_rows == 50
+    finally:
+        jax.profiler.stop_trace()
+    assert s.last_execution_stats["filters"][0]["strategy"] == "device"
+    (path,) = glob.glob(os.path.join(out_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    planes = jax.profiler.ProfileData.from_file(path).planes
+    (host,) = [p for p in planes if p.name == "/host:CPU"]
+    names = {e.name for line in host.lines for e in line.events}
+    assert {"exec.scan", "io.read", "device.sync"} <= names
+
+
+def test_tracing_off_opens_no_span(monkeypatch):
+    """The disabled path of every new timing site: ``span()``, the sync
+    seams and a build phase allocate no Span and no annotation (the
+    phase still counts its seconds)."""
+    import jax
+    import jax.numpy as jnp
+
+    from hyperspace_tpu.execution import sync_guard
+    from hyperspace_tpu.telemetry.build_report import BuildReport
+
+    def refuse(*_a, **_k):
+        raise AssertionError("opened while tracing is off")
+
+    trace.disable_tracing()
+    monkeypatch.setattr(trace, "Span", refuse)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    assert span("exec.scan") is NOOP_SPAN
+    assert sync_guard.pull(jnp.arange(3), "t").tolist() == [0, 1, 2]
+    assert sync_guard.scalar(jnp.int32(7), "t") == 7
+    report = BuildReport()
+    with report.phase("read"):
+        pass
+    assert "read" in report.phases
+
+
 def test_run_report_on_clean_query(indexed):
     s, hs, d = indexed
     ds = s.read.parquet(d).filter(col("k") == 7).select("k", "v")
