@@ -277,7 +277,6 @@ def _kernel_microbench() -> dict:
     column shipping.  This shows what the chip does once data is
     resident, independent of the host–device link."""
     import jax
-    import jax.numpy as jnp
     import numpy as np
     import pyarrow as pa
 
@@ -285,8 +284,8 @@ def _kernel_microbench() -> dict:
     from hyperspace_tpu.ops.aggregate import _group_sort, _segment_reduce
     from hyperspace_tpu.ops.hash import use_pallas
     from hyperspace_tpu.ops.join import (
-        _expand,
-        _match_ranges,
+        _join_pairs,
+        _join_ranges,
         sorted_equi_join_np,
     )
     from hyperspace_tpu.ops.sort import (
@@ -339,13 +338,10 @@ def _kernel_microbench() -> dict:
     rk_d = jax.device_put(rk, dev)
 
     def dev_join():
-        r_perm = jnp.argsort(rk_d)
-        rk_sorted = rk_d[r_perm]
-        lo, hi = _match_ranges(lk_d, rk_sorted)
-        total = int(jnp.sum(hi - lo))  # intrinsic scalar sync
-        li, rp = _expand(lo, hi, round_up_pow2(total))
-        ri = r_perm[jnp.clip(rp, 0, n - 1)]
-        jax.block_until_ready((li, ri))
+        r_perm, lo, hi, total = _join_ranges(lk_d, rk_d, n, n)
+        total = int(total)  # intrinsic scalar sync
+        jax.block_until_ready(
+            _join_pairs(r_perm, lo, hi, round_up_pow2(total)))
 
     dev_join()
     j_dev = _time(dev_join, repeats=3)

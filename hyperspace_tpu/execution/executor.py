@@ -1534,7 +1534,7 @@ class Executor:
             # its own threshold (host inputs only: resident arrays keep
             # the single-device kernel, whose HBM placement is its own
             # layout).  Same match set either way — the mesh changes
-            # where the searchsorted runs, not what it finds.
+            # where the match ranges are found, not what they hold.
             mesh = self._active_mesh()
             use_mesh = (mesh is not None and not resident
                         and max_rows

@@ -4,11 +4,14 @@ Reference analog: the shuffle-free sort-merge join the covering indexes
 enable (JoinIndexRule.scala:36-50).  Spark's SMJ streams row iterators; the
 XLA-native formulation is vectorized:
 
-  1. sort the right side by key (one ``jnp.sort`` — on bucketed index data
-     the input is already sorted, making this a near-no-op merge),
-  2. ``searchsorted`` left keys into the right keys → per-left-row match
-     ranges [lo, hi),
-  3. expand to output pairs with ``jnp.repeat(..., total_repeat_length=N)``.
+  1. merge both sides' keys in one stable sort (the TPU's sort unit; on
+     bucketed index data the input is already sorted),
+  2. rank them there → the right side's sort permutation and each left
+     row's match range [lo, hi) of right ranks — running counts over the
+     merged order and a second sort back to row order, where a binary
+     search would be a loop of dependent gathers,
+  3. expand to output pairs: one more sort merges each left row's start
+     with the output slots, so every slot finds its row by running sums.
 
 Step 3 needs the total match count N as a static shape, so the kernel is
 two-phase with one host sync in between (count → materialize) — the standard
@@ -24,41 +27,96 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from hyperspace_tpu.execution import sync_guard
-from hyperspace_tpu.ops.hash import lexsort32
 from jax import enable_x64 as _enable_x64
 from hyperspace_tpu.utils.shapes import round_up_pow2
 
 
+def _same_key(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """``a == b`` as ``lax.sort`` orders keys: -0.0 equals 0.0 and every
+    NaN equals every NaN."""
+    if jnp.issubdtype(a.dtype, jnp.floating):
+        return (a == b) | (jnp.isnan(a) & jnp.isnan(b))
+    return a == b
+
+
 @jax.jit
-def _match_ranges(left_keys: jnp.ndarray, right_keys_sorted: jnp.ndarray
-                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    lo = jnp.searchsorted(right_keys_sorted, left_keys, side="left")
-    hi = jnp.searchsorted(right_keys_sorted, left_keys, side="right")
-    return lo, hi
+def _match_ranges(left_keys: jnp.ndarray, right_keys: jnp.ndarray
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """(r_perm, lo, hi): the stable argsort of ``right_keys`` and, per
+    left row, the ranks [lo, hi) of the right rows whose key equals it —
+    ``jnp.searchsorted`` of each left key into the sorted right keys, on
+    the left and on the right side.
+
+    Two sorts and two scans, no gathers.  The first sort merges both
+    sides by key, left rows ahead of equal right rows (concatenation
+    order, stable sort): the right rows before a left row are its
+    ``lo``, those up to the end of its equal-key run its ``hi``, and a
+    right row's ``lo`` is its rank.  The second sort sends every row
+    home — left rows to their position, right rows to their rank — so
+    the ranges come back in left-row order and ``r_perm`` in rank order.
+    Integer right keys merged as floats can round together; their own
+    values then order them second, as the right side's own sort would.
+    """
+    n_left = left_keys.shape[0]
+    n = n_left + right_keys.shape[0]
+    keys = jnp.concatenate([left_keys, right_keys])
+    if keys.dtype == jnp.bool_:
+        keys = keys.astype(jnp.int32)
+    operands = [keys, jax.lax.iota(jnp.int32, n)]
+    if (jnp.issubdtype(right_keys.dtype, jnp.integer)
+            and jnp.issubdtype(keys.dtype, jnp.floating)):
+        lowest = jnp.full(n_left, jnp.iinfo(right_keys.dtype).min,
+                          right_keys.dtype)
+        operands.insert(1, jnp.concatenate([lowest, right_keys]))
+    keys, *_, pos = jax.lax.sort(tuple(operands),
+                                 num_keys=len(operands) - 1, is_stable=True)
+    is_right = pos >= n_left
+    right_upto = jax.lax.cumsum(is_right.astype(jnp.int32))
+    lo = right_upto - is_right
+    run_end = jnp.concatenate([~_same_key(keys[1:], keys[:-1]),
+                               jnp.ones(1, jnp.bool_)])
+    hi = jax.lax.cummin(jnp.where(run_end, right_upto, n), reverse=True)
+    home = jnp.where(is_right, n_left + lo, pos)
+    _, lo_or_row, hi = jax.lax.sort(
+        (home, jnp.where(is_right, pos - n_left, lo), hi), num_keys=1)
+    return lo_or_row[n_left:], lo_or_row[:n_left], hi[:n_left]
 
 
 @partial(jax.jit, static_argnames=("capacity",))
 def _expand(lo: jnp.ndarray, hi: jnp.ndarray, capacity: int
             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    # ``capacity`` is the match count rounded UP to a power of two (caller
-    # slices to the true count): the static output shape must not track the
-    # exact count or every distinct query result size costs a fresh XLA
-    # compile.
-    # Output slot s belongs to the left row whose match range covers it:
-    # the first row whose inclusive running end exceeds s.  A binary
-    # search per slot, not ``jnp.repeat`` — same result, and the TPU
-    # compiler takes a second for it where the repeat took most of a
-    # minute.  Slots past the match count are clamped to the last row;
-    # callers slice them off.
+    """(left_idx, right_pos) of each of ``capacity`` output slots: left
+    row i fills the ``hi[i] - lo[i]`` slots from its running start with
+    the right ranks ``lo[i], lo[i] + 1, ...``.
+
+    ``capacity`` is the match count rounded UP to a power of two (the
+    caller slices to the true count): the static output shape must not
+    track the exact count or every distinct query result size costs a
+    fresh XLA compile.  Slots past the match count belong to the last
+    row; callers slice them off.
+
+    A slot belongs to the last row starting at or before it.  One sort
+    merges a marker per row (at its start, ahead of the slot there) with
+    the slots; running sums along it count the markers before each slot
+    and add up each marker's change in ``lo - start``, which leaves the
+    covering row and its offset at every slot.  A second sort puts the
+    slots back in order.  Unsigned keys ``2 * start`` and ``2 * slot + 1``
+    order a marker before the slot it starts at without a stable sort."""
     counts = hi - lo
-    ends = jnp.cumsum(counts)
-    slots = jnp.arange(capacity, dtype=ends.dtype)
-    left_idx = jnp.minimum(jnp.searchsorted(ends, slots, side="right"),
-                           lo.shape[0] - 1)
-    # Offset of each output row within its left-row group.
-    within = slots - (ends - counts)[left_idx]
-    right_pos = lo[left_idx] + within
-    return left_idx, right_pos
+    starts = jnp.cumsum(counts) - counts
+    off = lo - starts
+    keys = jnp.concatenate([starts.astype(jnp.uint32) * 2,
+                            jax.lax.iota(jnp.uint32, capacity) * 2 + 1])
+    steps = jnp.concatenate([off[:1], off[1:] - off[:-1],
+                             jnp.zeros(capacity, off.dtype)])
+    keys, steps = jax.lax.sort((keys, steps), num_keys=1)
+    is_slot = (keys & 1) == 1
+    left_idx = jax.lax.cumsum((~is_slot).astype(jnp.int32)) - 1
+    right_pos = (keys >> 1).astype(off.dtype) + jax.lax.cumsum(steps)
+    home = jnp.where(is_slot, keys >> 1, jnp.uint32(capacity))
+    _, left_idx, right_pos = jax.lax.sort((home, left_idx, right_pos),
+                                          num_keys=1)
+    return left_idx[:capacity], right_pos[:capacity]
 
 
 @jax.jit
@@ -67,7 +125,7 @@ def _join_ranges(left_keys, right_keys, n_left, n_right):
     or past ``n_left`` / ``n_right`` are padding and match nothing.
 
     Right padding takes the dtype's largest value (NaN for floats), so
-    the stable argsort parks it after every real row, real ties with the
+    the stable sort parks it after every real row, real ties with the
     fill value included; clamping the ranges to ``n_right`` then drops
     it.  Padded left rows get empty ranges."""
     dt = right_keys.dtype
@@ -79,11 +137,7 @@ def _join_ranges(left_keys, right_keys, n_left, n_right):
         fill = jnp.array(jnp.iinfo(dt).max, dt)
     rk = jnp.where(jnp.arange(right_keys.shape[0]) < n_right, right_keys,
                    fill)
-    if jnp.issubdtype(dt, jnp.floating):
-        r_perm = jnp.argsort(rk, stable=True)
-    else:
-        r_perm = lexsort32((rk,))
-    lo, hi = _match_ranges(left_keys, rk[r_perm])
+    r_perm, lo, hi = _match_ranges(left_keys, rk)
     lo = jnp.minimum(lo, n_right)
     hi = jnp.where(jnp.arange(left_keys.shape[0]) < n_left,
                    jnp.minimum(hi, n_right), lo)
@@ -101,10 +155,11 @@ def _join_pairs(r_perm, lo, hi, capacity: int):
 
 def sorted_equi_join_np(left_keys: np.ndarray, right_keys: np.ndarray
                         ) -> Tuple[np.ndarray, np.ndarray]:
-    """Host mirror of ``sorted_equi_join`` — the same sort/searchsorted/
-    expand formulation in numpy.  Below the device row threshold a device
-    round trip costs more than it saves; covering-index data arrives sorted
-    within buckets, so the mergesort argsort here is near-linear."""
+    """Host mirror of ``sorted_equi_join`` — the same ranges and pair
+    order, from numpy's sort and binary searches.  Below the device row
+    threshold a device round trip costs more than it saves;
+    covering-index data arrives sorted within buckets, so the mergesort
+    argsort here is near-linear."""
     left_keys = np.asarray(left_keys)
     right_keys = np.asarray(right_keys)
     if left_keys.size == 0 or right_keys.size == 0:
@@ -281,8 +336,8 @@ def sorted_equi_join(left_keys: np.ndarray, right_keys: np.ndarray
     inputs.  Right side is sorted on device; left side order is preserved.
     """
     # Narrow integer keys to int32 when every value fits: TPU has no native
-    # int64 (XLA emulates it as two u32 passes), so a 32-bit sort/searchsorted
-    # is the fast path.  Keys that genuinely need 64 bits (TPC-H orderkey at
+    # int64 (XLA emulates it as two u32 passes), so a 32-bit sort is the
+    # fast path.  Keys that genuinely need 64 bits (TPC-H orderkey at
     # SF100 exceeds 2^31) take the scoped-x64 path — scoped, not global,
     # because flipping x64 globally would change dtype defaults for every
     # other JAX user in the process.

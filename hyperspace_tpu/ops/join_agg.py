@@ -6,9 +6,10 @@ aggregation as separate engines forces the full joined row set through
 host memory and back across the host–device link.  This
 pipeline keeps the intermediate entirely in HBM:
 
-  1. sorted equi-join over the (resident) key columns — searchsorted
-     match ranges, one host sync for the match count (the standard XLA
-     dynamic-shape point, same as ops/join.py);
+  1. sorted equi-join over the (resident) key columns — match ranges
+     ranked by the sort unit (one merged sort of both sides' keys, one
+     back to row order; ops/join.py), one host sync for the match count
+     (the standard XLA dynamic-shape point);
   2. device gather of every referenced column through the match indices
      (group keys, aggregate inputs) — the joined table never
      materializes anywhere;

@@ -7,7 +7,7 @@ import pytest
 
 from hyperspace_tpu.io import columnar
 from hyperspace_tpu.ops.hash import bucket_ids
-from hyperspace_tpu.ops.join import sorted_equi_join
+from hyperspace_tpu.ops.join import sorted_equi_join, sorted_equi_join_np
 from hyperspace_tpu.ops.sort import bucket_counts, bucket_sort_permutation
 
 
@@ -378,3 +378,95 @@ def test_lexsort32_matches_jnp_lexsort(dtypes):
                 keys.append(jnp.asarray(vals.astype(dt)))
         np.testing.assert_array_equal(np.asarray(lexsort32(keys)),
                                       np.asarray(jnp.lexsort(keys)))
+
+
+def _ranking_keys(case, rng, size):
+    """Keys for the ranking parity cases: few distinct values, so both
+    sides hold duplicates."""
+    if case == "int32":
+        return rng.integers(-6, 6, size).astype(np.int32)
+    if case in ("int64_extremes", "int32_vs_int64"):
+        pool = np.array([-2**63, -2**63 + 1, -1, 0, 1, 2**40,
+                         2**63 - 2, 2**63 - 1], dtype=np.int64)
+        return rng.choice(pool, size)
+    if case == "float64_vs_wide_int64":
+        # Right keys 2^60 + a few: distinct as int64, one value as float64.
+        pool = np.array([2**60, 2**60 + 1, 2**60 + 255, 2**60 - 1, -2**60,
+                         -2**60 + 3, 0, 1], dtype=np.int64)
+        return rng.choice(pool, size)
+    if case == "bool":
+        return rng.integers(0, 2, size).astype(bool)
+    if case == "float64":
+        pool = np.array([0.0, -0.0, np.nan, -np.nan, np.inf, -np.inf, 1.5,
+                         -2.0, np.float64(np.uint64(0x7ff8000000000001)
+                                          .view(np.float64))])
+        return rng.choice(pool, size)
+    raise AssertionError(case)
+
+
+@pytest.mark.parametrize("case", [
+    "int32", "int64_extremes", "bool", "float64", "int32_vs_int64",
+    "float64_vs_wide_int64", "no_matches",
+])
+def test_sort_ranking_matches_searchsorted(case):
+    """The join's sort-based ranking gives exactly what binary searches
+    into the stably sorted right keys give — ranges, right permutation
+    and the expanded (left, right) pair sequence, every slot of the
+    padded capacity included — with padding past ``n_left`` /
+    ``n_right`` on both sides."""
+    import jax
+
+    from hyperspace_tpu.ops.join import _expand, _join_pairs, _join_ranges
+
+    rng = np.random.default_rng(7)
+    n_left, n_right, cap_left, cap_right = 50, 100, 64, 128
+    if case == "no_matches":
+        lk = rng.integers(0, 10, cap_left)
+        rk = rng.integers(20, 30, cap_right)
+        lk[n_left:] = 25  # padding matches nothing, even with real keys
+    else:
+        lk = _ranking_keys(case, rng, cap_left)
+        rk = _ranking_keys(case, rng, cap_right)
+        if case == "int32_vs_int64":
+            lk = rng.integers(-3, 3, cap_left).astype(np.int32)
+            rk = np.where(rng.random(cap_right) < 0.5, rk,
+                          rng.integers(-3, 3, cap_right))
+        if case == "float64_vs_wide_int64":
+            lk = lk.astype(np.float64)
+    # The numpy rendering: fill the right padding with the largest key,
+    # stable argsort, two binary searches, clamp to the real rows.
+    if rk.dtype.kind == "f":
+        fill = np.nan
+    elif rk.dtype == bool:
+        fill = True
+    else:
+        fill = np.iinfo(rk.dtype).max
+    rk_f = np.where(np.arange(cap_right) < n_right, rk, fill)
+    r_perm = np.argsort(rk_f, kind="stable")
+    lo = np.minimum(np.searchsorted(rk_f[r_perm], lk, "left"), n_right)
+    hi = np.where(np.arange(cap_left) < n_left, np.minimum(
+        np.searchsorted(rk_f[r_perm], lk, "right"), n_right), lo)
+    total = int((hi - lo).sum())
+    with jax.enable_x64(True):
+        got = _join_ranges(lk, rk, n_left, n_right)
+        np.testing.assert_array_equal(np.asarray(got[0]), r_perm)
+        np.testing.assert_array_equal(np.asarray(got[1]), lo)
+        np.testing.assert_array_equal(np.asarray(got[2]), hi)
+        assert int(got[3]) == total
+        for cap in (max(total, 1), 2 * max(total, 1)):
+            counts = hi - lo
+            starts = np.cumsum(counts) - counts
+            slots = np.arange(cap)
+            li_want = np.minimum(np.searchsorted(starts + counts, slots,
+                                                 "right"), cap_left - 1)
+            pos_want = lo[li_want] + slots - starts[li_want]
+            li, pos = _expand(got[1], got[2], cap)
+            np.testing.assert_array_equal(np.asarray(li), li_want)
+            np.testing.assert_array_equal(np.asarray(pos), pos_want)
+            li, ri = _join_pairs(got[0], got[1], got[2], cap)
+            np.testing.assert_array_equal(
+                np.asarray(ri), r_perm[np.clip(pos_want, 0, cap_right - 1)])
+    # The pairs are the host join's over the real rows, in its order.
+    li_host, ri_host = sorted_equi_join_np(lk[:n_left], rk[:n_right])
+    np.testing.assert_array_equal(np.asarray(li)[:total], li_host)
+    np.testing.assert_array_equal(np.asarray(ri)[:total], ri_host)

@@ -15,6 +15,9 @@ suite runs under several xdist workers.
 
 from __future__ import annotations
 
+import re
+import time
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -119,19 +122,26 @@ def test_segment_reduce_float64_and_int64(chip):
         chip((ROWS,), jnp.int64), x64=True)
 
 
+def _loops(compiled) -> int:
+    """``while`` ops in a compiled program: a binary search over the
+    keys compiles to one, a sort to none."""
+    return len(re.findall(r"\swhile\(", compiled.as_text()))
+
+
 def test_match_ranges_int64(chip):
     from hyperspace_tpu.ops.join import _match_ranges
 
     keys = chip((ROWS,), jnp.int64)
-    _compile(_match_ranges, keys, keys, x64=True)
+    assert _loops(_compile(_match_ranges, keys, keys, x64=True)) == 0
 
 
 def test_expand(chip):
     from hyperspace_tpu.ops.join import _expand
 
     ranges = chip((ROWS,), jnp.int32)
-    _compile(lambda lo, hi: _expand(lo, hi, 4 * ROWS), ranges, ranges,
-             x64=True)
+    out = _compile(lambda lo, hi: _expand(lo, hi, 4 * ROWS), ranges, ranges,
+                   x64=True)
+    assert _loops(out) == 0
 
 
 def test_join_ranges_int32_keys(chip):
@@ -141,7 +151,23 @@ def test_join_ranges_int32_keys(chip):
 
     keys = chip((ROWS,), jnp.int32)
     count = chip((), jnp.int32)
-    _compile(_join_ranges, keys, keys, count, count, x64=True)
+    assert _loops(_compile(_join_ranges, keys, keys, count, count,
+                           x64=True)) == 0
+
+
+def test_join_ranges_int64_keys_has_no_search_loop(chip):
+    """Q3's join at its padded shapes: resident int64 keys, 1<<20 orders
+    against 1<<22 lineitems.  Ranked by sorts, the program holds no
+    loop of dependent gathers; the cold compile seconds are printed."""
+    from hyperspace_tpu.ops.join import _join_ranges
+
+    count = chip((), jnp.int64)
+    t0 = time.perf_counter()
+    out = _compile(_join_ranges, chip((ROWS,), jnp.int64),
+                   chip((4 * ROWS,), jnp.int64), count, count, x64=True)
+    print(f"_join_ranges int64 {ROWS}x{4 * ROWS}: compiled in "
+          f"{time.perf_counter() - t0:.1f} s")
+    assert _loops(out) == 0
 
 
 def test_topk_groups_float64(chip):
