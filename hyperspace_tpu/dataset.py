@@ -371,6 +371,16 @@ class Dataset:
         return self.session.last_run_report_value
 
     def _collect_traced(self, plan_cache=None) -> pa.Table:
+        """One query inside a snapshot scope (sources/snapshots.py): each
+        versioned table is read at the version latest when it starts,
+        and the answer states those versions in its schema metadata."""
+        from hyperspace_tpu.sources import snapshots
+
+        with snapshots.query_scope() as versions:
+            out = self._collect_in_scope(plan_cache)
+        return snapshots.with_source_versions(out, versions)
+
+    def _collect_in_scope(self, plan_cache=None) -> pa.Table:
         from hyperspace_tpu.exceptions import (
             DeadlineExceededError,
             DeviceSyncError,
@@ -387,10 +397,17 @@ class Dataset:
         executor = Executor(self.session)
         plan = None
         cache_key = None
-        if plan_cache is not None:
+        try:
+            # Versioned tables resolve their version here, once per query:
+            # the cache entry must have been optimized against the same.
+            versions = self.session.source_provider_manager.source_versions(
+                self.plan)
+        except Exception:  # noqa: BLE001 — the query itself reports it
+            versions = None
+        if plan_cache is not None and versions is not None:
             cache_key = plan_cache.key_for(self.session, self.plan)
             if cache_key is not None:
-                plan = plan_cache.get(cache_key)
+                plan = plan_cache.get(cache_key, versions)
                 if plan is not None:
                     # The optimizer pass (whose rules feed indexes_used)
                     # is skipped on a hit: attribute the cached plan's
@@ -408,7 +425,7 @@ class Dataset:
             try:
                 plan = self.optimized_plan()
                 if cache_key is not None:
-                    plan_cache.put(cache_key, plan)
+                    plan_cache.put(cache_key, plan, versions)
                     run_report.record("plan_cache", hit=False,
                                       fingerprint=cache_key)
             except Exception as e:  # noqa: BLE001 — InjectedCrash propagates.

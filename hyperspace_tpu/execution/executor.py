@@ -31,6 +31,8 @@ from hyperspace_tpu.io import columnar
 from hyperspace_tpu.telemetry import timeline
 from hyperspace_tpu.telemetry.trace import span
 from hyperspace_tpu.utils import deadline as _deadline
+from hyperspace_tpu.utils.shapes import round_up_pow2
+import jax
 from jax import enable_x64 as _enable_x64
 from hyperspace_tpu.io.files import list_data_files
 from hyperspace_tpu.io.parquet import bucket_id_of_file, read_table
@@ -184,8 +186,6 @@ class Executor:
         if arr is not None:
             counters["hits"] += 1
             return arr
-        import jax
-
         host = convert(table.column(column))
         with _enable_x64():  # int64 columns must keep full width
             dev = jax.device_put(np.asarray(host))
@@ -366,7 +366,8 @@ class Executor:
         if isinstance(plan, SetOp):
             return self._set_op(plan)
         if isinstance(plan, (BucketUnion, Union)):
-            tables = [self.execute(c) for c in plan.children]
+            tables = [self._execute_appended(c) if _is_appended_branch(c)
+                      else self.execute(c) for c in plan.children]
             # Public Union: "permissive" widens same-named numeric columns
             # of different widths (int32 ∪ int64 -> int64, int ∪ float ->
             # double) like Spark's unionByName.  BucketUnion merges an
@@ -1347,11 +1348,19 @@ class Executor:
                                           mesh=mesh)
         device_cols = [self._device_column(table, c, identity, "num")
                        for c in order]
+        if not any(isinstance(c, jax.Array) for c in device_cols):
+            # Host columns (no HBM-resident copy) are padded to a power
+            # of two: a table whose row count moves (a new snapshot's
+            # appended rows) keeps its compiled predicate.
+            from hyperspace_tpu.ops.sort import _pad_rows
+
+            rows = round_up_pow2(max(1, table.num_rows))
+            device_cols = [_pad_rows(c, rows) for c in device_cols]
         t0 = timeline.kernel_begin()
         with _enable_x64():
             mask = fn(device_cols, literals)
         timeline.kernel_end("filter", t0, mask)
-        return sync_guard.pull(mask, "filter.mask")
+        return sync_guard.pull(mask, "filter.mask")[:table.num_rows]
 
     def _normalize_literals(self, expr: Expr, table: pa.Table) -> Expr:
         """Rewrite temporal/bool literals to their int64 device domain."""
@@ -1833,9 +1842,10 @@ class Executor:
         hash kernel."""
         appended_by_bucket: Dict[int, pa.Table] = {}
         if side.appended is not None:
-            table = self.execute(side.appended)
             num_buckets, cols, _sort = side.scan.relation.bucket_spec
-            routed = self._route_to_buckets(table, cols, num_buckets, side.scan)
+            routed = self._execute_appended(
+                side.appended, lambda table: self._route_to_buckets(
+                    table, cols, num_buckets, side.scan))
             if routed is None:
                 return None
             appended_by_bucket = routed
@@ -1854,6 +1864,31 @@ class Executor:
 
         return {b: (lambda b=b: make(b))
                 for b in set(by_bucket) | set(appended_by_bucket)}
+
+    def _execute_appended(self, node: LogicalPlan, then=None):
+        """Execute a hybrid scan's appended branch (the source files the
+        index does not cover yet) under ``exec.hybrid_appended``: listing,
+        decode and, through ``then`` on the bucketed join path, routing
+        into the index's buckets.  Returns ``then(table)`` or the table.
+        The answer states the source version the branch's file list was
+        taken at (sources/snapshots.py)."""
+        from hyperspace_tpu.sources import snapshots
+        from hyperspace_tpu.telemetry import metrics
+
+        scans = node.leaf_relations()
+        for s in scans:
+            if s.relation.source_version is not None:
+                snapshots.record_version(
+                    os.path.abspath(s.relation.root_paths[0]),
+                    s.relation.source_version)
+        files = sum(len(s.relation.file_paths or ()) for s in scans)
+        with span("exec.hybrid_appended", files=files) as sp:
+            table = self.execute(node)
+            sp.set(rows=table.num_rows)
+            out = table if then is None else then(table)
+        metrics.inc("hybrid.appended_files", files)
+        metrics.inc("hybrid.appended_rows", table.num_rows)
+        return out
 
     def _route_to_buckets(self, table: pa.Table, cols, num_buckets: int,
                           index_scan: Scan) -> Optional[Dict[int, pa.Table]]:
@@ -1969,6 +2004,13 @@ class _BucketedSide:
         self.inner = inner
         self.outer = outer
         self.appended = appended
+
+
+def _is_appended_branch(node: LogicalPlan) -> bool:
+    """The appended branch of a hybrid-scan union, as rules/hybrid.py
+    marks it."""
+    return any(s.relation.hybrid_appended_of is not None
+               for s in node.leaf_relations())
 
 
 def _is_bucketed_index_scan(node: LogicalPlan) -> bool:

@@ -18,7 +18,7 @@ plan by:
   - the session's hyperspace-enabled switch (same plan, rules on vs off,
     different result).
 
-Entries are invalidated three ways:
+Entries are invalidated four ways:
 
   - **generation**: every committed index action (create/refresh/vacuum/
     optimize/delete — actions/base.py) bumps a process-global generation;
@@ -26,10 +26,17 @@ Entries are invalidated three ways:
     generation is a miss.  This is what makes "build an index while the
     server runs" safe: the very next request re-optimizes and picks the
     new index up.
-  - **TTL**: source data can drift without any index action (files
-    appended under a scanned root).  Entries expire after ``ttl_s`` —
-    the serving layer passes ``hyperspace.index.cache.expiryDurationInSeconds``,
-    the same staleness window the index-listing cache already accepts.
+  - **source version**: an entry carries the version of each versioned
+    table (Delta, Iceberg) its plan was optimized against
+    (``sources/snapshots.py``); a lookup states the versions the query
+    reads now, and an entry whose versions differ is dropped
+    (``serve.plan_cache.source_stale``) and re-optimized, so a commit is
+    seen by the next query.
+  - **TTL**: plain file sources have no version and can drift without
+    any index action (files appended under a scanned root).  Entries
+    expire after ``ttl_s`` — the serving layer passes
+    ``hyperspace.index.cache.expiryDurationInSeconds``, the same
+    staleness window the index-listing cache already accepts.
   - **explicit**: the serving layer drops an entry whose plan failed at
     execution before running the degraded/containment machinery, so a
     cached plan over quarantined files cannot fail twice.
@@ -46,7 +53,7 @@ from __future__ import annotations
 import hashlib
 import threading
 import time
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 from hyperspace_tpu.execution.device_cache import ByteBudgetLRU
 from hyperspace_tpu.plan.nodes import LogicalPlan, Scan
@@ -108,30 +115,40 @@ class PlanCache:
             return None
 
     # -- lookup / store -----------------------------------------------------
-    def get(self, key: str) -> Optional[LogicalPlan]:
-        entry: Optional[Tuple[LogicalPlan, int, float]] = self._lru.peek(key)
+    def get(self, key: str, versions: Optional[Dict[str, int]] = None
+            ) -> Optional[LogicalPlan]:
+        """The cached plan, or None; ``versions`` are the versioned
+        tables' versions the query reads (None or empty: none)."""
+        entry: Optional[Tuple[LogicalPlan, int, float, Dict[str, int]]] = \
+            self._lru.peek(key)
         if entry is not None:
-            plan, generation, stored_at = entry
-            if generation == current_generation() \
+            plan, generation, stored_at, stored_versions = entry
+            moved = stored_versions != (versions or {})
+            if not moved and generation == current_generation() \
                     and time.monotonic() - stored_at <= self.ttl_s:
                 self._lru.get(key)  # hit accounting + recency bump
                 return plan
-            # Stale: an index action landed since, or the TTL passed.
-            # Dropped BEFORE the counting lookup so the hit-rate the
-            # bench reports means "served from cache", nothing else.
+            # Stale: an index action landed since, a source version
+            # moved, or the TTL passed.  Dropped BEFORE the counting
+            # lookup so the hit-rate the bench reports means "served from
+            # cache", nothing else.
             self._lru.pop(key)
             from hyperspace_tpu.telemetry import metrics
 
             metrics.inc("serve.plan_cache.stale")
+            if moved:
+                metrics.inc("serve.plan_cache.source_stale")
         self._lru.get(key)  # registers the miss
         return None
 
-    def put(self, key: str, plan: LogicalPlan) -> None:
+    def put(self, key: str, plan: LogicalPlan,
+            versions: Optional[Dict[str, int]] = None) -> None:
         try:
             rendered = plan.tree_string()
         except Exception:  # noqa: BLE001 — unrenderable = uncacheable
             return
-        self._lru.put(key, (plan, current_generation(), time.monotonic()),
+        self._lru.put(key, (plan, current_generation(), time.monotonic(),
+                            dict(versions or {})),
                       _plan_bytes_estimate(rendered, plan),
                       self.budget_bytes)
 

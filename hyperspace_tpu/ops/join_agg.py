@@ -20,7 +20,9 @@ pipeline keeps the intermediate entirely in HBM:
   5. only the per-group results cross back to host: counts, reductions,
      and one (left, right) row-index pair per group so the executor can
      take the group-key VALUES from the host arrow tables in their exact
-     original types.
+     original types (in group slots of the power of two above the group
+     count, padded toward half the join's match capacity by at most 8
+     times, so a moving group count keeps its programs).
 
 Reference contract: Spark executes the rewritten plans of
 JoinIndexRule.scala:36-50 as exchange-free SMJ + HashAggregate; this is
@@ -228,8 +230,14 @@ def join_group_aggregate(
                     np.empty(0, np.int32), [np.empty(0) for _ in agg_ops])
         capacity = round_up_pow2(total)
         li, ri = _join_pairs(r_perm, lo, hi, capacity)
+        # Host columns are padded like their side's key, so a side whose
+        # row count moves (a new snapshot's appended rows) reuses its
+        # gather programs; an HBM-resident column keeps its fixed shape.
+        side_rows = {"l": lk.shape[0], "r": rk.shape[0]}
         gathered = [
-            jnp.asarray(c)[li if side == "l" else ri]
+            jnp.asarray(c if isinstance(c, jax.Array)
+                        else _pad_rows(c, side_rows[side]))[
+                li if side == "l" else ri]
             for c, side in zip(columns, column_sides)]
         key_words = tuple(_int_order_words(gathered[i])
                           for i in group_col_ix)
@@ -247,7 +255,13 @@ def join_group_aggregate(
             timeline.kernel_end("join_agg", t0, perm)
             return (np.empty(0, np.int64), np.empty(0, np.int64),
                     np.empty(0, np.int32), [np.empty(0) for _ in agg_ops])
+        # Group slots: the power of two above g, padded toward half the
+        # match capacity by at most 8 times.  A group count that moves
+        # with a growing table (a new snapshot's rows) keeps its programs
+        # while it stays between capacity/16 and capacity/2, and a few
+        # groups over many matches pull a few dozen slots, not the join.
         gcap = round_up_pow2(g)
+        gcap = max(gcap, min(capacity // 2, 8 * gcap))
         out = _segment_reduce(perm, boundaries, total, value_cols,
                               ops=tuple(agg_ops), capacity=gcap)
         if topn is not None:
@@ -266,11 +280,12 @@ def join_group_aggregate(
                        for r in out[2:]]
             return li_first, ri_first, counts, results
         timeline.kernel_end("join_agg", t0, out)
-        first_rows = out[0][:g]
+        # Gathered over all ``gcap`` slots and cut on host: programs sized
+        # by the exact group count would compile anew whenever it moves.
         li_first = sync_guard.pull(
-            li[first_rows], "join_agg.li_first").astype(np.int64)
+            li[out[0]], "join_agg.li_first")[:g].astype(np.int64)
         ri_first = sync_guard.pull(
-            ri[first_rows], "join_agg.ri_first").astype(np.int64)
+            ri[out[0]], "join_agg.ri_first")[:g].astype(np.int64)
         counts = sync_guard.pull(out[1], "join_agg.counts")[:g]
         results = [sync_guard.pull(r, "join_agg.results")[:g]
                    for r in out[2:]]

@@ -43,6 +43,11 @@ class ScanRelation:
     # (pruned files, total files) for display.
     data_skipping_of: Optional[str] = None
     data_skipping_stats: Optional[Tuple[int, int]] = None
+    # Hybrid scan (rules/hybrid.py): this scan reads the source files the
+    # index ``hybrid_appended_of`` does not cover, as listed at the
+    # source's ``source_version`` (None for a source without versions).
+    hybrid_appended_of: Optional[str] = None
+    source_version: Optional[int] = None
     # What-if planning (advisor/hypothetical.py): this scan was rewritten
     # onto a HYPOTHETICAL index — a plan-only artifact with zero data
     # files.  The executor refuses to run it; only the advisor's plan

@@ -229,11 +229,18 @@ def transform_plan_to_use_hybrid_scan(session, plan: LogicalPlan, target: Scan,
                 repair_scan))
 
     if appended:
+        # The executor states this version for the table when it reads
+        # the branch: a plan reused after a commit states the version its
+        # file list is from, not the one resolved for the query.
+        version = session.source_provider_manager.get_relation(
+            target).source_version()
         appended_scan = Scan(ScanRelation(
             root_paths=src_rel.root_paths,
             file_format=src_rel.file_format,
             options=src_rel.options,
             file_paths=tuple(f.name for f in appended),
+            hybrid_appended_of=entry.name,
+            source_version=version,
         ))
         appended_side: LogicalPlan = Project(visible_cols, appended_scan)
         cols = tuple(entry.indexed_columns)

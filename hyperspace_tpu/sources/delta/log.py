@@ -21,7 +21,7 @@ import json
 import os
 import re
 import urllib.parse
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from hyperspace_tpu.exceptions import CorruptMetadataError
 
@@ -78,29 +78,32 @@ class DeltaLog:
         return os.path.isdir(self.log_path) and bool(
             self.commit_versions() or self.checkpoint_versions())
 
-    def commit_versions(self) -> List[int]:
+    def _listing(self) -> Tuple[List[int], List[int]]:
+        """(commit versions, checkpoint versions), ascending, from one
+        listing of the log directory."""
         if not os.path.isdir(self.log_path):
-            return []
-        out = []
+            return [], []
+        commits, checkpoints = [], []
         for name in os.listdir(self.log_path):
             m = _COMMIT_RE.match(name)
             if m:
-                out.append(int(m.group(1)))
-        return sorted(out)
-
-    def checkpoint_versions(self) -> List[int]:
-        if not os.path.isdir(self.log_path):
-            return []
-        out = []
-        for name in os.listdir(self.log_path):
+                commits.append(int(m.group(1)))
+                continue
             m = _CHECKPOINT_RE.match(name)
             if m:
-                out.append(int(m.group(1)))
-        return sorted(out)
+                checkpoints.append(int(m.group(1)))
+        return sorted(commits), sorted(checkpoints)
+
+    def commit_versions(self) -> List[int]:
+        return self._listing()[0]
+
+    def checkpoint_versions(self) -> List[int]:
+        return self._listing()[1]
 
     def latest_version(self) -> int:
-        versions = self.commit_versions()
-        checkpoints = self.checkpoint_versions()
+        return self._latest(*self._listing())
+
+    def _latest(self, versions: List[int], checkpoints: List[int]) -> int:
         if not versions and not checkpoints:
             raise FileNotFoundError(f"Not a Delta table: {self.table_path}")
         return max(versions + checkpoints)
@@ -137,7 +140,8 @@ class DeltaLog:
 
     # -- replay -------------------------------------------------------------
     def snapshot(self, version: Optional[int] = None) -> Snapshot:
-        latest = self.latest_version()
+        all_commits, checkpoints = self._listing()
+        latest = self._latest(all_commits, checkpoints)
         if version is None:
             version = latest
         if version > latest or version < 0:
@@ -150,13 +154,13 @@ class DeltaLog:
 
         # Start from the newest checkpoint at or below the target version.
         start = 0
-        usable = [c for c in self.checkpoint_versions() if c <= version]
+        usable = [c for c in checkpoints if c <= version]
         if usable:
             cp = usable[-1]
             metadata, active, tombstones = self._read_checkpoint(cp)
             start = cp + 1
 
-        commits = [v for v in self.commit_versions() if start <= v <= version]
+        commits = [v for v in all_commits if start <= v <= version]
         expect = list(range(start, version + 1))
         if commits != expect:
             missing = sorted(set(expect) - set(commits))

@@ -30,8 +30,10 @@ from hyperspace_tpu.index.log_entry import (
     Relation,
 )
 from hyperspace_tpu.plan.nodes import Scan
+from hyperspace_tpu.sources import snapshots
 from hyperspace_tpu.sources.delta.log import DeltaLog, Snapshot
 from hyperspace_tpu.sources.interfaces import FileBasedRelation, FileBasedSourceProvider
+from hyperspace_tpu.telemetry.trace import span
 
 DELTA_FORMAT = "delta"
 DELTA_VERSION_HISTORY_PROPERTY = "deltaVersions"
@@ -74,16 +76,50 @@ class DeltaLakeRelation(FileBasedRelation):
     def table_version(self) -> int:
         return self._snapshot().version
 
+    def source_version(self) -> int:
+        """The version this relation reads, noted as the current query's
+        (sources/snapshots.py); a latest read costs one log listing per
+        query and no replay."""
+        opts = self.options
+        if "versionAsOf" not in opts and "timestampAsOf" not in opts:
+            return self._latest_version()
+        version = int(opts["versionAsOf"]) if "versionAsOf" in opts \
+            else self._snapshot().version
+        snapshots.record_version(self._log.table_path, version)
+        return version
+
+    def _latest_version(self) -> int:
+        def listed() -> int:
+            with span("source.snapshot", table=self._log.table_path) as sp:
+                version = self._log.latest_version()
+                sp.set(version=version)
+            return version
+
+        version = snapshots.resolve_once(("delta", self._log.table_path),
+                                         listed)
+        snapshots.record_version(self._log.table_path, version)
+        return version
+
     def _snapshot(self) -> Snapshot:
         if self._snapshot_cache is None:
             opts = self.options
-            version: Optional[int] = None
             if "versionAsOf" in opts:
                 version = int(opts["versionAsOf"])
             elif "timestampAsOf" in opts:
                 version = self._log.version_for_timestamp(
                     _timestamp_ms(opts["timestampAsOf"]))
-            self._snapshot_cache = self._log.snapshot(version)
+            else:
+                version = self._latest_version()
+
+            def replayed() -> Snapshot:
+                with span("source.snapshot", table=self._log.table_path,
+                          version=version) as sp:
+                    snap = self._log.snapshot(version)
+                    sp.set(files=len(snap.files))
+                return snap
+
+            self._snapshot_cache = snapshots.resolve_once(
+                ("delta", self._log.table_path, version), replayed)
         return self._snapshot_cache
 
     # -- FileBasedRelation --------------------------------------------------

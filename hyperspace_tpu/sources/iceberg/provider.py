@@ -36,7 +36,9 @@ from hyperspace_tpu.sources.iceberg.metadata import (
     IcebergTable,
     TableMetadata,
 )
+from hyperspace_tpu.sources import snapshots
 from hyperspace_tpu.sources.interfaces import FileBasedRelation, FileBasedSourceProvider
+from hyperspace_tpu.telemetry.trace import span
 
 ICEBERG_FORMAT = "iceberg"
 ICEBERG_VERSION_HISTORY_PROPERTY = "icebergSnapshots"
@@ -57,9 +59,19 @@ class IcebergRelation(FileBasedRelation):
 
     # -- snapshot resolution ------------------------------------------------
     def _metadata(self) -> TableMetadata:
+        """The table's metadata, loaded once per query
+        (sources/snapshots.py), so its current snapshot is pinned."""
         if self._metadata_cache is None:
-            self._metadata_cache = self._table.load_metadata()
+            self._metadata_cache = snapshots.resolve_once(
+                ("iceberg", self._table.table_path), self._load_metadata)
         return self._metadata_cache
+
+    def _load_metadata(self) -> TableMetadata:
+        with span("source.snapshot", table=self._table.table_path) as sp:
+            md = self._table.load_metadata()
+            current = md.current_snapshot()
+            sp.set(version=current.snapshot_id if current else -1)
+        return md
 
     def _snapshot(self) -> Optional[IcebergSnapshot]:
         """Resolve time travel: ``snapshot-id`` wins, then
@@ -81,6 +93,14 @@ class IcebergRelation(FileBasedRelation):
     def snapshot_id(self) -> Optional[int]:
         snap = self._snapshot()
         return snap.snapshot_id if snap else None
+
+    def source_version(self) -> int:
+        """The snapshot id this relation reads (-1 before the first
+        snapshot), noted as the current query's."""
+        snap = self._snapshot()
+        version = snap.snapshot_id if snap else -1
+        snapshots.record_version(self._table.table_path, version)
+        return version
 
     # -- FileBasedRelation --------------------------------------------------
     def all_files(self, tracker: Optional[FileIdTracker] = None) -> List[FileInfo]:

@@ -68,6 +68,11 @@ class FileBasedRelation:
         """Relation-level validity signature (interfaces.scala:52-58)."""
         raise NotImplementedError
 
+    def source_version(self) -> Optional[int]:
+        """The table version this relation reads, for versioned sources
+        (Delta version, Iceberg snapshot id); None for plain files."""
+        return None
+
     def create_relation_metadata(self, tracker: FileIdTracker) -> Relation:
         """Snapshot for the log entry (interfaces.scala:101-110)."""
         raise NotImplementedError

@@ -7,13 +7,18 @@ by exactly one provider (error on 0 or >1, :117-155).
 
 from __future__ import annotations
 
+import os
 from typing import Callable, Dict, List, Optional, TypeVar
 
 from hyperspace_tpu.config import HyperspaceConf
 from hyperspace_tpu.exceptions import HyperspaceError
 from hyperspace_tpu.index.log_entry import Relation
-from hyperspace_tpu.plan.nodes import Scan
-from hyperspace_tpu.sources.interfaces import FileBasedRelation, FileBasedSourceProvider
+from hyperspace_tpu.plan.nodes import LogicalPlan, Scan
+from hyperspace_tpu.sources.interfaces import (
+    LAKE_DATA_FORMATS,
+    FileBasedRelation,
+    FileBasedSourceProvider,
+)
 
 T = TypeVar("T")
 
@@ -79,6 +84,22 @@ class FileBasedSourceProviderManager:
 
     def get_relation(self, scan: Scan) -> FileBasedRelation:
         return self._run("get_relation", lambda p: p.get_relation(scan))
+
+    def source_versions(self, plan: LogicalPlan) -> Dict[str, int]:
+        """Table path -> version of each versioned table ``plan`` reads.
+        Plain file scans are skipped without a provider call, so they
+        cost no listing."""
+        out: Dict[str, int] = {}
+        for scan in plan.leaf_relations():
+            rel = scan.relation
+            if rel.file_format.lower() not in LAKE_DATA_FORMATS \
+                    or rel.index_scan_of is not None:
+                continue
+            relation = self.get_relation(scan)
+            version = relation.source_version()
+            if version is not None:
+                out[os.path.abspath(relation.root_paths[0])] = version
+        return out
 
     def internal_file_format_name(self, relation: Relation) -> str:
         return self._run("internal_file_format_name",
