@@ -8,10 +8,13 @@ for free through the block manager's RDD caching; here it is explicit: a
 process-wide, byte-budgeted LRU of POST-DECODE device arrays keyed by
 file identity.
 
-Keys are ``(files_fingerprint, column, kind)`` where the fingerprint
+Keys are ``(files fingerprint, column, kind)`` where the fingerprint
 hashes the scan's resolved file list with each file's (size, mtime):
 an overwritten or compacted index version can never serve stale arrays —
-its fingerprint differs, and the dead entries age out of the LRU.
+its fingerprint differs, and the dead entries age out of the LRU.  A
+fused join's side also leaves its :class:`SideManifest` under
+``(fingerprint, "__side__", "meta")``, so a repeat that finds the
+manifest and the columns serves the side without decoding a file.
 
 Residency changes ROUTING, not just speed: once a scan's referenced
 columns are resident, the device path's cost is kernel time plus
@@ -41,19 +44,68 @@ from typing import Dict, Iterable, Optional, Tuple
 Key = Tuple[str, str, str]  # (files fingerprint, column name, kind)
 
 
-def files_fingerprint(paths: Iterable[str]) -> Optional[str]:
-    """Content-identity hash of a resolved scan file list: path order plus
-    each file's size and mtime_ns.  None when any file is unstat-able
-    (races with vacuum — safer to skip caching than to key on guesses)."""
+def stat_files(paths: Iterable[str]) -> Tuple[Optional[str], int]:
+    """One ``stat`` per file: the content-identity hash of a resolved scan
+    file list (path order plus each file's size and mtime_ns) and the
+    files' total bytes.  The hash is None when any file is unstat-able
+    (races with vacuum — safer to skip caching than to key on guesses);
+    the bytes then count the files that could be stat-ed."""
     h = hashlib.md5()
-    try:
-        for p in paths:
+    total = 0
+    ok = True
+    for p in paths:
+        try:
             st = os.stat(p)
-            h.update(p.encode())
-            h.update(f":{st.st_size}:{st.st_mtime_ns};".encode())
-    except OSError:
-        return None
-    return h.hexdigest()
+        except OSError:
+            ok = False
+            continue
+        total += st.st_size
+        h.update(p.encode())
+        h.update(f":{st.st_size}:{st.st_mtime_ns};".encode())
+    return (h.hexdigest() if ok else None), total
+
+
+def derive_fingerprint(fp: str, transform: str) -> str:
+    """Identity of rows derived from ``fp``'s rows by a deterministic
+    transform (``filter:<predicate repr>``, ``dropnull:<column>``)."""
+    return hashlib.md5(f"{fp}|{transform}".encode()).hexdigest()
+
+
+def side_manifest_key(fp: str) -> Key:
+    """Where a join side's :class:`SideManifest` lives in the cache."""
+    return (fp, "__side__", "meta")
+
+
+class SideManifest:
+    """Host record of a materialized join side, kept in the device cache
+    beside the side's columns (so it ages out with them): the side's
+    arrow schema, row count and per-column null counts — all the fused
+    join needs of a side it does not decode."""
+
+    __slots__ = ("schema", "num_rows", "null_counts", "nbytes")
+
+    def __init__(self, schema, num_rows: int,
+                 null_counts: Dict[str, int]) -> None:
+        self.schema = schema
+        self.num_rows = int(num_rows)
+        self.null_counts = null_counts
+        # Host bytes, charged to the cache's budget like a column.
+        self.nbytes = int(schema.serialize().size) + 16 * len(null_counts)
+
+    @classmethod
+    def of(cls, table, prior: Optional["SideManifest"] = None
+           ) -> "SideManifest":
+        """The manifest of ``table``, widened by ``prior``'s columns (the
+        same rows under another projection)."""
+        fields = list(prior.schema) if prior is not None else []
+        counts = dict(prior.null_counts) if prior is not None else {}
+        for field in table.schema:
+            if field.name not in counts:
+                fields.append(field)
+                counts[field.name] = table.column(field.name).null_count
+        import pyarrow as pa
+
+        return cls(pa.schema(fields), table.num_rows, counts)
 
 
 class ByteBudgetLRU:
@@ -178,6 +230,28 @@ class DeviceColumnCache(ByteBudgetLRU):
     def put(self, key: Key, arr, budget_bytes: int) -> None:  # type: ignore[override]
         super().put(key, arr, int(getattr(arr, "nbytes", 0) or 0),
                     budget_bytes)
+
+    def side_manifest(self, fp: str) -> Optional[SideManifest]:
+        """The :class:`SideManifest` under ``fp``, freshened in the LRU, or
+        None.  A host record, not a device column: the lookup leaves the
+        ``cache.device.hits/misses`` counters alone."""
+        key = side_manifest_key(fp)
+        with self._lock:
+            value = self._entries.get(key)
+            if value is not None:
+                self._entries.move_to_end(key)
+            return value
+
+    def put_side(self, fp: str, table, budget_bytes: int) -> None:
+        """Record ``table``'s :class:`SideManifest` under ``fp``, widening
+        a manifest of the same rows that lacks some of its columns."""
+        key = side_manifest_key(fp)
+        prior = self.peek(key)
+        if prior is not None:
+            if all(n in prior.null_counts for n in table.column_names):
+                return
+            self.pop(key)
+        self.put(key, SideManifest.of(table, prior), budget_bytes)
 
 
 # One cache per process: device memory is a process-level resource, and

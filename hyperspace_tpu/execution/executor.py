@@ -90,6 +90,11 @@ class Executor:
         # id(table) -> (fingerprint, cacheable column names, table ref —
         # kept so the id can't be recycled mid-query).
         self._scan_fp: Dict[int, Tuple[str, frozenset, pa.Table]] = {}
+        # (fingerprint, bytes) of each scanned file list, from one stat
+        # per file per query: a join side's identity resolved from its
+        # plan and the scan that may decode it share the pass.
+        self._file_stats: Dict[Tuple[str, ...],
+                               Tuple[Optional[str], int]] = {}
         # Engine mesh, resolved once per executor (= per collect):
         # ``hyperspace.parallel.mesh.enabled`` gates every sharded
         # dispatch below; None keeps the bit-equal single-device paths.
@@ -105,14 +110,23 @@ class Executor:
         return mesh
 
     # -- HBM-resident column cache ------------------------------------------
-    def _register_scan_identity(self, table: pa.Table, paths) -> None:
+    def _cache_on(self) -> bool:
         conf = self.session.conf
-        if conf.device_cache_policy == "off" or conf.device_cache_bytes <= 0:
-            return
-        from hyperspace_tpu.execution.device_cache import files_fingerprint
+        return conf.device_cache_policy != "off" \
+            and conf.device_cache_bytes > 0
 
-        fp = files_fingerprint(paths)
-        if fp:
+    def _stat_files(self, paths: List[str]) -> Tuple[Optional[str], int]:
+        key = tuple(paths)
+        got = self._file_stats.get(key)
+        if got is None:
+            from hyperspace_tpu.execution.device_cache import stat_files
+
+            got = self._file_stats[key] = stat_files(paths)
+        return got
+
+    def _register_scan_identity(self, table: pa.Table,
+                                fp: Optional[str]) -> None:
+        if fp and self._cache_on():
             self._scan_fp[id(table)] = (
                 fp, frozenset(table.column_names), table)
 
@@ -132,13 +146,12 @@ class Executor:
         impossible."""
         if parent_identity is None or out is None:
             return
-        import hashlib
+        from hyperspace_tpu.execution.device_cache import derive_fingerprint
 
         fp, cacheable = parent_identity
-        derived = hashlib.md5(
-            f"{fp}|{transform}".encode()).hexdigest()
         self._scan_fp[id(out)] = (
-            derived, cacheable & frozenset(out.column_names), out)
+            derive_fingerprint(fp, transform),
+            cacheable & frozenset(out.column_names), out)
 
     def _propagate_identity(self, out: pa.Table, parent: pa.Table) -> None:
         """Row-preserving transforms (column selection) keep the parent's
@@ -866,36 +879,54 @@ class Executor:
             # Statically ineligible: leave the plan alone so the normal
             # path (bucketed host join included) runs untouched.
             return None
-        # Row pre-gate from parquet FOOTERS: when even the upper bound
-        # cannot clear the lowest applicable threshold, the device can
-        # never win — bail before materializing anything so small joins
-        # keep their bucketed host path.
+        a, b = pairs[0]
+        refs = set(plan.group_by)
+        for func, agg_in, _out in plan.aggs:
+            if func != "count_all":
+                refs |= set(_agg_refs(agg_in))
+        # A side whose columns HBM already holds is served from there,
+        # its files unopened; every other side is decoded.
+        left = self._resident_side(child.left, (a, b), refs)
+        right = self._resident_side(child.right, (a, b), refs)
+        # Row pre-gate: a resident side's rows are known, a side still to
+        # decode has an upper bound from its parquet FOOTERS.  When even
+        # that cannot clear the lowest applicable threshold, the device
+        # can never win — bail before materializing anything so small
+        # joins keep their bucketed host path.
         lo_thresh = min(conf.device_min_rows("join_agg"),
                         conf.resident_min_rows("join_agg"))
-        est_l = self._plan_row_upper_bound(child.left)
-        est_r = self._plan_row_upper_bound(child.right)
+        est_l = left.num_rows if left is not None \
+            else self._plan_row_upper_bound(child.left)
+        est_r = right.num_rows if right is not None \
+            else self._plan_row_upper_bound(child.right)
         if est_l is not None and est_r is not None \
                 and max(est_l, est_r) < lo_thresh:
             return None
 
-        left = self.execute(child.left)
-        right = self.execute(child.right)
+        if left is None:
+            left = self._decoded_side(child.left)
+        if right is None:
+            right = self._decoded_side(child.right)
 
         def fallback():
+            self._count_join_sides(0)
+            lt = left.table if left.table is not None \
+                else self.execute(child.left)
+            rt = right.table if right.table is not None \
+                else self.execute(child.right)
             self.stats["joins"].append(
                 {"strategy": "plain", "how": "inner"})
             return ("joined", self._host_join_tables(
-                left, right, child.condition, "inner"))
+                lt, rt, child.condition, "inner"))
 
-        a, b = pairs[0]
-        if a in left.column_names and b in right.column_names:
+        if a in left.names and b in right.names:
             lk_name, rk_name = a, b
-        elif b in left.column_names and a in right.column_names:
+        elif b in left.names and a in right.names:
             lk_name, rk_name = b, a
         else:
             return fallback()
-        if (lk_name == rk_name or lk_name in right.column_names
-                or rk_name in left.column_names):
+        if (lk_name == rk_name or lk_name in right.names
+                or rk_name in left.names):
             # Name present on both sides: the flat column index below
             # couldn't tell them apart.
             return fallback()
@@ -905,27 +936,21 @@ class Executor:
                     right.schema.field(rk_name).type)):
             return fallback()
         # Inner join: null keys never match — drop them up front (with
-        # derived identity so residency carries across repeats).
-        lv, rv = left, right
-        if left.column(lk_name).null_count > 0:
-            lv = left.filter(pc.is_valid(left.column(lk_name)))
-            self._register_derived_identity(
-                lv, self._scan_identity(left), f"dropnull:{lk_name}")
-        if right.column(rk_name).null_count > 0:
-            rv = right.filter(pc.is_valid(right.column(rk_name)))
-            self._register_derived_identity(
-                rv, self._scan_identity(right), f"dropnull:{rk_name}")
+        # derived identity so residency carries across repeats; a
+        # resident side was resolved past its null keys already).
+        lv = self._drop_null_keys(left, lk_name)
+        rv = self._drop_null_keys(right, rk_name)
         if lv.num_rows == 0 or rv.num_rows == 0:
             return fallback()
 
         def side_of(name: str) -> Optional[str]:
-            in_l = name in lv.column_names
-            in_r = name in rv.column_names
+            in_l = name in lv.names
+            in_r = name in rv.names
             if in_l == in_r:  # missing or ambiguous
                 return None
             return "l" if in_l else "r"
 
-        def table_of(side: str) -> pa.Table:
+        def table_of(side: str) -> "_JoinSide":
             return lv if side == "l" else rv
 
         # Group keys: int/bool/temporal (int64 device domain), null-free.
@@ -937,7 +962,7 @@ class Executor:
             if not (pa.types.is_integer(t) or pa.types.is_boolean(t)
                     or pa.types.is_temporal(t)) or pa.types.is_uint64(t):
                 return fallback()
-            if table_of(side).column(k).null_count > 0:
+            if table_of(side).null_count(k) > 0:
                 return fallback()
         # Aggregate inputs: strictly int/float null-free references;
         # min/max need a plain column (the result restores its type).
@@ -959,13 +984,10 @@ class Executor:
                     build_value_fn(agg_in, sorted(agg_in.referenced_columns()))
                 except ValueError:
                     return fallback()
-            refs = [agg_in.name] if isinstance(agg_in, Col) else (
-                [agg_in] if isinstance(agg_in, str)
-                else list(agg_in.referenced_columns()))
             if func in ("min", "max") and not (
                     isinstance(agg_in, (Col, str))):
                 return fallback()
-            for r in refs:
+            for r in _agg_refs(agg_in):
                 side = side_of(r)
                 if side is None:
                     return fallback()
@@ -974,7 +996,7 @@ class Executor:
                         or pa.types.is_floating(t)) \
                         or pa.types.is_uint64(t):
                     return fallback()
-                if table_of(side).column(r).null_count > 0:
+                if table_of(side).null_count(r) > 0:
                     return fallback()
                 agg_ref_names.append(r)
 
@@ -982,8 +1004,7 @@ class Executor:
         # threshold when every referenced column of a side is cached
         # (or will be) for that side's — possibly filter-derived —
         # identity.
-        id_l = self._scan_identity(lv)
-        id_r = self._scan_identity(rv)
+        id_l, id_r = lv.identity, rv.identity
         need_l = sorted({lk_name} | {
             c for c in set(plan.group_by) | set(agg_ref_names)
             if side_of(c) == "l"})
@@ -1008,9 +1029,14 @@ class Executor:
             use_device = eff < cold and max_rows >= eff
         if not use_device and not use_mesh:
             return fallback()
-        resident = self._all_resident(id_l, pl) \
-            and self._all_resident(id_r, pr)
+        resident = (lv.arrays is not None or self._all_resident(id_l, pl)) \
+            and (rv.arrays is not None or self._all_resident(id_r, pr))
         use_mesh = use_mesh and not resident
+        if use_mesh:
+            # The sharded path takes host arrays: decode a side that HBM
+            # would have served.
+            lv = self._decoded_if_resident(lv, child.left, lk_name)
+            rv = self._decoded_if_resident(rv, child.right, rk_name)
 
         # Device arrays for every referenced column (cache-aware); the
         # mesh path takes HOST arrays instead — sharded placement is its
@@ -1020,11 +1046,10 @@ class Executor:
         col_ix = {c: i for i, (_s, c) in enumerate(ref_order)}
         if use_mesh:
             columns = [np.asarray(columnar.to_device_numeric(
-                table_of(s).column(c))) for s, c in ref_order]
+                table_of(s).table.column(c))) for s, c in ref_order]
         else:
-            columns = [self._device_column(
-                table_of(s), c, id_l if s == "l" else id_r, "num")
-                for s, c in ref_order]
+            columns = [self._side_column(table_of(s), c)
+                       for s, c in ref_order]
         sides = [s for s, _c in ref_order]
         group_ix = [col_ix[k] for k in plan.group_by]
         value_fns, lits_list, agg_ops = [], [], []
@@ -1047,31 +1072,30 @@ class Executor:
         )
 
         if use_mesh:
-            li_first, ri_first, counts, results = \
-                join_group_aggregate_mesh(
-                    columns[col_ix[lk_name]], columns[col_ix[rk_name]],
-                    columns, sides, group_ix, agg_ops, value_fns,
-                    lits_list, mesh, pad_to=conf.device_batch_rows)
+            counts, results, key_values = join_group_aggregate_mesh(
+                columns[col_ix[lk_name]], columns[col_ix[rk_name]],
+                columns, sides, group_ix, agg_ops, value_fns, lits_list,
+                mesh, pad_to=conf.device_batch_rows)
         else:
-            li_first, ri_first, counts, results = join_group_aggregate(
+            counts, results, key_values = join_group_aggregate(
                 columns[col_ix[lk_name]], columns[col_ix[rk_name]],
                 columns, sides, group_ix, agg_ops, value_fns, lits_list,
                 topn=topn)
+        n_resident = (lv.table is None) + (rv.table is None)
         self.stats["joins"].append({
             "strategy": "mesh-fused-agg" if use_mesh
             else "device-fused-agg", "how": "inner",
-            "resident": resident})
+            "resident": resident, "resident_sides": n_resident})
         self.stats.setdefault("aggregates", []).append({
             "strategy": "mesh-join-agg" if use_mesh
             else "device-join-agg", "groups": int(len(counts)),
             "rows": int(max_rows), "resident": resident,
             "topn": None if topn is None else int(topn[2])})
-        data = {}
-        for k in plan.group_by:
-            if side_of(k) == "l":
-                data[k] = lv.column(k).take(pa.array(li_first))
-            else:
-                data[k] = rv.column(k).take(pa.array(ri_first))
+        # Group keys come back in the int64 domain (a resident side has no
+        # host table to take them from): cast to each column's type.
+        data = {k: columnar.from_device_int64(
+                    key_values[i], table_of(side_of(k)).schema.field(k).type)
+                for i, k in enumerate(plan.group_by)}
         # `results` is aligned with plan.aggs: the segment kernel emits
         # one output per op (count slots carry the group counts).
         for (func, agg_in, out_name), res in zip(plan.aggs, results):
@@ -1086,7 +1110,126 @@ class Executor:
                 data[out_name] = pa.array(res.astype(np.float64))
             else:  # sum: dtype carried by the device result
                 data[out_name] = pa.array(res)
+        if not use_mesh:
+            self._remember_join_sides((left, lv, right, rv))
+        self._count_join_sides(n_resident)
         return ("done", pa.table(data))
+
+    def _decoded_side(self, node) -> "_JoinSide":
+        table = self.execute(node)
+        return _JoinSide.decoded(table, self._scan_identity(table))
+
+    def _decoded_if_resident(self, side: "_JoinSide", node,
+                             key: str) -> "_JoinSide":
+        if side.table is not None:
+            return side
+        return self._drop_null_keys(self._decoded_side(node), key)
+
+    def _drop_null_keys(self, side: "_JoinSide", key: str) -> "_JoinSide":
+        if side.table is None or side.null_count(key) == 0:
+            return side
+        out = side.table.filter(pc.is_valid(side.table.column(key)))
+        self._register_derived_identity(out, side.identity,
+                                        f"dropnull:{key}")
+        return _JoinSide.decoded(out, self._scan_identity(out))
+
+    def _side_column(self, side: "_JoinSide", column: str):
+        if side.arrays is not None:
+            return side.arrays[column]
+        return self._device_column(side.table, column, side.identity, "num")
+
+    def _resident_side(self, node, pair, refs) -> Optional["_JoinSide"]:
+        """``node`` as a fused join's side served from HBM with none of
+        its files opened, or None to decode it.
+
+        The side must be ``(Project|Filter)*`` over a bucketed index
+        scan.  Its identity comes from the plan alone: the fingerprint of
+        the bucket-pruned file list, derived through each Filter's
+        predicate bottom-up as ``_filter`` derives it, and through
+        ``dropnull:<key>`` where the side's manifest counts null keys.
+        The side is resident when the device cache holds that identity's
+        manifest (``device_cache.SideManifest``) and every column of
+        ``refs`` and the join key that it outputs.  The arrays are taken
+        before the side counts as resident: an eviction in between
+        decodes it.  The resolution runs in an ``exec.scan`` span tagged
+        ``decoded=False``, whatever it finds."""
+        if not self._cache_on():
+            return None
+        wrappers, scan = _unwrap_chain(node)
+        if not _is_bucketed_index_scan(scan) or scan.relation.hypothetical:
+            return None
+        try:
+            scanned = self.session.schema_of(scan)  # planning cached it
+        except Exception:  # noqa: BLE001 — unreadable: decode says why
+            return None
+        names = _chain_columns(wrappers, scanned)
+        keys = [c for c in pair if names is not None and c in names]
+        if len(keys) != 1:
+            return None
+        from hyperspace_tpu.execution.device_cache import (
+            derive_fingerprint,
+            global_cache,
+        )
+
+        rel = scan.relation
+        with span("exec.scan", relation=rel.index_scan_of, is_index=True,
+                  decoded=False, resident=False) as sp:
+            listed = list(rel.file_paths)
+            paths = _prune_to_buckets(listed, rel.prune_to_buckets)
+            fp, nbytes = self._stat_files(paths)
+            if not paths or fp is None:
+                return None
+            for w in reversed(wrappers):
+                if isinstance(w, Filter):
+                    fp = derive_fingerprint(fp, f"filter:{w.condition!r}")
+            cache = global_cache()
+            manifest = cache.side_manifest(fp)
+            if manifest is not None \
+                    and manifest.null_counts.get(keys[0], 0) > 0:
+                fp = derive_fingerprint(fp, f"dropnull:{keys[0]}")
+                manifest = cache.side_manifest(fp)
+            if manifest is None or not all(n in manifest.null_counts
+                                           for n in names):
+                return None
+            col_keys = {c: (fp, c, "num")
+                        for c in {keys[0]} | (set(refs) & set(names))}
+            if not all(cache.contains(k) for k in col_keys.values()):
+                return None
+            arrays = {c: cache.get(k) for c, k in col_keys.items()}
+            if any(arr is None for arr in arrays.values()):
+                return None  # evicted since the probe
+            self.stats.setdefault(
+                "device_cache", {"hits": 0, "misses": 0})["hits"] += \
+                len(arrays)
+            record = self._scan_record(rel, paths, listed, nbytes,
+                                       decoded=False)
+            sp.set(**record, rows=manifest.num_rows, resident=True)
+        schema = pa.schema([manifest.schema.field(n) for n in names])
+        return _JoinSide(None, schema, manifest.num_rows,
+                         {n: manifest.null_counts[n] for n in names},
+                         (fp, frozenset(names)), arrays, record)
+
+    def _remember_join_sides(self, sides) -> None:
+        """After a fused run: a manifest for each side it decoded (so a
+        repeat can serve the side from HBM), the scan record of each side
+        it did not."""
+        from hyperspace_tpu.execution.device_cache import global_cache
+
+        budget = self.session.conf.device_cache_bytes
+        for side in sides:
+            if side.table is not None:
+                if side.identity is not None:
+                    global_cache().put_side(side.identity[0], side.table,
+                                            budget)
+            elif side.scan_record is not None:
+                self._log_scan(side.scan_record)
+                side.scan_record = None  # a side listed twice logs once
+
+    def _count_join_sides(self, resident: int) -> None:
+        from hyperspace_tpu.telemetry import metrics
+
+        metrics.inc("exec.join_agg.sides", 2)
+        metrics.inc("exec.join_agg.sides_resident", resident)
 
     # -- scan ---------------------------------------------------------------
     def _scan(self, plan: Scan, columns: Optional[List[str]] = None) -> pa.Table:
@@ -1124,37 +1267,17 @@ class Executor:
             else:
                 paths = [f.name for f in list_data_files(rel.root_paths)]
             all_paths = paths
-            if rel.prune_to_buckets is not None:
-                wanted = set(rel.prune_to_buckets)
-                paths = [p for p in paths
-                         if (b := bucket_id_of_file(p)) is None
-                         or b in wanted]
-            # Bytes are measured by stat (the files are about to be read,
-            # so the inodes are hot); a vanished file surfaces in
+            paths = _prune_to_buckets(paths, rel.prune_to_buckets)
+            # One stat per file gives the bytes about to be read and the
+            # cache fingerprint, taken before the read: a file rewritten
+            # in between keys its new rows under the old identity, which
+            # no later scan resolves.  A vanished file surfaces in
             # read_table with a better error than here.
-            bytes_read = 0
-            for p in paths:
-                try:
-                    bytes_read += os.path.getsize(p)
-                except OSError:
-                    pass
-        scan_record = {
-            "relation": rel.index_scan_of or ",".join(rel.root_paths),
-            "is_index": bool(rel.index_scan_of),
-            "files_read": len(paths),
-            "files_listed": len(all_paths),
-            "bytes_read": bytes_read,
-        }
-        self.stats["scans"].append(scan_record)
-        sp.set(relation=rel.index_scan_of or ",".join(rel.root_paths),
-               is_index=bool(rel.index_scan_of), files_read=len(paths),
-               files_listed=len(all_paths), bytes_read=bytes_read)
-        # The run report carries per-scan IO too: it is what the advisor's
-        # workload capture consumes (bytes actually scanned per relation)
-        # and what "why was my query slow" reads (telemetry/report.py).
-        from hyperspace_tpu.telemetry import report as run_report
-
-        run_report.record("scan", **scan_record)
+            fp, bytes_read = self._stat_files(paths)
+        scan_record = self._scan_record(rel, paths, all_paths, bytes_read,
+                                        decoded=True)
+        self._log_scan(scan_record)
+        sp.set(**scan_record)
         if not paths:
             # Bucket pruning removed every file (key hashes to an empty
             # bucket): the result is empty but MUST keep the scan schema so
@@ -1181,9 +1304,31 @@ class Executor:
         if columns:
             out = out.select(columns)
         scan_record["rows"] = out.num_rows
-        with span("scan.identity"):
-            self._register_scan_identity(out, paths)
+        self._register_scan_identity(out, fp)
         return out
+
+    @staticmethod
+    def _scan_record(rel, paths, all_paths, bytes_read: int,
+                     decoded: bool) -> dict:
+        """One scan's record; ``decoded`` False when its rows came from
+        HBM and none of its files was opened."""
+        return {
+            "relation": rel.index_scan_of or ",".join(rel.root_paths),
+            "is_index": bool(rel.index_scan_of),
+            "files_read": len(paths),
+            "files_listed": len(all_paths),
+            "bytes_read": bytes_read,
+            "decoded": decoded,
+        }
+
+    def _log_scan(self, scan_record: dict) -> None:
+        self.stats["scans"].append(scan_record)
+        # The run report carries per-scan IO too: it is what the advisor's
+        # workload capture consumes (bytes actually scanned per relation)
+        # and what "why was my query slow" reads (telemetry/report.py).
+        from hyperspace_tpu.telemetry import report as run_report
+
+        run_report.record("scan", **scan_record)
 
     # -- filter -------------------------------------------------------------
     def _filter(self, plan: Filter) -> pa.Table:
@@ -2011,6 +2156,71 @@ def _is_appended_branch(node: LogicalPlan) -> bool:
     marks it."""
     return any(s.relation.hybrid_appended_of is not None
                for s in node.leaf_relations())
+
+
+class _JoinSide:
+    """One side of a fused join-aggregate as its checks see it: a decoded
+    ``table``, or (``table`` None) a side served from HBM, described by
+    its manifest, with the device ``arrays`` the join needs already
+    taken and the ``scan_record`` of the scan it skipped."""
+
+    __slots__ = ("table", "schema", "names", "num_rows", "null_counts",
+                 "identity", "arrays", "scan_record")
+
+    def __init__(self, table, schema, num_rows: int, null_counts,
+                 identity, arrays=None, scan_record=None) -> None:
+        self.table = table
+        self.schema = schema
+        self.names = frozenset(schema.names)
+        self.num_rows = num_rows
+        self.null_counts = null_counts
+        self.identity = identity
+        self.arrays = arrays
+        self.scan_record = scan_record
+
+    @classmethod
+    def decoded(cls, table: pa.Table, identity) -> "_JoinSide":
+        return cls(table, table.schema, table.num_rows, None, identity)
+
+    def null_count(self, name: str) -> int:
+        if self.table is not None:
+            return self.table.column(name).null_count
+        return self.null_counts[name]
+
+
+def _agg_refs(agg_in) -> List[str]:
+    """Columns an aggregate's input references."""
+    if isinstance(agg_in, Col):
+        return [agg_in.name]
+    if isinstance(agg_in, str):
+        return [agg_in]
+    return list(agg_in.referenced_columns())
+
+
+def _prune_to_buckets(paths: List[str], buckets) -> List[str]:
+    """The bucket-pruning read: drop index files whose bucket id (from
+    the file name) is not in ``buckets``; None keeps every file."""
+    if buckets is None:
+        return paths
+    wanted = set(buckets)
+    return [p for p in paths
+            if (b := bucket_id_of_file(p)) is None or b in wanted]
+
+
+def _chain_columns(wrappers: List[LogicalPlan],
+                   scanned: List[str]) -> Optional[List[str]]:
+    """Output columns of a ``(Project|Filter)*`` chain over a scan of
+    ``scanned`` columns; None when a node names a column its child
+    lacks."""
+    avail = list(scanned)
+    for w in reversed(wrappers):  # innermost first
+        need = w.columns if isinstance(w, Project) \
+            else w.condition.referenced_columns()
+        if not set(need) <= set(avail):
+            return None
+        if isinstance(w, Project):
+            avail = list(w.columns)
+    return avail
 
 
 def _is_bucketed_index_scan(node: LogicalPlan) -> bool:

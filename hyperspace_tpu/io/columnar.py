@@ -182,6 +182,18 @@ def to_device_numeric(column: "pa.ChunkedArray | pa.Array") -> Optional[np.ndarr
     return _numeric_int64(column, fill_null_zero=False)
 
 
+def from_device_int64(values: np.ndarray, t: pa.DataType) -> pa.Array:
+    """Inverse of ``to_device_numeric`` over its int64 domain (integers,
+    bools, temporals in their own storage unit): the values as an arrow
+    array of type ``t``."""
+    values = np.asarray(values, dtype=np.int64)
+    if pa.types.is_boolean(t):
+        return pa.array(values != 0)
+    if pa.types.is_date32(t) or pa.types.is_time32(t):
+        return pa.array(values.astype(np.int32)).cast(t)
+    return pa.array(values).cast(t)
+
+
 def literal_to_numeric(value, t: pa.DataType) -> Optional[float]:
     """Normalize a literal to ``to_device_numeric``'s domain for a column of
     type ``t``; None if the literal doesn't fit that domain."""

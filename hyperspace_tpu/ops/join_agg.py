@@ -17,11 +17,11 @@ pipeline keeps the intermediate entirely in HBM:
      elementwise on the gathered arrays (ops/filter.build_value_fn);
   4. group-by via the segment machinery (ops/aggregate._group_sort /
      _segment_reduce) — second host sync for the group count;
-  5. only the per-group results cross back to host: counts, reductions,
-     and one (left, right) row-index pair per group so the executor can
-     take the group-key VALUES from the host arrow tables in their exact
-     original types (in group slots of the power of two above the group
-     count, padded toward half the join's match capacity by at most 8
+  5. only the per-group results cross back to host: counts, reductions
+     and the group-key values in their int64 domain, which the executor
+     casts back to the columns' arrow types — so a side served from HBM
+     needs no host table (in group slots of the power of two above the
+     group count, padded toward half the join's match capacity by at most 8
      times, so a moving group count keeps its programs).
 
 Reference contract: Spark executes the rewritten plans of
@@ -76,6 +76,26 @@ def _topk_groups(col, n_valid, *, k: int, ascending: bool,
     return idx
 
 
+@jax.jit
+def _stack_rows(cols, rows):
+    return jnp.stack([c[rows].astype(jnp.int64) for c in cols])
+
+
+def _group_keys_at(gathered, group_col_ix, rows) -> np.ndarray:
+    """The group columns' int64-domain values at the joined ``rows`` (each
+    group's first), gathered on device and pulled in one transfer."""
+    if not group_col_ix:
+        return np.empty((0, int(rows.shape[0])), np.int64)
+    return sync_guard.pull(
+        _stack_rows(tuple(gathered[i] for i in group_col_ix), rows),
+        "join_agg.group_keys")
+
+
+def _empty_result(n_aggs: int, n_group_cols: int):
+    return (np.empty(0, np.int32), [np.empty(0) for _ in range(n_aggs)],
+            np.empty((n_group_cols, 0), np.int64))
+
+
 def _int_order_words(x: jnp.ndarray) -> jnp.ndarray:
     """(n, 2) uint32 monotone order words from an int64-domain array
     (ints, bools, temporals in their numeric normalization): flip the
@@ -109,7 +129,7 @@ def join_group_aggregate_mesh(
     literals: Sequence[Sequence[float]],
     mesh,
     pad_to: int = 0,
-) -> Tuple[np.ndarray, np.ndarray, np.ndarray, List[np.ndarray]]:
+) -> Tuple[np.ndarray, List[np.ndarray], np.ndarray]:
     """Sharding-aware entry of the join→aggregate pipeline: the same
     result contract as :func:`join_group_aggregate` (groups ascending by
     key), computed as three mesh stages —
@@ -138,15 +158,11 @@ def join_group_aggregate_mesh(
     r_key = np.asarray(r_key)
     host_cols = [np.asarray(c) for c in columns]
 
-    def _empty():
-        return (np.empty(0, np.int64), np.empty(0, np.int64),
-                np.empty(0, np.int32), [np.empty(0) for _ in agg_ops])
-
     if l_key.shape[0] == 0 or r_key.shape[0] == 0:
-        return _empty()
+        return _empty_result(len(agg_ops), len(group_col_ix))
     li, ri = sorted_equi_join_mesh(l_key, r_key, mesh)
     if li.size == 0:
-        return _empty()
+        return _empty_result(len(agg_ops), len(group_col_ix))
     gathered = [c[li if side == "l" else ri]
                 for c, side in zip(host_cols, column_sides)]
     key_words = [_int_order_words_np(gathered[i]) for i in group_col_ix]
@@ -159,9 +175,10 @@ def join_group_aggregate_mesh(
         for fn, lits in zip(value_fns, literals)]
     first_rows, counts, results = mesh_grouped_aggregate(
         key_words, value_cols, agg_ops, mesh, pad_to=pad_to)
-    li_first = li[first_rows.astype(np.int64)]
-    ri_first = ri[first_rows.astype(np.int64)]
-    return li_first, ri_first, counts, results
+    first_rows = first_rows.astype(np.int64)
+    keys = np.stack([gathered[i][first_rows] for i in group_col_ix]
+                    ).astype(np.int64).reshape(len(group_col_ix), -1)
+    return counts, results, keys
 
 
 def join_group_aggregate(
@@ -174,7 +191,7 @@ def join_group_aggregate(
     value_fns: Sequence[Callable],
     literals: Sequence[Sequence[float]],
     topn: Optional[Tuple[int, bool, int]] = None,
-) -> Tuple[np.ndarray, np.ndarray, np.ndarray, List[np.ndarray]]:
+) -> Tuple[np.ndarray, List[np.ndarray], np.ndarray]:
     """Inner-join two sides on single numeric keys, then group-aggregate
     the joined rows — all on device.
 
@@ -195,11 +212,12 @@ def join_group_aggregate(
         O(groups) to O(k)).
 
     Returns:
-      (li_first, ri_first, counts, results): per group, the ORIGINAL
-      (left, right) row indices of its first joined row — the executor
-      takes group-key values from the host tables with these — plus row
-      counts and one result array per aggregate.
+      (counts, results, keys): per group, its row count, one result
+      array per aggregate, and — a (len(group_col_ix), groups) int64
+      array pulled in one transfer — the group keys' values at its first
+      joined row, which the executor casts back to the columns' types.
     """
+
     from hyperspace_tpu.telemetry import timeline
     from hyperspace_tpu.utils.xla_cache import ensure_persistent_xla_cache
 
@@ -214,8 +232,7 @@ def join_group_aggregate(
     with _enable_x64():
         n_left, n_right = int(l_key.shape[0]), int(r_key.shape[0])
         if n_left == 0 or n_right == 0:
-            return (np.empty(0, np.int64), np.empty(0, np.int64),
-                    np.empty(0, np.int32), [np.empty(0) for _ in agg_ops])
+            return _empty_result(len(agg_ops), len(group_col_ix))
         # Keys padded to a power of two (ops/join.sorted_equi_join's
         # capacity contract): padded rows match nothing, so the match
         # indices only ever address the real rows of ``columns``.
@@ -226,8 +243,7 @@ def join_group_aggregate(
         total = int(sync_guard.scalar(total, "join_agg.matches"))
         if total == 0:
             timeline.kernel_end("join_agg", t0, (lo, hi))
-            return (np.empty(0, np.int64), np.empty(0, np.int64),
-                    np.empty(0, np.int32), [np.empty(0) for _ in agg_ops])
+            return _empty_result(len(agg_ops), len(group_col_ix))
         capacity = round_up_pow2(total)
         li, ri = _join_pairs(r_perm, lo, hi, capacity)
         # Host columns are padded like their side's key, so a side whose
@@ -253,8 +269,7 @@ def join_group_aggregate(
         g = int(sync_guard.scalar(n_groups, "join_agg.groups"))
         if g == 0:
             timeline.kernel_end("join_agg", t0, perm)
-            return (np.empty(0, np.int64), np.empty(0, np.int64),
-                    np.empty(0, np.int32), [np.empty(0) for _ in agg_ops])
+            return _empty_result(len(agg_ops), len(group_col_ix))
         # Group slots: the power of two above g, padded toward half the
         # match capacity by at most 8 times.  A group count that moves
         # with a growing table (a new snapshot's rows) keeps its programs
@@ -270,23 +285,16 @@ def join_group_aggregate(
             sel = _topk_groups(out[2 + agg_i], g, k=k_eff,
                                ascending=bool(ascending), capacity=gcap)
             timeline.kernel_end("join_agg", t0, (out, sel))
-            first_rows = out[0][sel]
-            li_first = sync_guard.pull(
-                li[first_rows], "join_agg.li_first").astype(np.int64)
-            ri_first = sync_guard.pull(
-                ri[first_rows], "join_agg.ri_first").astype(np.int64)
             counts = sync_guard.pull(out[1][sel], "join_agg.counts")
             results = [sync_guard.pull(r[sel], "join_agg.results")
                        for r in out[2:]]
-            return li_first, ri_first, counts, results
+            keys = _group_keys_at(gathered, group_col_ix, out[0][sel])
+            return counts, results, keys
         timeline.kernel_end("join_agg", t0, out)
         # Gathered over all ``gcap`` slots and cut on host: programs sized
         # by the exact group count would compile anew whenever it moves.
-        li_first = sync_guard.pull(
-            li[out[0]], "join_agg.li_first")[:g].astype(np.int64)
-        ri_first = sync_guard.pull(
-            ri[out[0]], "join_agg.ri_first")[:g].astype(np.int64)
         counts = sync_guard.pull(out[1], "join_agg.counts")[:g]
         results = [sync_guard.pull(r, "join_agg.results")[:g]
                    for r in out[2:]]
-    return li_first, ri_first, counts, results
+        keys = _group_keys_at(gathered, group_col_ix, out[0])[:, :g]
+    return counts, results, keys

@@ -18,8 +18,8 @@ import pytest
 from hyperspace_tpu import HyperspaceSession, col
 from hyperspace_tpu.execution.device_cache import (
     DeviceColumnCache,
-    files_fingerprint,
     global_cache,
+    stat_files,
 )
 
 
@@ -53,18 +53,48 @@ class TestLRU:
         assert not c.contains(("f", "b", "num"))
         assert c.stats()["hits"] == 0 and c.stats()["misses"] == 0
 
+    def test_side_manifest_lookup_counts_no_hit_or_miss(self):
+        """A manifest is a host record: looking it up, found or not,
+        leaves the device columns' hit and miss counts alone, and a
+        found one is freshened in the LRU."""
+        c = DeviceColumnCache()
+        c.put_side("fp", pa.table({"k": [1, None]}), budget_bytes=10_000)
+        m = c.side_manifest("fp")
+        assert (m.num_rows, m.null_counts) == (2, {"k": 1})
+        assert c.side_manifest("other") is None
+        budget = m.nbytes + 150
+        c.put(("f", "a", "num"), _FakeArray(100), budget_bytes=budget)
+        assert c.side_manifest("fp") is m
+        c.put(("f", "b", "num"), _FakeArray(100), budget_bytes=budget)
+        assert c.side_manifest("fp") is m
+        assert not c.contains(("f", "a", "num"))
+        assert c.stats()["hits"] == 0 and c.stats()["misses"] == 0
+
 
 class TestFingerprint:
     def test_changes_with_content_identity(self, tmp_path):
         p = tmp_path / "x.parquet"
         p.write_bytes(b"aaaa")
-        fp1 = files_fingerprint([str(p)])
-        assert fp1 == files_fingerprint([str(p)])
+        fp1, size = stat_files([str(p)])
+        assert (fp1, size) == (stat_files([str(p)])[0], 4)
         p.write_bytes(b"bbbbbb")  # size + mtime change
-        assert files_fingerprint([str(p)]) != fp1
+        assert stat_files([str(p)])[0] != fp1
 
     def test_missing_file_yields_none(self, tmp_path):
-        assert files_fingerprint([str(tmp_path / "gone.parquet")]) is None
+        p = tmp_path / "x.parquet"
+        p.write_bytes(b"aaaa")
+        gone = str(tmp_path / "gone.parquet")
+        assert stat_files([str(p), gone]) == (None, 4)
+
+    def test_order_is_part_of_identity(self, tmp_path):
+        paths = []
+        for i, name in enumerate(["a.parquet", "b.parquet"]):
+            p = tmp_path / name
+            p.write_bytes(b"x" * (i + 1))
+            paths.append(str(p))
+        fwd, back = stat_files(paths), stat_files(paths[::-1])
+        assert fwd[1] == back[1] == 3 and fwd[0] != back[0]
+        assert stat_files([])[1] == 0
 
 
 @pytest.fixture()
@@ -438,6 +468,201 @@ def test_null_keys_resident_join_matches_host(join_env, tmp_path):
     s.conf.device_join_min_rows = 1 << 60
     host = q()
     assert host.num_rows == first.num_rows == second.num_rows
+
+
+# -- join sides served from HBM without decode -------------------------------
+
+# A group column of each type the fused path admits as a group key.
+_GROUP_TYPES = ("g_i32", "g_i64", "g_date", "g_ts", "g_bool")
+
+
+def _typed_orders(path: str, null_keys: bool = False) -> None:
+    n = 8_000
+    k = np.arange(n, dtype=np.int64)
+    keys = pa.array(k)
+    if null_keys:
+        keys = pa.array([None if i % 7 == 0 else int(i) for i in k],
+                        type=pa.int64())
+    os.makedirs(path)
+    pq.write_table(pa.table({
+        "o_orderkey": keys,
+        "o_totalprice": pa.array(np.linspace(0, 100_000, n)),
+        "g_i32": pa.array((k % 7 - 3).astype(np.int32)),
+        "g_i64": pa.array((k % 11) * 3_000_000_007 - 10**10),
+        "g_date": pa.array((k % 13 + 9_000).astype(np.int32)).cast(
+            pa.date32()),
+        "g_ts": pa.array(k % 5 * 1_000_003 + 1_600_000_000_000_000).cast(
+            pa.timestamp("us")),
+        "g_bool": pa.array(k % 3 == 0),
+    }), os.path.join(path, "p.parquet"))
+
+
+@pytest.fixture()
+def indexed_join_env(join_env, tmp_path):
+    """``join_env``'s lineitem and an orders table holding a group column
+    of each admitted type, both under covering indexes, so the join rule
+    makes each side a filter chain over a bucketed index scan; the
+    ``eager`` policy fills the device cache on first use."""
+    from hyperspace_tpu import Hyperspace, IndexConfig
+
+    s, _left, right_dir = join_env
+    dirs = {"plain": str(tmp_path / "orders_typed"),
+            "null_keys": str(tmp_path / "orders_null_keys")}
+    _typed_orders(dirs["plain"])
+    _typed_orders(dirs["null_keys"], null_keys=True)
+    s.conf.num_buckets = 4
+    s.conf.device_cache_policy = "eager"
+    s.conf.device_resident_min_rows = 1
+    hs = Hyperspace(s)
+    for name, d in dirs.items():
+        hs.create_index(s.read.parquet(d), IndexConfig(
+            f"ord_{name}", ["o_orderkey"], ["o_totalprice", *_GROUP_TYPES]))
+    hs.create_index(s.read.parquet(right_dir),
+                    IndexConfig("li", ["l_orderkey"], ["l_quantity"]))
+    s.enable_hyperspace()
+    global_cache().clear()
+    return s, dirs, right_dir
+
+
+def _side_q(s, orders_dir, lineitem_dir, group, price_cap=60_000.0):
+    return (s.read.parquet(orders_dir)
+            .filter(col("o_totalprice") < price_cap)
+            .join(s.read.parquet(lineitem_dir),
+                  col("o_orderkey") == col("l_orderkey"))
+            .group_by(group)
+            .agg(q=(col("l_quantity"), "sum"), n=(col("l_quantity"), "count"))
+            .collect().sort_by(group))
+
+
+def _side_host(orders_dir, lineitem_dir, group, price_cap=60_000.0):
+    """The same query in plain pyarrow."""
+    import pyarrow.compute as pc
+
+    o = pq.read_table(orders_dir)
+    o = o.filter(pc.less(o["o_totalprice"], price_cap))
+    j = o.join(pq.read_table(lineitem_dir), "o_orderkey", "l_orderkey")
+    out = j.group_by(group).aggregate([("l_quantity", "sum"),
+                                       ("l_quantity", "count")])
+    return out.rename_columns(
+        {"l_quantity_sum": "q", "l_quantity_count": "n"}).select(
+        [group, "q", "n"]).sort_by(group)
+
+
+def _side_stats(s):
+    st = s.last_execution_stats
+    return ({sc["relation"]: sc["decoded"] for sc in st["scans"]},
+            st["joins"][-1])
+
+
+def _sides_counted(before):
+    from hyperspace_tpu.telemetry import metrics
+
+    now = metrics.snapshot()
+    return tuple(now.get(k, 0.0) - before.get(k, 0.0)
+                 for k in ("exec.join_agg.sides",
+                           "exec.join_agg.sides_resident"))
+
+
+def _no_file_reads(monkeypatch):
+    from hyperspace_tpu.execution import executor
+
+    def refuse(*_a, **_kw):
+        raise AssertionError("a resident side opened a file")
+
+    monkeypatch.setattr(executor, "read_table", refuse)
+
+
+@pytest.mark.parametrize("group,orders", [
+    *[(g, "plain") for g in _GROUP_TYPES], ("g_i64", "null_keys")])
+def test_warm_join_sides_served_from_hbm_without_decode(
+        indexed_join_env, monkeypatch, group, orders):
+    """A warm repeat serves both sides from HBM: no file opened, each
+    group key cast back to its exact type, the answer the cold run and
+    plain pyarrow give.  With null join keys the side resolves through
+    its ``dropnull:`` identity.  The two counters count the sides."""
+    from hyperspace_tpu.telemetry import metrics
+
+    s, dirs, lineitem = indexed_join_env
+    before = metrics.snapshot()
+    cold = _side_q(s, dirs[orders], lineitem, group)
+    assert _sides_counted(before) == (2, 0)
+    decoded, join = _side_stats(s)
+    assert decoded == {f"ord_{orders}": True, "li": True}
+    assert join["strategy"] == "device-fused-agg"
+    assert join["resident_sides"] == 0
+
+    _no_file_reads(monkeypatch)
+    before = metrics.snapshot()
+    warm = _side_q(s, dirs[orders], lineitem, group)
+    assert _sides_counted(before) == (2, 2)
+    assert metrics.snapshot().get("io.files.read", 0) \
+        == before.get("io.files.read", 0)
+    decoded, join = _side_stats(s)
+    assert decoded == {f"ord_{orders}": False, "li": False}
+    assert join["resident_sides"] == 2
+    assert warm.schema.field(group).type == cold.schema.field(group).type
+    assert warm.equals(cold)
+    assert warm.equals(_side_host(dirs[orders], lineitem, group))
+
+
+def test_rewritten_index_file_is_decoded_not_served(indexed_join_env):
+    """A rewritten index file (new size and mtime) changes the side's
+    identity: the side is decoded, and answers from the new file."""
+    s, dirs, lineitem = indexed_join_env
+    _side_q(s, dirs["plain"], lineitem, "g_i32")
+    entry = s.index_collection_manager.get_index("li")
+    path = max((f.name for f in entry.content.file_infos()),
+               key=os.path.getsize)
+    table = pq.read_table(path)
+    pq.write_table(table, path, compression="none")
+    warm = _side_q(s, dirs["plain"], lineitem, "g_i32")
+    decoded, join = _side_stats(s)
+    assert decoded == {"ord_plain": False, "li": True}
+    assert join["resident_sides"] == 1
+    assert warm.equals(_side_host(dirs["plain"], lineitem, "g_i32"))
+
+
+@pytest.mark.parametrize("mesh", [False, True])
+def test_changed_predicate_decodes_its_side_only(indexed_join_env, mesh):
+    """A new predicate is a new identity: that side is decoded while the
+    other, unchanged, is served from HBM, and the answer is right.  Where
+    the join then runs sharded over the mesh, which takes host arrays,
+    the resident side is decoded too."""
+    s, dirs, lineitem = indexed_join_env
+    _side_q(s, dirs["plain"], lineitem, "g_date", price_cap=20_000.0)
+    if mesh:
+        s.conf.mesh_join_min_rows = 1
+    other = _side_q(s, dirs["plain"], lineitem, "g_date",
+                    price_cap=70_000.0)
+    decoded, join = _side_stats(s)
+    assert decoded == {"ord_plain": True, "li": mesh}
+    assert join["strategy"] == ("mesh-fused-agg" if mesh
+                                else "device-fused-agg")
+    assert join["resident_sides"] == (0 if mesh else 1)
+    assert other.equals(_side_host(dirs["plain"], lineitem, "g_date",
+                                   price_cap=70_000.0))
+
+
+def test_eviction_between_check_and_use_decodes(indexed_join_env,
+                                                monkeypatch):
+    """A column evicted after the presence probe and before it is taken
+    leaves its side to decode, and the answer stays right."""
+    s, dirs, lineitem = indexed_join_env
+    _side_q(s, dirs["plain"], lineitem, "g_bool")
+    cache = global_cache()
+    real_get = cache.get
+
+    def get_after_eviction(key):
+        if key[1:] == ("l_quantity", "num"):
+            cache.pop(key)
+        return real_get(key)
+
+    monkeypatch.setattr(cache, "get", get_after_eviction)
+    warm = _side_q(s, dirs["plain"], lineitem, "g_bool")
+    decoded, join = _side_stats(s)
+    assert decoded == {"ord_plain": False, "li": True}
+    assert join["resident_sides"] == 1
+    assert warm.equals(_side_host(dirs["plain"], lineitem, "g_bool"))
 
 
 def test_warm_repeat_window_aggregate_resident(env):

@@ -266,7 +266,7 @@ def _join_count(matched, repeat=3, topn=None):
     try:
         counts = join_agg.join_group_aggregate(
             l_key, r_key, [l_key], ["l"], [0], ["count"], [], [],
-            topn=topn)[2]
+            topn=topn)[0]
     finally:
         join_agg._segment_reduce = real
     return counts, slots

@@ -321,13 +321,13 @@ class TestMeshQueryKernels:
         f2 = join_group_aggregate_mesh(
             l_key, r_key, columns, sides, [3], ["sum", "count_all"],
             [fn], [lits], mesh, pad_to=64)
-        # Same groups in the same (ascending-key) order with the same
-        # exact integer reductions; first-row indices may differ (any
-        # row of the group is a valid witness for the key VALUES).
-        assert np.array_equal(np.asarray(group)[np.asarray(f1[1])],
-                              np.asarray(group)[np.asarray(f2[1])])
+        # Same group-key values in the same (ascending-key) order with
+        # the same exact integer reductions.
         assert np.array_equal(np.asarray(f1[2]), np.asarray(f2[2]))
-        for a, b in zip(f1[3], f2[3]):
+        assert np.array_equal(np.asarray(f1[2][0]),
+                              np.unique(group[np.unique(l_key)]))
+        assert np.array_equal(np.asarray(f1[0]), np.asarray(f2[0]))
+        for a, b in zip(f1[1], f2[1]):
             assert np.array_equal(np.asarray(a), np.asarray(b))
 
     def test_mesh_kernels_attribute_per_device(self):
