@@ -219,17 +219,6 @@ class CreateActionBase(Action):
         self._file_id_tracker = FileIdTracker()
         self._relation_cache = None
 
-    def _publish_build_stats(self) -> None:
-        """This build's phase seconds so far, as ``<phase>_s`` keys, on
-        session.build_stats_log (bench.py attributes build time by it)."""
-        log = getattr(self.session, "build_stats_log", None)
-        if log is None:
-            log = []
-            self.session.build_stats_log = log
-        phases = dict(self.build_report.phases)
-        log.append({"index": self.index_name,
-                    **{f"{k}_s": round(v, 4) for k, v in phases.items()}})
-
     @property
     def conf(self) -> HyperspaceConf:
         return self.session.conf
@@ -333,7 +322,6 @@ class CreateActionBase(Action):
             multihost_build.run_multihost_build(
                 self, files, columns, relation, resolved, lineage,
                 batch_rows)
-            self._publish_build_stats()
             return
         if streaming and resolved.layout == "zorder":
             # Z-order builds beyond one batch take a dedicated two-pass
@@ -342,13 +330,11 @@ class CreateActionBase(Action):
             # samples and gut second-dimension pruning).
             self._zorder_streaming_build(files, columns, relation, lineage,
                                          resolved, batch_rows)
-            self._publish_build_stats()
             return
         spill = _BucketSpill(self, resolved)
         try:
             self._stream_build(files, columns, relation, lineage, resolved,
                                batch_rows, streaming, spill)
-            self._publish_build_stats()
         finally:
             # A FINALLY, not an except: it must join + shut down the
             # route/finalize worker pools and remove the spill dir on

@@ -15,8 +15,7 @@ fingerprint — so the same code works over :class:`PosixLogStore` and
 :class:`EmulatedObjectStore`, survives restarts, and merges across
 processes via generation-CAS.
 
-Cost contract (bench.py ``advisor`` section gates < 3% on the filter
-workload): repeats of a known fingerprint fold into an in-process hit
+Cost contract: repeats of a known fingerprint fold into an in-process hit
 counter and only flush to the store at power-of-two total hit counts (or
 every 32 pending), so the steady-state per-query cost is a plan walk and
 a dict update.  ``flush_pending`` forces the counters out — the
@@ -55,17 +54,10 @@ def workload_root(conf) -> str:
 
 
 def store_for(conf):
-    """The capture store: backend class from
-    ``hyperspace.index.logStoreClass`` (the quarantine manager's exact
-    construction), rooted at the workload dir."""
-    from hyperspace_tpu.exceptions import HyperspaceError
-    from hyperspace_tpu.io.log_store import LogStore
-    from hyperspace_tpu.utils.reflection import load_class
+    """The capture store, rooted at the workload dir."""
+    from hyperspace_tpu.io import log_store
 
-    cls = load_class(conf.log_store_class, LogStore, HyperspaceError)
-    return cls(workload_root(conf),
-               stale_list_s=float(getattr(
-                   conf, "object_store_stale_list_ms", 0.0)) / 1000.0)
+    return log_store.store_for(conf, workload_root(conf))
 
 
 # ---------------------------------------------------------------------------

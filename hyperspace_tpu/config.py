@@ -331,8 +331,8 @@ class HyperspaceConf:
     #     LogStore CAS seam — each host routes claimed chunks, then
     #     finalizes claimed bucket GROUPS into its own staging dir; the
     #     coordinating action CAS-commits the union or nothing.  1 runs
-    #     one subprocess host through the same claim pipeline (the bench
-    #     baseline for the scaling ratio; also handy for debugging the
+    #     one subprocess host through the same claim pipeline (the
+    #     baseline for a scaling ratio; also handy for debugging the
     #     protocol without host interleaving).  0 = the ordinary
     #     single-process build (zero multihost code runs).
     #   - claimTtlS: a work claim expires this long after its last
@@ -394,7 +394,7 @@ class HyperspaceConf:
     # tracing.enabled turns on per-query span trees (disabled cost: one
     # module-global bool check per instrumented site); trace.sink is a
     # JSONL file path every finished root span is appended to — the
-    # machine-readable artifact bench.py and production runs leave.
+    # machine-readable artifact production runs leave.
     # Run reports and the metrics registry are always on (their cost is
     # a contextvar read / a dict increment at file/action granularity).
     telemetry_tracing_enabled: bool = False
@@ -455,13 +455,12 @@ class HyperspaceConf:
     # run records per-phase wall time, bytes moved, spill counts, and
     # memory gauges into a BuildReport (Hyperspace.last_build_report()),
     # exported through the metrics registry (build.phase.*.seconds,
-    # build.spill.bytes, ...).  Disabling keeps the pre-existing
-    # build_stats_log phase seconds but skips the memory sampling,
-    # metric/span export, and the ledger append — the bench
-    # ``build_profile`` section gates the on-vs-off delta < 3%.
+    # build.spill.bytes, ...).  Disabling keeps the phase seconds but
+    # skips the memory sampling, metric/span export, and the ledger
+    # append.
     build_profiling_enabled: bool = True
     # Persistent perf ledger (telemetry/perf_ledger.py): every completed
-    # action (and bench section) appends a compact structured record —
+    # action appends a compact structured record —
     # phases, bytes, outcome, host/jax/conf fingerprint — through the
     # LogStore seam under <systemPath>/_hyperspace_perf, readable via
     # Hyperspace.perf_history() and the interop ``perf_history`` verb.
@@ -477,7 +476,7 @@ class HyperspaceConf:
     #     Off by default: capture writes small files per *distinct* query
     #     shape (repeats fold into a hit counter, flushed at
     #     power-of-two hit counts so the steady-state cost is one dict
-    #     update) — bench gates the overhead < 3% on the filter workload.
+    #     update).
     #   - capture.maxEntries: cap on distinct fingerprints; new shapes
     #     beyond it are dropped (counted in advisor.capture.dropped).
     #   - maxCandidates: how many candidate indexes

@@ -130,8 +130,8 @@ class PlanCache:
                 return plan
             # Stale: an index action landed since, a source version
             # moved, or the TTL passed.  Dropped BEFORE the counting
-            # lookup so the hit-rate the bench reports means "served from
-            # cache", nothing else.
+            # lookup so the hit-rate means "served from cache", nothing
+            # else.
             self._lru.pop(key)
             from hyperspace_tpu.telemetry import metrics
 

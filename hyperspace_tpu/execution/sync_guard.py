@@ -6,7 +6,7 @@ from device only through attributed seams; this module enforces the same
 contract at RUNTIME, where the static pass cannot see — a monkeypatched
 kernel, a REPL experiment, a dependency upgrade that starts calling
 ``.item()`` on our arrays.  Armed, it turns PR 11's ``exec.transfer.*``
-metrics from an observation into a contract tests and bench can assert.
+metrics from an observation into a contract tests can assert.
 
 Two halves:
 

@@ -145,24 +145,20 @@ class Hyperspace:
 
         return last_report()
 
-    def perf_history(self, index: str = None, section: str = None,
-                     limit: int = None) -> pa.Table:
+    def perf_history(self, index: str = None, limit: int = None) -> pa.Table:
         """The persistent perf ledger (telemetry/perf_ledger.py) as an
-        arrow table — one row per recorded action/bench-section run under
+        arrow table — one row per recorded action run under
         ``<systemPath>/_hyperspace_perf``, oldest first, readable over
         both LogStore backends.  Columns: key, kind, name, ts,
         wallSeconds, outcome, phasesJson, bytesWritten, spillBytes,
         recordJson (the full record).
 
         Filters (also on the interop ``perf_history`` verb): ``index``
-        keeps action records for that index, ``section`` keeps bench
-        records for that section, ``limit`` keeps the most recent N
-        after filtering — callers used to re-filter raw records by
-        hand."""
+        keeps action records for that index, ``limit`` keeps the most
+        recent N after filtering."""
         from hyperspace_tpu.telemetry.perf_ledger import history_table
 
-        return history_table(self.session.conf, index=index,
-                             section=section, limit=limit)
+        return history_table(self.session.conf, index=index, limit=limit)
 
     # -- timeline profiler + health doctor (docs/16-observability.md) -------
     def export_timeline(self, path: str, trace_id: str = None,
@@ -454,7 +450,7 @@ class Hyperspace:
         return m.registry().render_prometheus()
 
     def reset_metrics(self) -> None:
-        """Zero every series (tests; a bench section isolating deltas)."""
+        """Zero every series (tests isolating deltas)."""
         from hyperspace_tpu.telemetry import metrics as m
 
         m.reset()

@@ -29,7 +29,7 @@ import time
 import urllib.parse
 from typing import Dict, List, Optional, Set
 
-from hyperspace_tpu.io.log_store import LogStore
+from hyperspace_tpu.io.log_store import LogStore, store_for
 
 QUARANTINE_DIR = "_hyperspace_quarantine"
 
@@ -38,14 +38,8 @@ def quarantine_manager_for(conf, index_path: str) -> "QuarantineManager":
     """The one constructor everyone uses (collection manager, rules,
     repair, vacuum): store backend from ``hyperspace.index.logStoreClass``
     rooted inside the index directory."""
-    from hyperspace_tpu.exceptions import HyperspaceError
-    from hyperspace_tpu.utils.reflection import load_class
-
-    cls = load_class(conf.log_store_class, LogStore, HyperspaceError)
-    store = cls(os.path.join(index_path, QUARANTINE_DIR),
-                stale_list_s=float(getattr(
-                    conf, "object_store_stale_list_ms", 0.0)) / 1000.0)
-    return QuarantineManager(index_path, store)
+    return QuarantineManager(index_path, store_for(
+        conf, os.path.join(index_path, QUARANTINE_DIR)))
 
 
 class QuarantineManager:

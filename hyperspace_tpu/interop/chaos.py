@@ -1,7 +1,7 @@
 """Seeded fleet chaos drill: randomized faults, deterministic schedule,
 checked invariants.
 
-SIGKILL drills (tests/test_fleet_serving.py, bench ``fleet``) prove the
+SIGKILL drills (tests/test_fleet_serving.py) prove the
 fleet survives CLEAN deaths; this module composes every failure mode the
 repo can inject into one reproducible storm against a live 3-server
 fleet under sustained mixed load:
@@ -44,8 +44,8 @@ they are end-state properties:
      the survivor's index is byte-identical to the single-host
      baseline, and the claim journal shows exactly one commit.
 
-Entry points: ``tools/chaos.py`` (CLI), the bench ``chaos`` section,
-and tests/test_chaos.py (smoke + schedule determinism).
+Entry points: ``tools/chaos.py`` (CLI) and tests/test_chaos.py (smoke
++ schedule determinism).
 """
 
 from __future__ import annotations

@@ -1123,15 +1123,12 @@ def _serve_verb(session, spec: Dict[str, Any],
       {"verb": "workload"}         -> the captured advisor workload table
                                       (advisor/workload.py)
       {"verb": "perf_history",
-       "index"?, "section"?,
-       "limit"?}                    -> the persistent perf ledger
+       "index"?, "limit"?}         -> the persistent perf ledger
                                       (telemetry/perf_ledger.py): one row
-                                      per recorded action/bench-section
-                                      run under the serving session's
-                                      systemPath; optional filters —
-                                      ``index`` keeps action records for
-                                      that index, ``section`` keeps
-                                      bench records for that section,
+                                      per recorded action run under the
+                                      serving session's systemPath;
+                                      optional filters — ``index`` keeps
+                                      action records for that index,
                                       ``limit`` the most recent N
       {"verb": "build_report"}     -> one row, column ``report_json`` —
                                       the session's most recent action
@@ -1238,17 +1235,13 @@ def _serve_verb(session, spec: Dict[str, Any],
         from hyperspace_tpu.telemetry.perf_ledger import history_table
 
         index = spec.get("index")
-        section = spec.get("section")
         limit = spec.get("limit")
         if index is not None and not isinstance(index, str):
             raise ValueError('"index" must be a string')
-        if section is not None and not isinstance(section, str):
-            raise ValueError('"section" must be a string')
         if limit is not None and (not isinstance(limit, int)
                                   or isinstance(limit, bool) or limit < 0):
             raise ValueError('"limit" must be a non-negative integer')
-        return history_table(session.conf, index=index, section=section,
-                             limit=limit)
+        return history_table(session.conf, index=index, limit=limit)
     if verb == "build_report":
         report = session.last_build_report_value
         payload = json.dumps(report.to_dict() if report is not None

@@ -285,3 +285,15 @@ class EmulatedObjectStore(PosixLogStore):
 
     def _decode(self, name: str) -> str:
         return urllib.parse.unquote(name)
+
+
+def store_for(conf, root: str) -> LogStore:
+    """The store every subsystem opens under ``root``: backend class
+    from ``hyperspace.index.logStoreClass``, stale-list window from
+    ``hyperspace.system.objectStore.staleListMs``."""
+    from hyperspace_tpu.exceptions import HyperspaceError
+    from hyperspace_tpu.utils.reflection import load_class
+
+    cls = load_class(conf.log_store_class, LogStore, HyperspaceError)
+    return cls(root, stale_list_s=float(getattr(
+        conf, "object_store_stale_list_ms", 0.0)) / 1000.0)

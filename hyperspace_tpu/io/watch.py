@@ -86,7 +86,7 @@ def watch_store_root(conf) -> str:
 
 
 def _store(conf):
-    from hyperspace_tpu.telemetry.perf_ledger import store_for
+    from hyperspace_tpu.io.log_store import store_for
 
     return store_for(conf, watch_store_root(conf))
 

@@ -48,7 +48,7 @@ def journal_root(conf) -> str:
 
 
 def _store(conf):
-    from hyperspace_tpu.telemetry.perf_ledger import store_for
+    from hyperspace_tpu.io.log_store import store_for
 
     return store_for(conf, journal_root(conf))
 

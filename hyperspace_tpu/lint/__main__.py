@@ -61,7 +61,7 @@ def main(argv=None) -> int:
                    help="run only the telemetry-catalog rule (the docs/16 "
                         "contract); combine with --trace")
     p.add_argument("--trace", default=None, metavar="JSONL",
-                   help="also verify a bench JSONL trace: required span "
+                   help="also verify a JSONL trace: required span "
                         "kinds present, every span in the docs/16 taxonomy")
     args = p.parse_args(argv)
 

@@ -262,51 +262,38 @@ def wire_codes(ctx: LintContext) -> Set[str]:
 
 
 # ---------------------------------------------------------------------------
-# Bench-trace span check (the CI smoke's contract, ex-grep)
+# Trace span check
 # ---------------------------------------------------------------------------
-# Span kinds a toy bench run MUST leave in its JSONL trace: the end-to-end
-# proof that tracing, the optimizer rules, the build profiler, the advisor,
-# and the serving layer all actually emitted.  Kept next to the catalog
-# parser so the list and the docs/16 taxonomy are checked together
-# (lint --check-catalog --trace <file>).
-REQUIRED_BENCH_SPANS = (
-    "bench.setup",
-    "bench.sf1_queries",
-    "query.collect",
-    "optimize",
-    "optimize.rule.filter",
-    "execute",
+# Span kinds the chip benchmark's per-layer metrics read
+# (benchmark/layer_metrics/): a trace of a served Q3, a hybrid-scan Q3 and
+# a full refresh must hold every one (tests/test_measured_spans.py).
+# Kept next to the catalog parser so the list and the docs/16 taxonomy
+# are checked together (lint --check-catalog --trace <file>).
+REQUIRED_SPANS = (
+    "serve.request",
+    "source.snapshot",
     "exec.scan",
+    "scan.files",
     "io.read",
-    "bench.advisor",
-    "advisor.whatif",
-    "bench.build_profile",
-    "action.CreateAction",
+    "exec.hybrid_appended",
+    "exec.filter",
+    "exec.join",
+    "exec.aggregate",
+    "device.sync",
     "build.phase.read",
-    "build.phase.write",
+    "build.phase.prefetch",
     "build.phase.spill_route",
     "build.phase.spill_finish",
-    "bench.multichip",
-    "bench.serving",
-    "serve.request",
-    "bench.flight_recorder",
-    "bench.alerts",
-    "alert.evaluate",
-    "alert.capture",
-    "bench.fleet_obs",
-    "fleet.publish",
-    "bench.ingest",
-    "lifecycle.cycle",
-    "bench.timeline",
-    "timeline.export",
-    "doctor.run",
+    "build.phase.write",
+    "build.phase.validate",
+    "build.phase.commit",
 )
 
 
 def check_trace(path: str, span_entries: Sequence[str]) -> List[str]:
-    """Problems with a bench JSONL trace: required span kinds missing,
-    and span names present in the trace but absent from the docs/16
-    taxonomy (catalog drift the old CI grep could never see)."""
+    """Problems with a JSONL trace: required span kinds missing, and
+    span names present in the trace but absent from the docs/16
+    taxonomy."""
     import json as _json
 
     seen: Set[str] = set()
@@ -333,7 +320,7 @@ def check_trace(path: str, span_entries: Sequence[str]) -> List[str]:
         return [f"cannot read trace {path}: {e}"]
 
     problems = [f"required span kind missing from trace: {name}"
-                for name in REQUIRED_BENCH_SPANS if name not in seen]
+                for name in REQUIRED_SPANS if name not in seen]
     for name in sorted(seen):
         if not any(name_matches_entry(name, e) for e in span_entries):
             problems.append(

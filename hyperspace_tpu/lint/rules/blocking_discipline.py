@@ -70,6 +70,9 @@ _IO_PATHS = (
     "hyperspace_tpu/io/log_store.py",
     "hyperspace_tpu/io/avro.py",
 )
+# Opening a store object touches no file; what blocks is the store
+# method calls caught by ``_STORE_METHODS``.
+_NOT_IO = ("hyperspace_tpu/io/log_store.py::store_for",)
 
 _EXEC_TARGETS = (
     "hyperspace_tpu/execution/executor.py::Executor.execute",
@@ -94,7 +97,7 @@ def _blocking_kind(site: callgraph.CallSite,
             return f"store .{last}()"
     for t in site.targets:
         path, qual = t.split("::", 1)
-        if path in _IO_PATHS:
+        if path in _IO_PATHS and t not in _NOT_IO:
             return f"io call {qual}()"
     if n == "open":
         return "open()"

@@ -2,7 +2,7 @@
 config.py, wired into ``_FIELD_BY_KEY``, documented in docs/02, and
 actually used — in both directions, so the three surfaces cannot drift:
 
-  - a literal used anywhere (engine, bench, tests, examples) that
+  - a literal used anywhere (engine, tests, examples) that
     config.py does not declare is a typo'd or unregistered key — with
     near-miss suggestions, since ``conf.set`` raising ``KeyError`` at
     runtime is a far worse place to learn about it;

@@ -2,7 +2,7 @@
 
 Three checks:
 
-  - **bare except** anywhere in the engine or bench: catches
+  - **bare except** anywhere in the engine: catches
     ``KeyboardInterrupt``/``SystemExit`` — and this codebase models
     crashes as ``InjectedCrash(BaseException)`` precisely so cleanup
     code CANNOT swallow them; a bare except re-opens that hole.
@@ -32,7 +32,7 @@ from hyperspace_tpu.lint.engine import (
     enclosing_function_name,
 )
 
-_SCAN_INCLUDE = ("hyperspace_tpu/", "bench.py", "run-tests.py")
+_SCAN_INCLUDE = ("hyperspace_tpu/", "run-tests.py")
 _SCAN_EXCLUDE = ("hyperspace_tpu/lint/",)
 
 # Where `except Exception: pass` is a correctness bug, not a policy call.

@@ -17,7 +17,7 @@ from typing import Dict, List, Optional, Tuple
 from hyperspace_tpu.lint import catalog
 from hyperspace_tpu.lint.engine import Finding, LintContext, const_str
 
-_SCAN_INCLUDE = ("hyperspace_tpu/", "tests/", "bench.py")
+_SCAN_INCLUDE = ("hyperspace_tpu/", "tests/")
 # tests/test_lint.py carries deliberately-typo'd fixture sites.
 _SCAN_EXCLUDE = ("hyperspace_tpu/lint/", "tests/test_lint.py")
 

@@ -22,7 +22,7 @@ from typing import Dict, List, Optional, Set, Tuple
 
 from hyperspace_tpu.lint.engine import Finding, LintContext
 
-_SCAN_INCLUDE = ("hyperspace_tpu/", "bench.py", "run-tests.py", "tools/")
+_SCAN_INCLUDE = ("hyperspace_tpu/", "run-tests.py", "tools/")
 _SCAN_EXCLUDE = ()
 
 Binding = Tuple[Optional[str], str, Optional[str]]  # (module, name, asname)

@@ -28,7 +28,7 @@ from hyperspace_tpu.lint.engine import (
     joined_pattern,
 )
 
-_SCAN_INCLUDE = ("hyperspace_tpu/", "bench.py")
+_SCAN_INCLUDE = ("hyperspace_tpu/",)
 _SCAN_EXCLUDE = (
     "hyperspace_tpu/lint/",
     "hyperspace_tpu/telemetry/metrics.py",   # the registry itself

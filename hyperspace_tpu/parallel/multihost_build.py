@@ -74,7 +74,7 @@ def armed(conf) -> bool:
 
     0 disables (the ordinary in-process build); 1 runs a single
     subprocess host through the SAME claim/stage/commit protocol —
-    degenerate but useful as the bench baseline for the 1-vs-2-host
+    degenerate but useful as the baseline for a 1-vs-2-host
     scaling ratio (identical per-chunk overheads on both sides); >= 2
     is the real multi-host build."""
     return int(getattr(conf, "multihost_build_hosts", 0)) >= 1
@@ -87,7 +87,7 @@ def build_root(conf) -> str:
 
 
 def _store(conf, build_id: str):
-    from hyperspace_tpu.telemetry.perf_ledger import store_for
+    from hyperspace_tpu.io.log_store import store_for
 
     return store_for(conf, os.path.join(build_root(conf), build_id))
 
@@ -618,8 +618,8 @@ def _poll_done(claims, items) -> int:
 def _claim_span(claims, items) -> float:
     """Phase wall-clock from the claim records: first acquire to last
     complete across the items' done records.  Excludes subprocess
-    interpreter spin-up, which is what makes the bench's scaling gate
-    honest."""
+    interpreter spin-up, so a 1-vs-2-host ratio compares the work
+    itself."""
     first, last = None, None
     for item in items:
         rec, _gen = claims.get(item)
@@ -725,7 +725,7 @@ def run_multihost_build(action, files, columns, relation, resolved,
         # Phase wall-clock from the claim records themselves (first
         # acquire -> last complete): what the work actually took,
         # independent of the ~seconds of subprocess interpreter spin-up
-        # — the number the bench's near-2x gate is honest against.
+        # — the number a host-scaling ratio is honest against.
         route_wall = _claim_span(claims, chunk_items)
         finalize_wall = _claim_span(claims, group_items)
         total_wall = _time.perf_counter() - t_spawn

@@ -51,8 +51,7 @@ Metrics: ``alerts.evaluations`` / ``alerts.transitions`` /
 ``alerts.bundles_captured`` / ``alerts.notifications`` counters and the
 ``alerts.firing`` gauge; spans ``alert.evaluate`` and ``alert.capture``
 (docs/16-observability.md).  The serve path itself is never touched —
-a disabled engine costs the serving workload nothing (bench ``alerts``
-section gates the ENABLED engine < 3% on the serving workload).
+a disabled engine costs the serving workload nothing.
 """
 
 from __future__ import annotations
@@ -102,7 +101,7 @@ def alert_root(conf) -> str:
 
 
 def _store(conf):
-    from hyperspace_tpu.telemetry.perf_ledger import store_for
+    from hyperspace_tpu.io.log_store import store_for
 
     return store_for(conf, alert_root(conf))
 
@@ -229,7 +228,7 @@ class AlertEngine:
     """One evaluator per session (``engine_for``); opt-in via
     ``hyperspace.alerts.enabled`` like the lifecycle daemon and the
     fleet publisher.  ``run_once()`` is the synchronous evaluation the
-    thread loops on — tests, the bench section, and the chaos drill
+    thread loops on — tests and the chaos drill
     drive it directly."""
 
     def __init__(self, session) -> None:
@@ -482,7 +481,7 @@ class AlertEngine:
             metrics,
             timeline,
         )
-        from hyperspace_tpu.telemetry.perf_ledger import store_for
+        from hyperspace_tpu.io.log_store import store_for
         from hyperspace_tpu.telemetry.trace import span
 
         try:

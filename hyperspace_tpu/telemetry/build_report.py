@@ -40,9 +40,7 @@ the report as ``session.last_build_report_value`` / :func:`last_report`
 
 Cost contract: ``hyperspace.system.buildProfiling.enabled`` (default on)
 gates the memory sampling, metric export, span tags, and the perf
-ledger append; phase timing itself stays on (``build_stats_log``, which
-bench.py records, is derived from it).  The bench ``build_profile``
-section gates the on-vs-off delta < 3%.
+ledger append; phase timing itself stays on.
 """
 
 from __future__ import annotations

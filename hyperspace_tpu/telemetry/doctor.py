@@ -28,9 +28,8 @@ finding):
                     going stale).
   ``perf``          perf-ledger trend: for each action name with enough
                     history, the latest ``wall_s`` against the median of
-                    its predecessors, judged by the bench_compare
-                    direction rules — a ≥ 25% AND ≥ 0.5 s regression is
-                    ``warn``.
+                    its predecessors (lower is better) — a ≥ 25% AND
+                    ≥ 0.5 s regression is ``warn``.
   ``serving``       shed rate (``serve.shed`` / ``serve.requests``)
                     above ``hyperspace.doctor.shedWarnRatio`` → ``warn``
                     (``crit`` past 5× the ratio); latency SLO burn — the
@@ -299,11 +298,11 @@ def _check_maintenance(session) -> DoctorCheck:
 def _check_perf(session, min_history: int = 4,
                 threshold_pct: float = 25.0,
                 min_abs_s: float = 0.5) -> DoctorCheck:
-    """Latest-vs-history trend per recorded action name, judged by the
-    bench_compare direction rules (``wall_s`` → lower is better)."""
-    from hyperspace_tpu.telemetry import bench_compare, perf_ledger
+    """Latest-vs-history trend per recorded action name: ``wall_s`` is
+    lower-is-better, so a latest run slower than the history's median
+    by more than both thresholds is a regression."""
+    from hyperspace_tpu.telemetry import perf_ledger
 
-    direction = bench_compare._direction("wall_s")
     by_name: Dict[str, List[float]] = {}
     for rec in perf_ledger.records(session.conf):
         if rec.get("kind") != "action" or rec.get("outcome") != "ok":
@@ -321,8 +320,7 @@ def _check_perf(session, min_history: int = 4,
         baseline = statistics.median(walls[-9:-1])
         if baseline <= 0:
             continue
-        worse = latest - baseline if direction == "lower" \
-            else baseline - latest
+        worse = latest - baseline
         if worse > min_abs_s and worse / baseline * 100.0 > threshold_pct:
             regressions[name] = {"latest_s": round(latest, 3),
                                  "baseline_s": round(baseline, 3)}

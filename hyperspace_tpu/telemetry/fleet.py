@@ -152,7 +152,7 @@ def fleet_root(conf) -> str:
 
 
 def _store(conf):
-    from hyperspace_tpu.telemetry.perf_ledger import store_for
+    from hyperspace_tpu.io.log_store import store_for
 
     return store_for(conf, fleet_root(conf))
 

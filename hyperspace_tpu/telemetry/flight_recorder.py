@@ -30,8 +30,7 @@ One record is a flat dict:
 
 Serialization cost is paid only for RETAINED records — the offer
 decision is a few conf reads and a counter, so the healthy fast path
-stays flat (bench ``flight_recorder`` section gates < 3% on the serving
-workload).
+stays flat.
 
 Persistence: :func:`dump_diagnostics` (called by ``QueryServer.drain``
 — so SIGTERM via ``handle_sigterm=True`` dumps — and by
@@ -325,7 +324,7 @@ def dump_diagnostics(conf) -> Optional[str]:
     fault counter or die to an injected crash)."""
     from hyperspace_tpu.io import faults
     from hyperspace_tpu.telemetry import metrics
-    from hyperspace_tpu.telemetry.perf_ledger import store_for
+    from hyperspace_tpu.io.log_store import store_for
     from hyperspace_tpu.telemetry.trace import span
 
     if not bool(getattr(conf, "flight_recorder_enabled", True)):
@@ -359,7 +358,7 @@ def bundles(conf) -> List[Dict[str, Any]]:
     """Every parseable persisted bundle, oldest first (``key`` attached).
     Torn/unparseable bundles are skipped — diagnostics are advisory."""
     from hyperspace_tpu.io import faults
-    from hyperspace_tpu.telemetry.perf_ledger import store_for
+    from hyperspace_tpu.io.log_store import store_for
 
     out: List[Dict[str, Any]] = []
     try:
@@ -383,7 +382,7 @@ def bundles(conf) -> List[Dict[str, Any]]:
 def clear_bundles(conf) -> None:
     """Wipe persisted bundles (tests)."""
     from hyperspace_tpu.io import faults
-    from hyperspace_tpu.telemetry.perf_ledger import store_for
+    from hyperspace_tpu.io.log_store import store_for
 
     with faults.quiet():
         store = store_for(conf, flight_root(conf))

@@ -23,8 +23,8 @@ Design constraints, in order:
     into names cannot grow it without bound.  Histograms keep fixed
     log-scale buckets plus count/sum/min/max — O(1) per observation,
     O(buckets) memory.
-  - **resettable**: ``reset()`` zeroes everything (tests; a bench section
-    isolating its own deltas).
+  - **resettable**: ``reset()`` zeroes everything (tests isolating their
+    own deltas).
 
 Disabled-cost note: there is no enable switch — an increment is a dict
 update under a lock, cheap enough to leave always-on at the file/action

@@ -1,21 +1,18 @@
 """The persistent perf ledger: a durable, diffable record of every run.
 
-BENCH_r05 (rc=124, headline lost) showed the perf trajectory living only
-in the mind of whoever read the last bench log.  This module gives every
-action — and every bench section — a compact structured record appended
-through the PR 2 :class:`~hyperspace_tpu.io.log_store.LogStore` seam
-under ``<systemPath>/_hyperspace_perf``, so the same code works over
+Every profiled action appends a compact structured record through the
+:class:`~hyperspace_tpu.io.log_store.LogStore` seam under
+``<systemPath>/_hyperspace_perf``, so the same code works over
 :class:`PosixLogStore` and :class:`EmulatedObjectStore`, survives
-restarts, and is readable by ``Hyperspace.perf_history()`` / the interop
-``perf_history`` verb / ``bench.py --compare auto``.
+restarts, and is readable by ``Hyperspace.perf_history()``, the interop
+``perf_history`` verb and the doctor's ``perf`` trend check.
 
 Record shape (one flat JSON object per key):
 
-  - ``kind``: ``"action"`` or ``"bench"``
-  - ``name``: action class + index, or bench section name
+  - ``kind``: ``"action"``
+  - ``name``: action class + index
   - ``ts`` / ``wall_s`` / ``outcome``
-  - ``phases_s`` + the byte counters (action records: the BuildReport
-    serialization; bench records: the section's scalar metrics)
+  - ``phases_s`` + the byte counters (the BuildReport serialization)
   - ``fingerprint``: host, platform, jax/pyarrow versions, and the
     build-relevant conf knobs — so a diff across records can tell a real
     regression from a changed environment.
@@ -40,6 +37,8 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from hyperspace_tpu.io.log_store import store_for
+
 PERF_DIR = "_hyperspace_perf"
 RECORD_VERSION = 1
 
@@ -51,20 +50,6 @@ def perf_root(conf) -> str:
     from hyperspace_tpu.index.path_resolver import PathResolver
 
     return os.path.join(PathResolver(conf).system_path, PERF_DIR)
-
-
-def store_for(conf, root: Optional[str] = None):
-    """The ledger store: backend class from
-    ``hyperspace.index.logStoreClass`` (the workload/quarantine managers'
-    exact construction), rooted at the perf dir."""
-    from hyperspace_tpu.exceptions import HyperspaceError
-    from hyperspace_tpu.io.log_store import LogStore
-    from hyperspace_tpu.utils.reflection import load_class
-
-    cls = load_class(conf.log_store_class, LogStore, HyperspaceError)
-    return cls(root if root is not None else perf_root(conf),
-               stale_list_s=float(getattr(
-                   conf, "object_store_stale_list_ms", 0.0)) / 1000.0)
 
 
 def enabled(conf) -> bool:
@@ -121,7 +106,7 @@ def append(conf, record: Dict[str, Any]) -> Optional[str]:
         return None
     try:
         with faults.quiet():
-            store = store_for(conf)
+            store = store_for(conf, perf_root(conf))
             rec = {"v": RECORD_VERSION, "ts": time.time(), **record}
             payload = json.dumps(rec, default=str).encode("utf-8")
             key = None
@@ -145,7 +130,7 @@ def append(conf, record: Dict[str, Any]) -> Optional[str]:
         return None
 
 
-def records(conf, root: Optional[str] = None) -> List[Dict[str, Any]]:
+def records(conf) -> List[Dict[str, Any]]:
     """Every parseable ledger record, oldest first.  Torn/unparseable
     records are skipped — the ledger is advisory data."""
     from hyperspace_tpu.io import faults
@@ -153,7 +138,7 @@ def records(conf, root: Optional[str] = None) -> List[Dict[str, Any]]:
     out: List[Dict[str, Any]] = []
     try:
         with faults.quiet():
-            store = store_for(conf, root)
+            store = store_for(conf, perf_root(conf))
             for key in sorted(store.list_keys()):
                 try:
                     rec = json.loads(store.read(key).decode("utf-8"))
@@ -168,46 +153,35 @@ def records(conf, root: Optional[str] = None) -> List[Dict[str, Any]]:
     return out
 
 
-def filtered_records(conf, root: Optional[str] = None,
-                     index: Optional[str] = None,
-                     section: Optional[str] = None,
+def filtered_records(conf, index: Optional[str] = None,
                      limit: Optional[int] = None) -> List[Dict[str, Any]]:
-    """Ledger records with the ``perf_history`` ergonomics filters
-    applied: ``index`` keeps action records for that index (the
-    ``Action(index)`` naming or the serialized ``index`` field),
-    ``section`` keeps bench records for that section name, ``limit``
-    keeps the most recent N after filtering."""
-    out = records(conf, root)
+    """Ledger records with the ``perf_history`` filters applied:
+    ``index`` keeps action records for that index (the ``Action(index)``
+    naming or the serialized ``index`` field), ``limit`` keeps the most
+    recent N after filtering."""
+    out = records(conf)
     if index:
         out = [r for r in out
                if r.get("index") == index
                or str(r.get("name", "")).endswith(f"({index})")]
-    if section:
-        out = [r for r in out
-               if r.get("kind") == "bench"
-               and r.get("name") == section]
     if limit is not None and limit >= 0:
         out = out[-int(limit):] if limit else []
     return out
 
 
-def history_table(conf, root: Optional[str] = None,
-                  index: Optional[str] = None,
-                  section: Optional[str] = None,
+def history_table(conf, index: Optional[str] = None,
                   limit: Optional[int] = None):
     """The ledger as an arrow table (one row per record) — the shape
     ``Hyperspace.perf_history()`` and the interop ``perf_history`` verb
-    return, both of which pass the ``index``/``section``/``limit``
-    filters straight through (callers used to re-filter raw records by
-    hand).  Structured sub-objects ride as JSON strings so the schema
+    return, both of which pass the ``index``/``limit`` filters straight
+    through.  Structured sub-objects ride as JSON strings so the schema
     stays flat and stable."""
     import pyarrow as pa
 
     rows = {"key": [], "kind": [], "name": [], "ts": [], "wallSeconds": [],
             "outcome": [], "phasesJson": [], "bytesWritten": [],
             "spillBytes": [], "recordJson": []}
-    for rec in filtered_records(conf, root, index=index, section=section,
-                                limit=limit):
+    for rec in filtered_records(conf, index=index, limit=limit):
         rows["key"].append(rec.get("key", ""))
         rows["kind"].append(str(rec.get("kind", "")))
         rows["name"].append(str(rec.get("name", "")))
@@ -237,6 +211,6 @@ def clear(conf) -> None:
     from hyperspace_tpu.io import faults
 
     with faults.quiet():
-        store = store_for(conf)
+        store = store_for(conf, perf_root(conf))
         for key in store.list_keys():
             store.delete(key)

@@ -44,8 +44,7 @@ the async dispatcher would otherwise hide is exactly the cost the gate
 exists to avoid).  Enabled, instrumentation stays at phase/operator/
 kernel granularity — never per row — and the ring is bounded
 (``hyperspace.system.timeline.maxIntervals``, oldest dropped and
-counted).  The bench ``timeline`` section gates recorder + sampler
-overhead < 3% on the build_profile workload.
+counted).
 """
 
 from __future__ import annotations
@@ -223,7 +222,7 @@ def kernel_end(name: str, t0_ns: Optional[int], out: Any = None,
     ``devices`` names the mesh an SPMD program ran over: the program
     occupies EVERY mesh device for its duration, so the elapsed ms is
     attributed to each (one counter bump and one lane interval per
-    device — the per-device skew view the multichip bench reads).
+    device — the per-device skew view).
     No-op (and no sync!) when the timeline is disabled."""
     if t0_ns is None:
         return

@@ -6,8 +6,8 @@ those decisions *visible*.  A span is one timed region with outcome tags
 (``span("exec.scan", files=3)``); spans nest through a ``contextvar`` so
 a query's trace is a tree — optimize under collect, rules under optimize,
 file reads under the scan — and the finished ROOT span is delivered to
-the registered sinks (a collecting sink for tests, a JSONL sink for bench
-and production runs, conf ``hyperspace.system.telemetry.trace.sink``).
+the registered sinks (a collecting sink for tests, a JSONL sink for
+production runs, conf ``hyperspace.system.telemetry.trace.sink``).
 
 Cost contract: tracing is OFF by default
 (``hyperspace.system.telemetry.tracing.enabled``) and the disabled path
@@ -244,7 +244,7 @@ class CollectingTraceSink(TraceSink):
 
 class JsonlTraceSink(TraceSink):
     """One JSON object per finished root span, appended to ``path`` — the
-    machine-readable artifact bench.py and production runs leave behind
+    machine-readable artifact production runs leave behind
     (conf ``hyperspace.system.telemetry.trace.sink``).
 
     Bounded by size-based rotation (conf

@@ -280,7 +280,7 @@ def calibrated_resident_min_rows(kind: str) -> int:
 
 
 def profile_summary() -> Dict[str, object]:
-    """JSON-ready view for bench/telemetry output."""
+    """JSON-ready view for telemetry output."""
     profile = device_profile()
     if profile is None:
         return {"calibrated": False,

@@ -10,8 +10,9 @@ Environment must be set before jax is imported anywhere.
 import os
 
 # Tests run on the CPU: the suite needs a deterministic 8-device mesh, and
-# the chip is reached through chip_smoke.py (and bench.py) instead.  The
-# env var covers child processes; the jax.config pin below covers this one.
+# the chip is reached through benchmark/run.py and chip_smoke.py instead.
+# The env var covers child processes; the jax.config pin below covers this
+# one.
 os.environ["JAX_PLATFORMS"] = "cpu"
 # Tiny kernel capacity: tests build 10-row indexes; padding them to the
 # production 1M-row batch would lexsort a million rows per create.
@@ -48,7 +49,6 @@ _HEAVY_MODULES = frozenset({
     "test_multihost_build",     # subprocess host fleets + SIGKILL drill
     "test_interop",             # Arrow-IPC server + C++ client build
     "test_external_build",      # streaming spill builds
-    "test_bench_resilience",    # runs bench.py end-to-end in subprocesses
     "test_chaos",               # seeded fleet chaos drill (3-server fleet)
     "test_netfaults",           # wire-fault drills + SIGSTOP subprocesses
 })

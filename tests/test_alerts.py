@@ -389,8 +389,8 @@ class TestEndToEnd:
         assert transitions == ["firing", "resolved"]
 
     def test_chaos_alert_drill_invariant(self, tmp_path):
-        # The exact invariant the chaos drill and the bench alerts
-        # section gate on, via the shared helper.
+        # The exact invariant the chaos drill gates on, via the shared
+        # helper.
         from hyperspace_tpu.interop.chaos import _alert_drill
 
         s = _session(tmp_path)
@@ -517,27 +517,3 @@ class TestSurfacing:
         assert snap.get("alerts.bundles_captured", 0) >= 1
         _drive_to_resolved(engine)
         assert _metrics.snapshot().get("alerts.firing") == 0.0
-
-
-class TestBenchCompareDirections:
-    def test_firing_and_ratio_are_lower_better(self):
-        from hyperspace_tpu.telemetry.bench_compare import _direction
-
-        assert _direction("alerts.firing") == "lower"
-        assert _direction("alerts.overhead_ratio") == "lower"
-        assert _direction("chaos.hedge_win_rate") is None
-
-    def test_unitless_lower_metric_skips_seconds_floor(self):
-        from hyperspace_tpu.telemetry.bench_compare import (
-            RunMetrics,
-            compare_runs,
-        )
-
-        base = RunMetrics(path="a", metrics={"alerts.firing": 1.0},
-                          key_section={}, phases={})
-        cur = RunMetrics(path="b", metrics={"alerts.firing": 2.0},
-                         key_section={}, phases={})
-        result = compare_runs(cur, base, threshold_pct=5.0,
-                              min_abs_s=0.5)
-        assert [r["metric"] for r in result.regressions] == \
-            ["alerts.firing"]

@@ -1,13 +1,11 @@
-"""Build-pipeline profiler, perf ledger, and regression watchdog tests
-(docs/16-observability.md "Build reports & perf ledger";
-docs/13-benchmarking.md "--compare").
+"""Build-pipeline profiler and perf ledger tests
+(docs/16-observability.md "Build reports & perf ledger").
 
 Covers the PR's acceptance loop:
   - a toy build's BuildReport phase seconds sum to ~the action wall time
     and its spill-bytes figure matches the bytes actually written;
   - the report survives a conflict-retried action;
-  - ledger round-trip + bounds over BOTH LogStore backends;
-  - bench_compare regression / no-regression / missing-baseline.
+  - ledger round-trip + bounds over BOTH LogStore backends.
 """
 
 from __future__ import annotations
@@ -21,7 +19,7 @@ import pyarrow.parquet as pq
 import pytest
 
 from hyperspace_tpu import Hyperspace, HyperspaceSession, IndexConfig
-from hyperspace_tpu.telemetry import bench_compare, perf_ledger
+from hyperspace_tpu.telemetry import perf_ledger
 
 BOTH_STORES = ("hyperspace_tpu.io.log_store.PosixLogStore",
                "hyperspace_tpu.io.log_store.EmulatedObjectStore")
@@ -66,9 +64,9 @@ class TestBuildReport:
         assert report is not None and report.action == "CreateAction"
         assert report.index == "bi" and report.outcome == "ok"
         # The protocol phases (validate/commit) plus the build phases
-        # account for nearly the whole run — the acceptance bound is 10%
-        # at bench scale; the test band is slightly looser because a toy
-        # build's fixed dispatch overhead is a larger fraction.
+        # account for nearly the whole run — within 10% at a real size;
+        # the test band is slightly looser because a toy build's fixed
+        # dispatch overhead is a larger fraction.
         coverage = report.phase_total_s() / max(report.wall_s, 1e-9)
         assert 0.80 <= coverage <= 1.20, report.to_dict()
         for phase in ("read", "kernel", "write", "sketch", "validate",
@@ -234,8 +232,8 @@ class TestBuildReport:
             "build.phase.read.seconds") > 0
         # The phases are spans of their own, opened where they ran: the
         # action's thread nests them under its span, worker threads
-        # deliver them as roots — what the CI trace grep asserts on the
-        # real bench.
+        # deliver them as roots (tests/test_measured_spans.py holds the
+        # benchmark's paths to emitting them).
         assert sink.find("action.CreateAction")
         names = {s.name for root in sink.spans for s in root.walk()}
         for phase in ("validate", "read", "kernel", "write", "commit"):
@@ -352,7 +350,7 @@ class TestPerfLedger:
         session.conf.perf_ledger_max_entries = 3
         for i in range(6):
             perf_ledger.append(session.conf,
-                               {"kind": "bench", "name": f"s{i}",
+                               {"kind": "action", "name": f"s{i}",
                                 "wall_s": float(i)})
         recs = perf_ledger.records(session.conf)
         assert len(recs) == 3
@@ -369,7 +367,7 @@ class TestPerfLedger:
         faults.install(plan)
         try:
             assert perf_ledger.append(session.conf,
-                                      {"kind": "bench", "name": "x",
+                                      {"kind": "action", "name": "x",
                                        "wall_s": 0.0}) is not None
             assert plan._calls == 0  # the armed site never saw the put
         finally:
@@ -380,112 +378,6 @@ class TestPerfLedger:
         assert os.path.isdir(os.path.join(
             session.conf.system_path, perf_ledger.PERF_DIR))
         assert hs.indexes().column("name").to_pylist() == ["bi"]
-
-
-# ---------------------------------------------------------------------------
-# Regression watchdog (bench_compare)
-# ---------------------------------------------------------------------------
-def _write_results(path, sections) -> str:
-    with open(path, "w", encoding="utf-8") as f:
-        f.write(json.dumps({"bench": "hyperspace-tpu"}) + "\n")
-        for rec in sections:
-            f.write(json.dumps(rec) + "\n")
-    return str(path)
-
-
-def _sections(filter_median=0.01, speedup=4.0, build_s=2.0,
-              spill_route=1.0, scan_median=2.0):
-    return [
-        {"section": "setup", "status": "ok", "elapsed_s": 3.0,
-         "index_build_s": build_s,
-         "index_build_phases": [
-             {"index": "li_idx", "read_s": 0.5,
-              "spill_route_s": spill_route, "write_s": 0.4}]},
-        {"section": "sf1_queries", "status": "ok", "elapsed_s": 2.0,
-         "filter_scan_s": {"median": scan_median, "min": scan_median,
-                           "max": scan_median, "reps": 3},
-         "filter_indexed_s": {"median": filter_median,
-                              "min": filter_median, "max": filter_median,
-                              "reps": 3},
-         "filter_speedup": speedup},
-    ]
-
-
-class TestBenchCompare:
-    def test_identical_runs_no_regression(self, tmp_path):
-        a = _write_results(tmp_path / "a.jsonl", _sections())
-        b = _write_results(tmp_path / "b.jsonl", _sections())
-        result, report = bench_compare.compare_files(a, b, 25.0, 0.0)
-        assert result.ok and result.compared >= 3
-        assert "no regression" in report
-
-    def test_timing_regression_flagged_with_attribution(self, tmp_path):
-        base = _write_results(tmp_path / "base.jsonl", _sections())
-        cur = _write_results(tmp_path / "cur.jsonl",
-                             _sections(build_s=5.0, spill_route=4.0))
-        result, report = bench_compare.compare_files(cur, base, 25.0, 0.1)
-        assert not result.ok
-        metrics_flagged = {r["metric"] for r in result.regressions}
-        assert "index_build_s" in metrics_flagged
-        assert result.regressions[0]["section"] == "setup"
-        # The per-phase attribution table names the phase that ate it.
-        assert "per-phase attribution" in report
-        assert "spill_route" in report
-        assert "+3.000" in report
-
-    def test_speedup_regression_flagged(self, tmp_path):
-        base = _write_results(tmp_path / "base.jsonl",
-                              _sections(speedup=8.0))
-        cur = _write_results(tmp_path / "cur.jsonl", _sections(speedup=4.0))
-        result, _report = bench_compare.compare_files(cur, base, 25.0, 0.5)
-        assert any(r["metric"] == "filter_speedup"
-                   for r in result.regressions)
-
-    def test_ratio_noise_guard_uses_reference_seconds(self, tmp_path):
-        """A halved speedup over a MILLISECOND workload is timer noise:
-        the ratio's abs floor resolves through the workload's own scan
-        seconds, so toy runs compare quiet back to back while a slow
-        workload's halved speedup still flags."""
-        base = _write_results(tmp_path / "base.jsonl",
-                              _sections(speedup=8.0, scan_median=0.004))
-        cur = _write_results(tmp_path / "cur.jsonl",
-                             _sections(speedup=4.0, scan_median=0.004))
-        result, _ = bench_compare.compare_files(cur, base, 25.0, 0.5)
-        assert not any(r["metric"] == "filter_speedup"
-                       for r in result.regressions)
-
-    def test_abs_floor_suppresses_toy_noise(self, tmp_path):
-        # +100% but only +10ms: under the 0.5s floor this is noise.
-        base = _write_results(tmp_path / "base.jsonl",
-                              _sections(filter_median=0.01))
-        cur = _write_results(tmp_path / "cur.jsonl",
-                             _sections(filter_median=0.02))
-        result, _ = bench_compare.compare_files(cur, base, 25.0, 0.5)
-        assert not any(r["metric"].startswith("filter_indexed_s")
-                       for r in result.regressions)
-        result2, _ = bench_compare.compare_files(cur, base, 25.0, 0.0)
-        assert any(r["metric"] == "filter_indexed_s.median"
-                   for r in result2.regressions)
-
-    def test_missing_baseline_raises(self, tmp_path):
-        cur = _write_results(tmp_path / "cur.jsonl", _sections())
-        with pytest.raises(bench_compare.BaselineError):
-            bench_compare.compare_files(cur, str(tmp_path / "nope.jsonl"))
-
-    def test_headline_shaped_baseline_loads(self, tmp_path):
-        headline = {"metric": "tpch_sf1_indexed_query_speedup_geomean",
-                    "value": 4.5, "unit": "x", "vs_baseline": 4.5,
-                    "detail": {"filter_speedup": 4.0,
-                               "index_build_s": 2.0,
-                               "platform": "cpu"}}
-        base = tmp_path / "BENCH_rXX.json"
-        base.write_text(json.dumps(headline))
-        cur = _write_results(tmp_path / "cur.jsonl",
-                             _sections(speedup=1.0, build_s=2.0))
-        result, _ = bench_compare.compare_files(str(cur), str(base),
-                                                25.0, 0.5)
-        assert any(r["metric"] == "filter_speedup"
-                   for r in result.regressions)
 
 
 # ---------------------------------------------------------------------------

@@ -161,7 +161,7 @@ class TestWatchSeam:
                      watch_debounce_ms=0.0)
         w = watch.SourceWatcher(s.conf, [], mode="store").start()
         try:
-            from hyperspace_tpu.telemetry.perf_ledger import store_for
+            from hyperspace_tpu.io.log_store import store_for
 
             store = store_for(s.conf, watch.watch_store_root(s.conf))
             assert store.put_if_absent("w-torn", b"{not json")
@@ -187,7 +187,7 @@ class TestWatchSeam:
         s = _session(tmp_path)
         for i in range(watch._MARKER_CAP + 10):
             assert watch.publish(s.conf, str(tmp_path), detail=str(i))
-        from hyperspace_tpu.telemetry.perf_ledger import store_for
+        from hyperspace_tpu.io.log_store import store_for
 
         store = store_for(s.conf, watch.watch_store_root(s.conf))
         assert len(store.list_keys()) <= watch._MARKER_CAP

@@ -88,7 +88,7 @@ def _session(tmp_path, store_class=EMULATED, interval=30.0):
 
 def _put_snapshot(conf, snap):
     """Plant a foreign snapshot directly (a process we don't spawn)."""
-    from hyperspace_tpu.telemetry.perf_ledger import store_for
+    from hyperspace_tpu.io.log_store import store_for
 
     store = store_for(conf, fleet.fleet_root(conf))
     key = "hb-" + snap["process"]
@@ -610,7 +610,7 @@ class TestBuildClaimsCheck:
     def _plant_claim(self, conf, holder, ttl_s, build="build-1-abc"):
         from hyperspace_tpu.lifecycle.lease import WorkClaims
         from hyperspace_tpu.parallel import multihost_build
-        from hyperspace_tpu.telemetry.perf_ledger import store_for
+        from hyperspace_tpu.io.log_store import store_for
 
         store = store_for(conf, os.path.join(
             multihost_build.build_root(conf), build))

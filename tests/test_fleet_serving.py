@@ -188,7 +188,7 @@ class TestFrontDoor:
         """The drain-grace routing hole: a draining server's heartbeat
         row says so, and the router stops picking it — requests go to
         the survivor instead of bouncing off ERR BUSY."""
-        from hyperspace_tpu.telemetry.perf_ledger import store_for
+        from hyperspace_tpu.io.log_store import store_for
 
         s, data = env
         s.conf.set("hyperspace.fleet.telemetry.publishIntervalS", 30.0)
@@ -416,7 +416,7 @@ class TestDrainingHeartbeat:
 # ---------------------------------------------------------------------------
 class TestDaemonsCheck:
     def _put_snapshot(self, conf, snap):
-        from hyperspace_tpu.telemetry.perf_ledger import store_for
+        from hyperspace_tpu.io.log_store import store_for
 
         store = store_for(conf, fleet.fleet_root(conf))
         key = "hb-" + snap["process"]
@@ -547,6 +547,15 @@ class TestFleetChurn:
 
                 for k in range(20):      # warm: all three serving
                     check(k)
+                # The router folds heartbeats in once a second and drops
+                # any older than two publish intervals (0.4 s), which a
+                # loaded host's publishers can all miss at that instant:
+                # keep warming until one refresh lands on a fresh row.
+                deadline = time.monotonic() + 30
+                while time.monotonic() < deadline and all(
+                        ep.load is None for ep in fc._endpoints):
+                    check(0)
+                    time.sleep(0.05)
                 # Fleet rows surfaced the children (addresses matched).
                 assert sum(1 for ep in fc._endpoints
                            if ep.load is not None) >= 1

@@ -104,7 +104,7 @@ class TestLeaseProtocol:
         assert b.epoch == 2
 
     def test_torn_record_reads_absent(self, tmp_path, store_class):
-        from hyperspace_tpu.telemetry.perf_ledger import store_for
+        from hyperspace_tpu.io.log_store import store_for
 
         s = _session(tmp_path, store_class)
         store = store_for(s.conf, lease.lease_root(s.conf))

@@ -1,7 +1,7 @@
 """hslint (hyperspace_tpu/lint): per-rule fixture tests — one snippet
 that FIRES and one that stays QUIET per rule — plus the baseline
 add/expire round-trip, the JSON output schema, the CLI exit codes on a
-seeded violation, the bench-trace catalog check, and the self-clean gate
+seeded violation, the trace catalog check, and the self-clean gate
 (the linter over the real repo reports zero new findings).
 
 The fixtures build a miniature repo with the same layout the parsers
@@ -557,40 +557,40 @@ class TestTraceCheck:
 
     def test_complete_trace_passes(self, tmp_path):
         path = self._write_trace(
-            tmp_path, lint_catalog.REQUIRED_BENCH_SPANS)
+            tmp_path, lint_catalog.REQUIRED_SPANS)
         # Entries that make every required span name legal.
-        entries = list(lint_catalog.REQUIRED_BENCH_SPANS)
+        entries = list(lint_catalog.REQUIRED_SPANS)
         assert lint_catalog.check_trace(path, entries) == []
 
     def test_missing_required_span_flagged(self, tmp_path):
-        names = [n for n in lint_catalog.REQUIRED_BENCH_SPANS
+        names = [n for n in lint_catalog.REQUIRED_SPANS
                  if n != "serve.request"]
         path = self._write_trace(tmp_path, names)
         problems = lint_catalog.check_trace(
-            path, list(lint_catalog.REQUIRED_BENCH_SPANS))
+            path, list(lint_catalog.REQUIRED_SPANS))
         assert any("serve.request" in p for p in problems)
 
     def test_undocumented_span_in_trace_flagged(self, tmp_path):
-        names = list(lint_catalog.REQUIRED_BENCH_SPANS) + ["mystery.span"]
+        names = list(lint_catalog.REQUIRED_SPANS) + ["mystery.span"]
         path = self._write_trace(tmp_path, names)
         problems = lint_catalog.check_trace(
-            path, list(lint_catalog.REQUIRED_BENCH_SPANS))
+            path, list(lint_catalog.REQUIRED_SPANS))
         assert any("mystery.span" in p for p in problems)
 
     def test_torn_line_tolerated(self, tmp_path):
         path = self._write_trace(
-            tmp_path, lint_catalog.REQUIRED_BENCH_SPANS)
+            tmp_path, lint_catalog.REQUIRED_SPANS)
         with open(path, "a") as f:
             f.write('{"name": "torn')  # SIGTERM mid-write
         assert lint_catalog.check_trace(
-            path, list(lint_catalog.REQUIRED_BENCH_SPANS)) == []
+            path, list(lint_catalog.REQUIRED_SPANS)) == []
 
     def test_required_spans_are_in_real_catalog(self):
         # The required list must stay a subset of what docs/16 documents
         # (names the catalog can't match would always fail the smoke).
         ctx = lint_engine.build_context(REPO_ROOT)
         _metrics, spans = lint_catalog.telemetry_catalog(ctx)
-        for name in lint_catalog.REQUIRED_BENCH_SPANS:
+        for name in lint_catalog.REQUIRED_SPANS:
             assert any(lint_catalog.name_matches_entry(name, e)
                        for e in spans), name
 

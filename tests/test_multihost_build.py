@@ -36,7 +36,7 @@ from hyperspace_tpu.io.parquet import bucket_id_of_file
 from hyperspace_tpu.lifecycle import journal as lifecycle_journal
 from hyperspace_tpu.lifecycle.lease import WorkClaims
 from hyperspace_tpu.parallel import multihost_build
-from hyperspace_tpu.telemetry.perf_ledger import store_for
+from hyperspace_tpu.io.log_store import store_for
 
 BOTH_STORES = ["hyperspace_tpu.io.log_store.PosixLogStore",
                "hyperspace_tpu.io.log_store.EmulatedObjectStore"]

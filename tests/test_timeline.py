@@ -13,7 +13,7 @@ Covers the PR's acceptance loop:
   - the doctor matrix over BOTH LogStore backends: clean tree → ok,
     seeded quarantine → crit (and ok again after repair), stale
     index → warn;
-  - ``perf_history`` index/section/limit filters (API + verb).
+  - ``perf_history`` index/limit filters (API + verb).
 """
 
 from __future__ import annotations
@@ -609,7 +609,7 @@ class TestPerfHistoryFilters:
         hs.create_index(ds, IndexConfig("aa", ["k"], ["v"]))
         hs.create_index(ds, IndexConfig("bb", ["k"], ["v"]))
         perf_ledger.append(session.conf, {
-            "kind": "bench", "name": "sf1_queries", "outcome": "ok",
+            "kind": "action", "name": "RefreshAction(bb)", "outcome": "ok",
             "wall_s": 1.0})
         return session, hs
 
@@ -619,12 +619,6 @@ class TestPerfHistoryFilters:
         names = table.column("name").to_pylist()
         assert names and all(n.endswith("(aa)") for n in names)
         assert hs.perf_history(index="nope").num_rows == 0
-
-    def test_section_filter(self, seeded):
-        _session_, hs = seeded
-        table = hs.perf_history(section="sf1_queries")
-        assert table.num_rows == 1
-        assert table.column("kind").to_pylist() == ["bench"]
 
     def test_limit_keeps_most_recent(self, seeded):
         _session_, hs = seeded
@@ -638,10 +632,11 @@ class TestPerfHistoryFilters:
     def test_verb_mirrors_filters(self, seeded):
         from hyperspace_tpu.interop.server import _serve_verb
 
-        session, _hs = seeded
+        session, hs = seeded
         table = _serve_verb(session, {"verb": "perf_history",
-                                      "section": "sf1_queries"})
-        assert table.num_rows == 1
+                                      "index": "bb"})
+        assert table.column("name").to_pylist()[-1] == "RefreshAction(bb)"
+        assert table.num_rows == hs.perf_history(index="bb").num_rows
         table = _serve_verb(session, {"verb": "perf_history", "limit": 1})
         assert table.num_rows == 1
         with pytest.raises(ValueError, match='"limit"'):

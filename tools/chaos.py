@@ -2,7 +2,7 @@
 """Seeded fleet chaos drill launcher.
 
 The engine lives at :mod:`hyperspace_tpu.interop.chaos` (importable from
-bench and tests); this shim makes it runnable from a checkout without an
+tests); this shim makes it runnable from a checkout without an
 install::
 
     python tools/chaos.py --seed 7 --duration 8
